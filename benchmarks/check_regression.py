@@ -2,7 +2,7 @@
 
 Every benchmark writes one ``BENCH_<family>_r<round>.json`` artifact per
 round (``BENCH_serving_r06.json``, ``BENCH_capacity_r05.json``, bare
-``BENCH_r05.json``).  Until now those were a folder of JSON — nothing
+``BENCH_r01.json``).  Until now those were a folder of JSON — nothing
 failed when a PR made serving 30% slower.  This tool turns the
 trajectory into a gate:
 
@@ -44,7 +44,7 @@ import re
 import sys
 from typing import Any, Dict, List, Optional, Tuple
 
-#: BENCH_<family>_r<round>.json; bare BENCH_r05.json → family "core"
+#: BENCH_<family>_r<round>.json; bare BENCH_r01.json → family "core"
 _BENCH_RE = re.compile(r"^BENCH_(?:(?P<family>.+)_)?r(?P<round>\d+)"
                        r"(?P<partial>_partial)?\.json$")
 

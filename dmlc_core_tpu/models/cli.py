@@ -238,7 +238,10 @@ def main(argv=None) -> int:
     import jax
     import optax
 
+    from ..utils.compile_cache import enable_compile_cache
     from .train import (auc_from_histograms, make_train_step, streaming_auc)
+
+    enable_compile_cache()
 
     model = MODEL_REGISTRY[p.model](p)
     needs_fields = p.model == "ffm"

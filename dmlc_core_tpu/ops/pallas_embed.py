@@ -30,36 +30,22 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["embed_bag", "embed_bag_pallas", "embed_bag_reference",
            "fm_embed_terms"]
 
-_pallas_ok_cache: dict = {}
+# f32 lane tile of the TPU's HBM/VMEM layout.  Both kernels fetch one
+# table row per DMA (``table_ref.at[pl.ds(idx, 1), :]``), and Mosaic
+# refuses a slice of a tiled HBM ref whose minor dimension is not a whole
+# number of lane tiles ("Slice shape along dimension 1 must be aligned to
+# tiling (128)") — tests/test_tpu_compile.py compiles both sides of this
+# rule for a described v5e.
+_LANES = 128
 
 
-def _pallas_supported(D: int, fused: bool = False) -> bool:
-    """One tiny eager compile per (embedding width, kernel): if Mosaic
-    rejects this lowering (un-validated D, driver quirks), dispatch falls
-    back to XLA instead of aborting the whole jitted train step at compile
-    time.  The single-output ``embed_bag`` and the fused two-output FM
-    kernel lower with different out_specs/scratch, so each is probed with
-    the kernel that will actually run."""
-    key = (D, fused)
-    ok = _pallas_ok_cache.get(key)
-    if ok is None:
-        try:
-            ids = jnp.zeros((2, 2), jnp.int32)
-            vals = jnp.ones((2, 2), jnp.float32)
-            table = jnp.ones((4, D), jnp.float32)
-            if fused:
-                jax.block_until_ready(fm_terms_pallas(ids, vals, table))
-            else:
-                jax.block_until_ready(embed_bag_pallas(ids, vals, table))
-            ok = True
-        except Exception as e:  # noqa: BLE001 — mosaic compile failure etc.
-            import warnings
-            warnings.warn(
-                f"pallas {'fm_terms' if fused else 'embed_bag'} unavailable "
-                f"for D={D} ({type(e).__name__}: {e}); using XLA path")
-            ok = False
-        _pallas_ok_cache[key] = ok
-    return ok
+def mosaic_row_dma_ok(D: int) -> bool:
+    """The engine rule's shape half: the per-row DMA kernels here and in
+    :mod:`.ragged_csr` lower on Mosaic only when the embedding width is a
+    multiple of the 128-lane tile.  ``engine="auto"`` sends every other
+    width to XLA; an explicit ``engine="pallas"`` (or the env pin) at such
+    a width is passed to the compiler, whose error reaches the caller."""
+    return D % _LANES == 0
 
 
 _engine_time_cache: dict = {}
@@ -71,12 +57,11 @@ def _pallas_profitable(B: int, K: int, D: int, fused: bool) -> bool:
     step, so the default verdict is a pure function of the call shape —
     no wall-clock probes whose outcome can differ across hosts/runs.
 
-    Measured truth (TPU_MICRO_r04.json, TPU v5 lite): the per-(row,k)
-    512-byte DMAs are latency-bound and the kernel loses to XLA's
-    gather+einsum by orders of magnitude at every shape that has run on
-    hardware (K=8, D=128: pallas 8394us vs xla 2.8us).  XLA's native
-    gather is simply good on TPU for these widths, so the deterministic
-    default is **always XLA**; the pallas engine stays available via
+    Not measured on this round's chip; default XLA.  (The per-(row,k)
+    512-byte DMAs are expected to be latency-bound against XLA's native
+    gather; ROADMAP S5 owes the kernel one chip verdict.)  So the
+    deterministic default is **always XLA**; the pallas engine stays
+    available via
     ``DMLC_EMBED_ENGINE=pallas`` (pin) or ``DMLC_EMBED_AUTOTUNE=1``
     (wall-clock probe — single-host bench use only, nondeterministic
     across hosts)."""
@@ -135,7 +120,7 @@ def _resolve_engine(engine: str, D: int, fused: bool = False,
     if pinned:                       # multi-host escape hatch: pin globally
         engine = pinned
     if engine == "auto":
-        if (jax.default_backend() == "tpu" and _pallas_supported(D, fused)
+        if (jax.default_backend() == "tpu" and mosaic_row_dma_ok(D)
                 and _pallas_profitable(B, K, D, fused)):
             return "pallas"
         return "xla"
@@ -156,7 +141,9 @@ def embed_bag(ids: jax.Array, vals: jax.Array, table: jax.Array,
       * ``"xla"``     — gather + einsum (reference semantics, any backend)
       * ``"pallas"``  — the DMA double-buffered kernel; on non-TPU backends
         runs ``interpret=True`` (slow, for tests)
-      * ``"auto"``    — pallas on TPU, xla elsewhere
+      * ``"auto"``    — xla, unless ``DMLC_EMBED_AUTOTUNE=1`` times the
+        kernel faster on a TPU at a width it lowers for
+        (:func:`mosaic_row_dma_ok`)
 
     Differentiable w.r.t. ``vals`` and ``table`` on every engine: the
     pallas forward carries a custom VJP whose backward is plain XLA

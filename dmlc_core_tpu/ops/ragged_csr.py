@@ -32,16 +32,16 @@ Two engines, same semantics:
   per-chunk accumulators are summed outside, so the pallas result is
   allclose (not bit-identical — different summation order).
 
-Engine selection mirrors ``pallas_embed``: the pallas import is
-attempted once at module import (absent ⇒ the XLA fallback is the only
-engine); ``auto`` resolves to pallas only on a TPU backend where a tiny
-probe compile succeeds, and ``DMLC_RAGGED_ENGINE=xla|pallas`` pins
-globally.  Honesty note (repo precedent, `docs/perf.md` §Pallas): the
-per-entry ~512-byte DMA pattern lost to XLA's native gather at every
-embedding-bag shape measured on v5e, and this kernel's profitability is
-**unmeasured on hardware** — the bench artifacts record both engines so
-the default can follow measurement, exactly as the embed-bag default
-did.  On non-TPU backends the kernels run ``interpret=True`` (tests).
+Engine selection is a stated function of backend and shape: ``auto``
+resolves to pallas on a TPU backend when the table width is a multiple
+of the 128-lane tile (``pallas_embed.mosaic_row_dma_ok`` — Mosaic refuses
+the per-row DMA at any other width) and to xla otherwise;
+``DMLC_RAGGED_ENGINE=xla|pallas`` pins globally.  A pinned or explicit
+``pallas`` is handed to the compiler as is: at a width Mosaic refuses,
+its error reaches the caller — nothing downgrades quietly.  The
+kernel's profitability against XLA's native gather is **not measured on
+this round's chip** (ROADMAP S5).  On non-TPU backends the kernels run
+``interpret=True`` (tests).
 """
 
 from __future__ import annotations
@@ -51,13 +51,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:  # fallback selected at import when Pallas is absent (ISSUE 6)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover - pallas-less jax build
-    pl = pltpu = None
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_embed import mosaic_row_dma_ok
 
 __all__ = ["ragged_segment_sum", "ragged_dense_matvec", "ragged_embed_sum",
            "ragged_embed_grad", "ragged_fm_pairwise", "mask_ragged",
@@ -279,50 +276,16 @@ def _gather_pallas(ids, segs, vals, nnz_used, table, num_rows: int,
     return summed if fm else summed[0]
 
 
-_pallas_ok_cache: dict = {}
-
-
-def _pallas_supported(D: int, fm: bool) -> bool:
-    """One tiny eager compile per (width, kernel) — a Mosaic rejection
-    downgrades to XLA with a warning instead of aborting the caller's
-    trace (the ``pallas_embed._pallas_supported`` contract)."""
-    key = (D, fm)
-    ok = _pallas_ok_cache.get(key)
-    if ok is None:
-        try:
-            ids = jnp.zeros(8, jnp.int32)
-            segs = jnp.zeros(8, jnp.int32)
-            vals = jnp.ones(8, jnp.float32)
-            table = jnp.ones((4, D), jnp.float32)
-            jax.block_until_ready(_gather_pallas(
-                ids, segs, vals, 8, table, 2, fm=fm))
-            ok = True
-        except Exception as e:  # noqa: BLE001 — mosaic compile failure etc.
-            import warnings
-            warnings.warn(
-                f"pallas ragged {'fm' if fm else 'embed'} kernel "
-                f"unavailable for D={D} ({type(e).__name__}: {e}); "
-                f"using XLA path")
-            ok = False
-        _pallas_ok_cache[key] = ok
-    return ok
-
-
-def _resolve_engine(engine: str, D: int, fm: bool = False) -> str:
+def _resolve_engine(engine: str, D: int) -> str:
     from ..utils.parameter import get_env
     pinned = get_env("DMLC_RAGGED_ENGINE", None)
     if pinned:
         engine = pinned
     if engine == "auto":
-        if (_HAVE_PALLAS and jax.default_backend() == "tpu"
-                and _pallas_supported(D, fm)):
-            return "pallas"
-        return "xla"
+        return ("pallas" if jax.default_backend() == "tpu"
+                and mosaic_row_dma_ok(D) else "xla")
     if engine not in ("xla", "pallas"):
         raise ValueError(f"unknown ragged engine {engine!r}")
-    if engine == "pallas" and not _HAVE_PALLAS:
-        raise ValueError("pallas requested but jax.experimental.pallas "
-                         "is unavailable in this jax build")
     return engine
 
 
@@ -335,7 +298,7 @@ def ragged_embed_sum(ids: jax.Array, vals: jax.Array, segments: jax.Array,
                      engine: str = "auto") -> jax.Array:
     """Ragged twin of :func:`.csr.csr_embed_sum`: out[r, :] = Σ vals[i] ·
     table[ids[i], :] over live entries with segments[i] == r."""
-    engine = _resolve_engine(engine, table.shape[1], fm=False)
+    engine = _resolve_engine(engine, table.shape[1])
     if engine == "xla":
         return _embed_sum_xla(ids, vals, segments, nnz_used, table,
                               num_rows)
@@ -376,7 +339,7 @@ def ragged_fm_pairwise(ids: jax.Array, vals: jax.Array,
     """Ragged twin of :func:`.csr.fm_pairwise` — both FM reductions from
     one pass over the gathered rows (pallas) or two fused segment-sums
     (xla)."""
-    engine = _resolve_engine(engine, table.shape[1], fm=True)
+    engine = _resolve_engine(engine, table.shape[1])
     if engine == "xla":
         return _fm_pairwise_xla(ids, vals, segments, nnz_used, table,
                                 num_rows)

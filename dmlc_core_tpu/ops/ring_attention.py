@@ -26,7 +26,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import axis_size, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["ring_attention", "make_ring_attention", "reference_attention"]
@@ -71,7 +70,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     [B, T_local, H, D].  Shard i initially holds K/V block i; at step s it
     processes block (i - s) mod N received via ppermute.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     scale = q.shape[-1] ** -0.5
     b, t_local, h, d = q.shape
@@ -108,7 +107,7 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "sp",
 
     @jax.jit
     def fn(q, k, v):
-        return shard_map(
+        return jax.shard_map(
             functools.partial(ring_attention, axis_name=axis_name,
                               causal=causal),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

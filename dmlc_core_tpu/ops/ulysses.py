@@ -36,7 +36,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import axis_size, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .ring_attention import reference_attention
@@ -89,7 +88,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     permutations, so each one's adjoint IS the other (``all_to_all``'s
     autodiff transpose mislowers under this shard_map configuration, and
     the explicit adjoint pair is also the numerically obvious thing)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     @jax.custom_vjp
     def run(q, k, v):
@@ -130,7 +129,7 @@ def make_ulysses_attention(mesh: Mesh, axis_name: str = "sp",
             raise ValueError(
                 f"ulysses needs heads ({q.shape[2]}) divisible by mesh axis "
                 f"{axis_name!r} size ({n}); use ring attention instead")
-        return shard_map(
+        return jax.shard_map(
             functools.partial(ulysses_attention, axis_name=axis_name,
                               causal=causal),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

@@ -24,7 +24,6 @@ from typing import Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..utils.jax_compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..utils import DMLCError, check
@@ -132,7 +131,7 @@ class MeshCollectives:
         out_spec = P() if kind == "allgather" else P(axis)
 
         def run(stacked):
-            return shard_map(body, mesh=self.mesh,
+            return jax.shard_map(body, mesh=self.mesh,
                              in_specs=P(axis), out_specs=out_spec,
                              check_vma=False)(stacked)
         fn = jax.jit(run)

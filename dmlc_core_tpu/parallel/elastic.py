@@ -64,10 +64,8 @@ from .rabit import RabitContext
 
 __all__ = ["ElasticJaxMesh", "ResyncResult"]
 
-_BOUNDED_SHUTDOWN: Optional[bool] = None
-
-# deliberately leaked coordination handles from torn-down generations on
-# jaxes without a bounded shutdown barrier — see _teardown's clear_state
+# deliberately leaked coordination handles from torn-down generations
+# whose shutdown raised — see _teardown's clear_state
 _ZOMBIE_HANDLES: list = []
 
 
@@ -113,24 +111,6 @@ class ResyncResult:
                 f"generation={self.generation}, "
                 f"state={'<restored>' if self.state is not None else None}, "
                 f"stats={self.stats})")
-
-
-def _bounded_shutdown_supported() -> bool:
-    """Whether this jax accepts heartbeat/shutdown budget kwargs on
-    ``jax.distributed.initialize`` — the same vintages bound the shutdown
-    barrier; older ones block it indefinitely and LOG(FATAL) on a dead
-    peer."""
-    global _BOUNDED_SHUTDOWN
-    if _BOUNDED_SHUTDOWN is None:
-        import inspect
-
-        import jax
-        try:
-            params = inspect.signature(jax.distributed.initialize).parameters
-            _BOUNDED_SHUTDOWN = "shutdown_timeout_seconds" in params
-        except (TypeError, ValueError):    # C-level signature: assume new
-            _BOUNDED_SHUTDOWN = True
-    return _BOUNDED_SHUTDOWN
 
 
 class ElasticJaxMesh:
@@ -219,25 +199,14 @@ class ElasticJaxMesh:
                 log_warning("elastic: could not clear jax distributed "
                             "state (%s) — private API moved?", e2)
 
-        if not _bounded_shutdown_supported():
-            # this jax cannot bound the shutdown barrier: with a dead
-            # peer in the cohort, shutdown() blocks on the barrier for
-            # its full default budget and then LOG(FATAL)s the whole
-            # process from C++ (client.h "Terminating process…").
-            # Dropping the client references is the only survivable
-            # teardown — the old generation's service dies with its
-            # process or is garbage-collected with its last reference.
-            log_warning("elastic: this jax has no bounded shutdown "
-                        "barrier — dropping generation-%d client without "
-                        "the barrier", self.generation)
+        # the shutdown barrier is bounded by the budget ensure() passed to
+        # initialize(), so a dead peer costs seconds, not the process
+        try:
+            jax.distributed.shutdown()
+        except Exception as e:  # noqa: BLE001 — half-dead service
+            log_warning("elastic: shutdown of generation %d raised "
+                        "(%s) — proceeding", self.generation, e)
             clear_state()
-        else:
-            try:
-                jax.distributed.shutdown()
-            except Exception as e:  # noqa: BLE001 — half-dead service
-                log_warning("elastic: shutdown of generation %d raised "
-                            "(%s) — proceeding", self.generation, e)
-                clear_state()
         if not final:
             # the old backend holds client handles into the dead
             # coordination service; initialize() refuses to run while any
@@ -376,20 +345,14 @@ class ElasticJaxMesh:
             # rendezvous misses ITS window.  The next generation is a
             # fresh service on a fresh port; nothing of the old one is
             # worth waiting minutes for.
-            kw = {}
-            if _bounded_shutdown_supported():
-                kw = dict(
-                    heartbeat_timeout_seconds=env_int(
-                        "DMLC_ELASTIC_HEARTBEAT_S", 10, minimum=1),
-                    shutdown_timeout_seconds=env_int(
-                        "DMLC_ELASTIC_SHUTDOWN_S", 10, minimum=1))
-            # a jax that predates the budget kwargs still rebuilds the
-            # mesh; its dead-peer detection is just slower and its teardown
-            # goes through the barrier-less path in _teardown
             jax.distributed.initialize(
                 coordinator_address=self._coordinator(gen),
                 num_processes=self.num_processes,
-                process_id=self.process_id, **kw)
+                process_id=self.process_id,
+                heartbeat_timeout_seconds=env_int(
+                    "DMLC_ELASTIC_HEARTBEAT_S", 10, minimum=1),
+                shutdown_timeout_seconds=env_int(
+                    "DMLC_ELASTIC_SHUTDOWN_S", 10, minimum=1))
         self.generation = gen
         self._dirty = False
         if reshard_on:

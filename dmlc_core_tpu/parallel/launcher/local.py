@@ -56,6 +56,13 @@ def submit(args, tracker_envs: Dict[str, str]) -> int:
         env = dict(os.environ)
         env.update(tracker_envs)
         env.update(args.extra_env)
+        if nproc > 1:
+            # one process per chip: a cohort of JAX processes on one host
+            # cannot share its accelerator (the second to ask fails or
+            # hangs), so unless the caller placed them — JAX_PLATFORMS or
+            # the runtime's visible-device variables, here or via --env —
+            # they run on the CPU
+            env.setdefault("JAX_PLATFORMS", "cpu")
         env.update({
             "DMLC_ROLE": role,
             "DMLC_TASK_ID": str(i),

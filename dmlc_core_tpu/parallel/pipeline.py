@@ -28,7 +28,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["make_pipeline", "split_microbatches", "stack_stage_params",
@@ -61,7 +60,7 @@ def make_pipeline(mesh: Mesh, axis: str,
     num_stages = mesh.shape[axis]
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P()), out_specs=P())
     def run(stage_params, xs):
         # my slice of the stacked stage axis has length 1 — drop it
@@ -90,13 +89,7 @@ def make_pipeline(mesh: Mesh, axis: str,
         cur0 = jnp.where(s == 0, xs[0], jnp.zeros_like(xs[0]))
         # the carry becomes device-varying over 'pp' inside the loop, so
         # the initial value must carry the same varying-manual-axes type
-        zeros = jnp.zeros_like(xs)
-        if hasattr(jax.lax, "pcast"):
-            outs0 = jax.lax.pcast(zeros, (axis,), to="varying")
-        else:
-            # pre-varying-types jax has no manual-axes type distinction;
-            # the untyped zeros carry is already correct there
-            outs0 = zeros
+        outs0 = jax.lax.pcast(jnp.zeros_like(xs), (axis,), to="varying")
         (_, outs), _ = jax.lax.scan(tick, (cur0, outs0),
                                     jnp.arange(ticks))
         # only the last stage holds real outputs; psum replicates them so

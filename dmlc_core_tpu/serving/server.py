@@ -566,6 +566,8 @@ def serve_main(argv=None) -> int:
     import jax
 
     from ..models.cli import MODEL_REGISTRY, TrainParams
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     p = TrainParams()
     p.init({"data": "unused", "model": args.get("model", "fm"),
             "features": args["features"], "dim": args.get("dim", "16"),

@@ -80,9 +80,8 @@ def test_pallas_embed_bag_interpret_matches_reference():
 
 def test_engine_dispatch_deterministic(monkeypatch):
     """Default dispatch is a pure function of shape (ADVICE r3: every host
-    on a shared mesh must pick the same engine) and, post-TPU_MICRO_r04,
-    always XLA: on-hardware timing showed the DMA kernel loses at every
-    shape that has ever run (latency-bound 512B fetches), so pallas is
+    on a shared mesh must pick the same engine) and always XLA: the DMA
+    kernel is not measured on this round's chip; default XLA, so pallas is
     opt-in via DMLC_EMBED_ENGINE=pallas or DMLC_EMBED_AUTOTUNE=1."""
     from dmlc_core_tpu.ops import pallas_embed as pe
 
@@ -95,8 +94,9 @@ def test_engine_dispatch_deterministic(monkeypatch):
 
 def test_pallas_embed_chunked_matches_reference(monkeypatch):
     """Batches whose flat ids/vals exceed the SMEM scalar-prefetch budget
-    split into independent row-chunk pallas_calls (TPU_MICRO_r04: 1MB+
-    scalar operands are a hard Mosaic OOM on v5e).  Force a tiny cap so
+    split into independent row-chunk pallas_calls (1MB+ scalar operands
+    overflow v5e's SMEM; not measured on this round's chip; default XLA).
+    Force a tiny cap so
     the chunk path runs at test scale; a non-multiple tail chunk included."""
     from dmlc_core_tpu.ops import pallas_embed as pe
 

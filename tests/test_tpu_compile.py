@@ -1,0 +1,236 @@
+"""Ahead-of-time compiles of the main path for a *described* TPU v5e.
+
+The TPU's compiler is installed in the CPU sandbox and compiles for a chip
+that is described, not attached (on-chip-measurement guide §2.3).  That
+shows what ``interpret=True`` cannot: a Pallas kernel whose row DMA is not
+aligned to the 128-lane tiling is refused here exactly as on the chip, and
+a program that does not fit 16 GB of HBM is refused too.  Nothing runs, so
+these cases say nothing about results or times — ``chip_smoke.py`` on the
+chip does that.  Code that asks ``jax.default_backend()`` still sees the
+CPU, so each case compiles the kernel or the jitted step itself.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from dmlc_core_tpu.models import (FactorizationMachine, make_train_step,
+                                  make_train_step_fused, param_shardings)
+from dmlc_core_tpu.ops import pallas_embed, ragged_csr
+from dmlc_core_tpu.pipeline.device_loader import (_fused_words_meta,
+                                                  make_decoder)
+
+HBM_BYTES = 16 * 10 ** 9           # one v5e chip
+ROWS, NNZ, F, D = 4096, 131072, 1 << 20, 32      # the smoke's shapes
+V2_META = 98304                    # v2 wire: a mid nnz bucket, raw ids
+V3_META = 98304 | (20 << 32)       # compact wire: 20-bit ids, raw f32 vals
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    # a described-device compile is written to the persistent cache but
+    # cannot be read back without a chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _hbm(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _batch(rows=ROWS, nnz=NNZ):
+    S, f32, i32 = jax.ShapeDtypeStruct, jnp.float32, jnp.int32
+    return {"ids": S((nnz,), i32), "vals": S((nnz,), f32),
+            "segments": S((nnz,), i32), "labels": S((rows,), f32),
+            "weights": S((rows,), f32)}
+
+
+def _fm_state(model, opt):
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    return params, jax.eval_shape(opt.init, params)
+
+
+def _gather(one, width, fm):
+    S, f32, i32 = jax.ShapeDtypeStruct, jnp.float32, jnp.int32
+    args = _on(one, (S((NNZ,), i32), S((NNZ,), i32), S((NNZ,), f32),
+                     S((), i32), S((F, width), f32)))
+    return ragged_csr._gather_pallas.lower(
+        *args, num_rows=ROWS, fm=fm, interpret=False)
+
+
+def _bag(one, width, fused):
+    S, f32, i32 = jax.ShapeDtypeStruct, jnp.float32, jnp.int32
+    args = _on(one, (S((ROWS, 32), i32), S((ROWS, 32), f32),
+                     S((F, width), f32)))
+    fn = (pallas_embed.fm_terms_pallas if fused
+          else pallas_embed.embed_bag_pallas)
+    return fn.lower(*args, interpret=False)
+
+
+def case_kernel(lower):
+    def run(topo):
+        one = SingleDeviceSharding(topo.devices[0])
+        # width 128: the one the engine rule sends to Pallas on a TPU
+        compiled = lower(one, 128).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    return run
+
+
+def case_refused_width(width):
+    def run(topo):
+        one = SingleDeviceSharding(topo.devices[0])
+        # the kernels were not repaired for the widths the rule sends to
+        # XLA: pinning Pallas there hands them to Mosaic, whose reason
+        # reaches the caller
+        assert not pallas_embed.mosaic_row_dma_ok(width)
+        for lower in (lambda: _gather(one, width, False),
+                      lambda: _bag(one, width, True)):
+            with pytest.raises(Exception, match="aligned to tiling"):
+                lower().compile()
+    return run
+
+
+def case_decoder(meta):
+    def run(topo):
+        one = SingleDeviceSharding(topo.devices[0])
+        buf = jax.ShapeDtypeStruct((_fused_words_meta(ROWS, meta),),
+                                   jnp.int32, sharding=one)
+        # the program _get_unpack builds off-CPU: donated wire buffer
+        compiled = jax.jit(make_decoder(ROWS, meta),
+                           donate_argnums=(0,)).lower(buf).compile()
+        assert _hbm(compiled) < HBM_BYTES
+        assert "tpu_custom_call" not in compiled.as_text()
+    return run
+
+
+def case_train_step(kstep):
+    def run(topo):
+        one = SingleDeviceSharding(topo.devices[0])
+        model = FactorizationMachine(num_features=F, dim=D)
+        opt = optax.adam(1e-2)
+        params, opt_state = _on(one, _fm_state(model, opt))
+        if kstep == 1:
+            lowered = make_train_step(model, opt).lower(
+                params, opt_state, _on(one, _batch()))
+        else:
+            bufs = jax.ShapeDtypeStruct(
+                (kstep, _fused_words_meta(ROWS, V3_META)), jnp.int32,
+                sharding=one)
+            lowered = make_train_step_fused(
+                model, opt, rows=ROWS, meta=V3_META, k=kstep).lower(
+                    params, opt_state, bufs)
+        compiled = lowered.compile()
+        assert _hbm(compiled) < HBM_BYTES
+        # D=32: the flat layout's ops.csr path, no Pallas kernel inside
+        assert "tpu_custom_call" not in compiled.as_text()
+    return run
+
+
+def case_serving_bucket(ragged):
+    def run(topo):
+        from dmlc_core_tpu.serving import InferenceEngine
+        one = SingleDeviceSharding(topo.devices[0])
+        model = FactorizationMachine(num_features=F, dim=D)
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        engine = InferenceEngine(model, params, postprocess="sigmoid",
+                                 ragged=ragged)
+        bucket = engine.ladder.buckets[-1]
+        # _get_compiled's program, with the donation the chip turns on
+        compiled = jax.jit(engine._forward_fn(), donate_argnums=(1,)).lower(
+            _on(one, params), _on(one, engine._batch_avals(bucket))).compile()
+        assert _hbm(compiled) < HBM_BYTES
+        assert "tpu_custom_call" not in compiled.as_text()
+    return run
+
+
+def case_mesh_step(topo):
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "mp"))
+    model = FactorizationMachine(num_features=F, dim=D)
+    opt = optax.adam(1e-2)
+    params, _ = _fm_state(model, opt)
+    shardings = param_shardings(model, params, mesh)
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                      sharding=shardings[k])
+              for k, v in params.items()}
+    # adam's moments follow their parameter; the step count replicates
+    rep = NamedSharding(mesh, P())
+    opt_state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(opt.init, params))
+    opt_state = (opt_state[0]._replace(mu=params, nu=params),
+                 *opt_state[1:])
+    batch = _on(NamedSharding(mesh, P("dp")), _batch())
+    compiled = make_train_step(model, opt, mesh).lower(
+        params, opt_state, batch).compile()
+    assert "all-reduce" in compiled.as_text()
+    assert _hbm(compiled) < HBM_BYTES      # bytes on each device
+
+
+CASES = {
+    "gather_embed_128": case_kernel(lambda one, w: _gather(one, w, False)),
+    "gather_fm_128": case_kernel(lambda one, w: _gather(one, w, True)),
+    "embed_bag_128": case_kernel(lambda one, w: _bag(one, w, False)),
+    "fm_terms_128": case_kernel(lambda one, w: _bag(one, w, True)),
+    "refused_width_16": case_refused_width(16),
+    "refused_width_32": case_refused_width(32),
+    "refused_width_64": case_refused_width(64),
+    "decoder_v2_donated": case_decoder(V2_META),
+    "decoder_compact_donated": case_decoder(V3_META),
+    "fm_train_step_kstep1": case_train_step(1),
+    "fm_train_step_kstep8": case_train_step(8),
+    "serving_bucket_padded": case_serving_bucket(False),
+    "serving_bucket_ragged": case_serving_bucket(True),
+    "fm_mesh_dp2_mp2_step": case_mesh_step,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compiles_for_v5e(case, topo):
+    CASES[case](topo)
+
+
+@pytest.mark.parametrize("width", [16, 32, 64, 128, 256])
+def test_engine_rule_is_a_function_of_backend_and_width(width, monkeypatch):
+    """``engine="auto"`` never probes: on a TPU backend it is Pallas
+    exactly at the widths Mosaic lowers the row DMA for (ragged ops; the
+    embed-bag default stays XLA until a chip timing says otherwise), XLA
+    on every other backend, and a pin is passed through untouched.  Needs
+    no TPU compiler — the compiles above hold the rule to the compiler."""
+    monkeypatch.delenv("DMLC_RAGGED_ENGINE", raising=False)
+    monkeypatch.delenv("DMLC_EMBED_ENGINE", raising=False)
+    monkeypatch.delenv("DMLC_EMBED_AUTOTUNE", raising=False)
+    assert ragged_csr._resolve_engine("auto", width) == "xla"   # cpu here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    want = "pallas" if width % 128 == 0 else "xla"
+    assert pallas_embed.mosaic_row_dma_ok(width) == (want == "pallas")
+    assert ragged_csr._resolve_engine("auto", width) == want
+    assert pallas_embed._resolve_engine("auto", width) == "xla"
+    assert ragged_csr._resolve_engine("pallas", width) == "pallas"
+    assert pallas_embed._resolve_engine("pallas", width) == "pallas"
