@@ -33,7 +33,6 @@ import re
 import shutil
 import sys
 import tempfile
-import threading
 import time
 
 import numpy as np
@@ -73,41 +72,14 @@ def say(msg: str) -> None:
 # measurement helpers
 # ---------------------------------------------------------------------------
 
-class CompileMeter:
-    """Backend-compile seconds, program count and persistent-cache hits,
-    from JAX's own monitoring events (one listener per process)."""
-
-    _instance = None
-
-    def __init__(self) -> None:
-        import jax
-        self._lock = threading.Lock()
-        self.seconds = 0.0
-        self.programs = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    @classmethod
-    def get(cls) -> "CompileMeter":
-        if cls._instance is None:
-            cls._instance = cls()
-        return cls._instance
-
-    def _duration(self, event: str, duration: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            with self._lock:
-                self.seconds += duration
-                self.programs += 1
-
-    def _event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            with self._lock:
-                self.cache_hits += 1
-
-    def snapshot(self):
-        with self._lock:
-            return self.seconds, self.programs, self.cache_hits
+def compile_counts():
+    """(backend-compile seconds, programs, persistent-cache hits) so far:
+    the program's own counters, fed by the listeners that
+    ``enable_compile_cache`` installs."""
+    from dmlc_core_tpu.utils.metrics import metrics
+    return (metrics.histogram("xla.backend_compile_seconds").sum,
+            metrics.counter("xla.backend_compiles").value,
+            metrics.counter("xla.persistent_cache_hits").value)
 
 
 def peak_hbm() -> str:
@@ -124,12 +96,11 @@ def phase(name: str, facts: dict):
     (printed with the smoke label) and a ``show`` dict in ``facts``.  An
     exception in the body propagates — nothing here catches a failed
     phase."""
-    meter = CompileMeter.get()
-    c0, p0, h0 = meter.snapshot()
+    c0, p0, h0 = compile_counts()
     t0 = time.perf_counter()
     yield
     wall = time.perf_counter() - t0
-    c1, p1, h1 = meter.snapshot()
+    c1, p1, h1 = compile_counts()
     rate = facts.get("rate")
     say(f"[{name}] wall={wall:.2f}s compile={c1 - c0:.2f}s "
         f"({p1 - p0} programs, {h1 - h0} cache hits) peak_hbm={peak_hbm()}"
@@ -766,7 +737,7 @@ def main(argv=None) -> int:
             run_one_chip(work, REAL, args.seed)
     finally:
         shutil.rmtree(work, ignore_errors=True)
-    seconds, programs, hits = CompileMeter.get().snapshot()
+    seconds, programs, hits = compile_counts()
     say(f"[done] all phases passed in {time.perf_counter() - t0:.1f}s; "
         f"compile total={seconds:.2f}s ({programs} programs, {hits} from "
         f"the persistent cache)")

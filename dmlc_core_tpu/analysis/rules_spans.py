@@ -9,7 +9,8 @@ against them — yet nothing stopped a PR from opening a
 documented trace-topology diagram still referenced.  Mirrors
 ``metric-vocabulary``, both directions:
 
-* every **literal** name passed to ``span()`` / ``start_span()`` must
+* every **literal** name passed to ``span()`` / ``start_span()`` /
+  ``record_completed()`` must
   match the span grammar (lowercase dotted segments; single-segment
   names like ``reshard`` are legal for whole-subsystem spans);
 * every such name must be covered by a row in the span catalog of
@@ -35,7 +36,7 @@ from .core import (Finding, LintContext, LintRule, ParsedModule, lint_rule,
                    str_const)
 from .rules_metrics import _expand_braces
 
-_SPAN_FUNCS = {"span", "start_span"}
+_SPAN_FUNCS = {"span", "start_span", "record_completed"}
 _GRAMMAR = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)*$")
 #: doc-table token: looks like a (possibly braced/wildcarded) span name
 _DOC_TOKEN = re.compile(r"`([a-z][a-z0-9_{}<>,./]*)`")

@@ -94,14 +94,16 @@ class DCNv2:
             w, b = wb
             return x0 * (x @ w + b) + x, None
 
-        out, _ = jax.lax.scan(layer, x0, (cross["w"], cross["b"]))
+        with jax.named_scope("dcn_cross"):
+            out, _ = jax.lax.scan(layer, x0, (cross["w"], cross["b"]))
         return out
 
     def forward(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
         linear, x0 = self._embed(params, batch)
         xL = self._cross(params["cross"], x0)
-        return (params["w0"] + linear + xL @ params["head"]["w"]
-                + params["head"]["b"])
+        with jax.named_scope("dcn_head"):
+            return (params["w0"] + linear + xL @ params["head"]["w"]
+                    + params["head"]["b"])
 
     def loss(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
         return task_loss(self.forward(params, batch), batch, self.task,

@@ -62,12 +62,13 @@ def task_loss(out: jax.Array, batch: Dict[str, jax.Array], task: str,
               l2: float, *regs: jax.Array) -> jax.Array:
     """Shared loss tail of the factorization-model family: task dispatch
     (binary BCE / regression MSE) + l2 on the given parameter arrays."""
-    if task == "binary":
-        base = weighted_bce(out, batch["labels"], batch["weights"])
-    else:
-        base = weighted_mse(out, batch["labels"], batch["weights"])
-    if l2:
-        base = base + l2 * sum(jnp.sum(r ** 2) for r in regs)
+    with jax.named_scope("loss"):
+        if task == "binary":
+            base = weighted_bce(out, batch["labels"], batch["weights"])
+        else:
+            base = weighted_mse(out, batch["labels"], batch["weights"])
+        if l2:
+            base = base + l2 * sum(jnp.sum(r ** 2) for r in regs)
     return base
 
 

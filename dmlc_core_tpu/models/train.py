@@ -102,9 +102,13 @@ def _sgd_step(model, optimizer):
     """The ONE SGD update recipe every step builder closes over
     (per-step, wire-fused scan, and kbatch scan must never drift)."""
     def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(model.loss)(params, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        # the scopes name the step's three parts in every HLO ``op_name``
+        with jax.named_scope("loss_and_grad"):
+            loss, grads = jax.value_and_grad(model.loss)(params, batch)
+        with jax.named_scope("optimizer_update"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("apply_updates"):
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
     return step
 

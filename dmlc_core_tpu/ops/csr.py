@@ -14,6 +14,11 @@ jit-friendly: static shapes, no data-dependent control flow.
 The Pallas TPU kernel for the embedding-bag hot path lives in
 :mod:`dmlc_core_tpu.ops.pallas_embed`; these lax/XLA versions are the
 reference semantics and the CPU/interpret fallback.
+
+Every gather runs under ``jax.named_scope("csr_gather")`` and every row
+reduction under ``csr_segment_sum``: the names ride each HLO op's
+``op_name`` (the backward scatter carries ``transpose(jvp(csr_gather))``),
+so a device trace is read by them and not by ``fusion.N``.
 """
 
 from __future__ import annotations
@@ -28,8 +33,11 @@ def csr_dense_matvec(ids: jax.Array, vals: jax.Array, segments: jax.Array,
                      w: jax.Array, num_rows: int) -> jax.Array:
     """Per-row sparse dot with a dense vector: out[r] = Σ vals[i]·w[ids[i]]
     over i with segments[i]==r.  Padding entries must carry vals==0."""
-    picked = w[ids] * vals
-    return jax.ops.segment_sum(picked, segments, num_segments=num_rows + 1)[:num_rows]
+    with jax.named_scope("csr_gather"):
+        picked = w[ids] * vals
+    with jax.named_scope("csr_segment_sum"):
+        return jax.ops.segment_sum(picked, segments,
+                                   num_segments=num_rows + 1)[:num_rows]
 
 
 def csr_embed_sum(ids: jax.Array, vals: jax.Array, segments: jax.Array,
@@ -38,9 +46,11 @@ def csr_embed_sum(ids: jax.Array, vals: jax.Array, segments: jax.Array,
 
     ``table``: [num_features, dim].  Output [num_rows, dim].
     """
-    gathered = table[ids] * vals[:, None]
-    return jax.ops.segment_sum(gathered, segments,
-                               num_segments=num_rows + 1)[:num_rows]
+    with jax.named_scope("csr_gather"):
+        gathered = table[ids] * vals[:, None]
+    with jax.named_scope("csr_segment_sum"):
+        return jax.ops.segment_sum(gathered, segments,
+                                   num_segments=num_rows + 1)[:num_rows]
 
 
 def fm_pairwise(ids: jax.Array, vals: jax.Array, segments: jax.Array,
@@ -50,8 +60,11 @@ def fm_pairwise(ids: jax.Array, vals: jax.Array, segments: jax.Array,
 
     Uses the classic O(nnz·d) identity; both segment sums fuse into one pass
     under XLA.  Returns [num_rows]."""
-    vx = table[ids] * vals[:, None]                    # [nnz, d]
-    s1 = jax.ops.segment_sum(vx, segments, num_segments=num_rows + 1)[:num_rows]
-    s2 = jax.ops.segment_sum(vx * vx, segments,
-                             num_segments=num_rows + 1)[:num_rows]
+    with jax.named_scope("csr_gather"):
+        vx = table[ids] * vals[:, None]                    # [nnz, d]
+    with jax.named_scope("csr_segment_sum"):
+        s1 = jax.ops.segment_sum(vx, segments,
+                                 num_segments=num_rows + 1)[:num_rows]
+        s2 = jax.ops.segment_sum(vx * vx, segments,
+                                 num_segments=num_rows + 1)[:num_rows]
     return 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)

@@ -33,7 +33,6 @@ byte range).
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
 from typing import Dict, Iterator, Optional
 
@@ -109,43 +108,46 @@ def make_decoder(rows: int, meta: int):
     nnz, w, dbits = _decode_meta(meta)
 
     def _unpack(b, segs=None):
+        # scopes only name the HLO ops; the function stays ``_unpack``, so
+        # the compiled program stays ``jit__unpack``
+        with jax.named_scope("wire_decode"):
             f32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.float32)  # noqa: E731
             u32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.uint32)  # noqa: E731
-            if w == 0:  # v2: raw int32 ids, raw f32 vals
-                ids = b[:nnz]
-                vals = f32(b[nnz:2 * nnz])
-                voff = 2 * nnz
-            else:  # v3: bit-packed ids (and codes)
-                def unpack_bits(region, width):
-                    pu = u32(region)
-                    i = jnp.arange(nnz, dtype=jnp.uint32)
-                    bitpos = i * jnp.uint32(width)
-                    word = (bitpos >> 5).astype(jnp.int32)
-                    off = bitpos & jnp.uint32(31)
-                    lo = pu[word] >> off
-                    hi = pu[jnp.minimum(word + 1, len(region) - 1)] << (
-                        jnp.where(off > 0, jnp.uint32(32) - off,
-                                  jnp.uint32(0)))
-                    hi = jnp.where(off > 0, hi, jnp.uint32(0))
-                    mask = jnp.uint32(
-                        0xFFFFFFFF if width >= 32 else (1 << width) - 1)
-                    return ((lo | hi) & mask).astype(jnp.int32)
 
-                iw = (nnz * w + 31) // 32
-                ids = unpack_bits(b[:iw], w)
-                if dbits:  # dict-coded values: dbits-wide codes + gather
+            def unpack_bits(region, width):
+                pu = u32(region)
+                i = jnp.arange(nnz, dtype=jnp.uint32)
+                bitpos = i * jnp.uint32(width)
+                word = (bitpos >> 5).astype(jnp.int32)
+                off = bitpos & jnp.uint32(31)
+                lo = pu[word] >> off
+                hi = pu[jnp.minimum(word + 1, len(region) - 1)] << (
+                    jnp.where(off > 0, jnp.uint32(32) - off,
+                              jnp.uint32(0)))
+                hi = jnp.where(off > 0, hi, jnp.uint32(0))
+                mask = jnp.uint32(
+                    0xFFFFFFFF if width >= 32 else (1 << width) - 1)
+                return ((lo | hi) & mask).astype(jnp.int32)
+
+            iw = nnz if w == 0 else (nnz * w + 31) // 32
+            with jax.named_scope("ids"):
+                # v2: raw int32 ids; v3: w-bit packed
+                ids = b[:nnz] if w == 0 else unpack_bits(b[:iw], w)
+            with jax.named_scope("vals"):
+                if w and dbits:  # dict-coded values: dbits-wide codes + gather
                     cw = (nnz * dbits + 31) // 32
                     dw = 1 << dbits
                     codes = unpack_bits(b[iw:iw + cw], dbits)
                     vals = f32(b[iw + cw:iw + cw + dw])[codes]
                     voff = iw + cw + dw
-                else:  # raw f32 fallback
+                else:  # raw f32 (v2, and v3's fallback)
                     vals = f32(b[iw:iw + nnz])
                     voff = iw + nnz
             rp = b[voff:voff + rows + 1]
-            segments = segs if segs is not None else jnp.searchsorted(
-                rp[1:], jnp.arange(nnz, dtype=jnp.int32),
-                side="right").astype(jnp.int32)
+            with jax.named_scope("segments"):
+                segments = segs if segs is not None else jnp.searchsorted(
+                    rp[1:], jnp.arange(nnz, dtype=jnp.int32),
+                    side="right").astype(jnp.int32)
             return {
                 "ids": ids,
                 "vals": vals,
@@ -504,6 +506,9 @@ class DeviceLoader:
         self._pool = _BufPool(cap=2 * depth + 2)
         self._inflight: deque = deque()
         self._inflight_depth = depth
+        # before a stage thread exists: the consumer, the pack thread and
+        # the transfer thread all read these handles
+        self._bind_metrics()
         # stage 1: parse+pack in its own thread → bounded host-buffer queue
         self._pack_iter: ThreadedIter = ThreadedIter(max_capacity=depth)
         self._pack_iter.init(self._pack_factory(), self._reset_source)
@@ -784,24 +789,27 @@ class DeviceLoader:
             for piece in pieces:
                 yield self._pack_host_ragged(piece)
 
+    def _pack_span(self, stall=None, **attrs):
+        """``device_loader.pack`` on every pack path: one span that is also
+        the stage total, in the trace of the thread that built the loader.
+        The python packers, whose every call packs one batch, also hand
+        the duration to the stall detector; a native generator's ``next``
+        is either a batch or the end of a block, two populations that one
+        z-score would read as stalls."""
+        return teltrace.span("device_loader.pack", stage=self._m_pack,
+                             stall=stall, parent=self._trace, **attrs)
+
     def _pack_host_ragged(self, block):
-        t0 = time.monotonic()
-        with teltrace.activate(self._trace), \
-                teltrace.span("device_loader.pack", rows=block.size,
-                              ragged=True), self._m_pack.time():
+        with self._pack_span(self._stall_pack, rows=block.size, ragged=True):
             host = pack_ragged(block, self.batch_rows, self.nnz_cap,
                                self.stats, id_mod=self.id_mod,
                                want_fields=self.fields)
             host["_rows"] = block.size
-        self._stall_pack.observe(time.monotonic() - t0)
         return ("arrays", host)
 
     def _pack_host(self, block, fused: bool):
-        t0 = time.monotonic()
-        with teltrace.activate(self._trace), \
-                teltrace.span("device_loader.pack",
-                              rows=getattr(block, "size", self.batch_rows)), \
-                self._m_pack.time():
+        with self._pack_span(self._stall_pack,
+                             rows=getattr(block, "size", self.batch_rows)):
             if self.layout == "flat":
                 host = pack_flat(block, self.batch_rows, self.nnz_cap,
                                  self.stats, id_mod=self.id_mod,
@@ -816,9 +824,7 @@ class DeviceLoader:
                 buf = _host_fused(host, self.batch_rows, self.nnz_cap,
                                   out=self._pool.get(
                                       fused_words(self.batch_rows, self.nnz_cap)))
-                self._stall_pack.observe(time.monotonic() - t0)
                 return ("fused", buf, self.nnz_cap, host["_rows"])
-        self._stall_pack.observe(time.monotonic() - t0)
         return ("arrays", host)
 
     def _host_items_streampack(self) -> Iterator:
@@ -852,7 +858,7 @@ class DeviceLoader:
                 gen = sp.feed_text(chunk, get_buf=self._pool.get,
                                    put_buf=self._pool.put)
                 while True:
-                    with self._m_pack.time():
+                    with self._pack_span():
                         item = next(gen, None)
                     if item is None:
                         break
@@ -886,7 +892,7 @@ class DeviceLoader:
                 gen = packer.feed(blk, get_buf=self._pool.get,
                                   put_buf=self._pool.put)
                 while True:
-                    with self._m_pack.time():
+                    with self._pack_span():
                         item = next(gen, None)
                     if item is None:
                         break
@@ -942,20 +948,26 @@ class DeviceLoader:
         immediately — concurrency comes from the pool's threads, and the
         ring (not thread-safe) stays unused."""
         self._maybe_bind()
-        t0 = time.monotonic()
-        # pool mode times under its own stage: K workers accumulate
-        # overlapping seconds, which must not be read as serial h2d time
-        with teltrace.activate(self._trace), \
-                teltrace.span("device_loader.h2d", sync=sync), \
-                (self._m_h2d_pool if sync else self._m_h2d).time():
+        # pool mode times under its own name: K workers accumulate
+        # overlapping seconds, which must not be read as serial h2d time.
+        # Its children say what the stage was doing: ``put`` is the host
+        # issuing the transfer and dispatching the decode, ``ring_wait`` /
+        # ``pool_wait`` is the feed blocked behind the chip.
+        with (teltrace.span("device_loader.h2d_pool",
+                            stage=self._m_h2d_pool, stall=self._stall_h2d,
+                            parent=self._trace, sync=True) if sync else
+              teltrace.span("device_loader.h2d",
+                            stage=self._m_h2d, stall=self._stall_h2d,
+                            parent=self._trace, sync=False)):
             if item[0] == "fused":
                 _, buf, nnz, rows_real = item
-                out = _put_fused_buf(buf, self.batch_rows, nnz)
+                with teltrace.span("device_loader.put", stage=self._m_put):
+                    out = _put_fused_buf(buf, self.batch_rows, nnz)
                 # wait on the WHOLE batch before recycling: the CPU direct
                 # path issues independent per-array puts, so readiness of
                 # one leaf doesn't imply the others have copied the buffer
                 if sync:
-                    jax.block_until_ready(out)
+                    self._pool_wait(out)
                     self._pool.put(buf)
                 else:
                     self._ring_push(out, buf)
@@ -967,11 +979,11 @@ class DeviceLoader:
                 host.pop("row_ptr", None)
                 # sharded arrays lead with the batch/nnz axis: one sharding
                 # fits each; fusing would mix axes, so transfer per-array
-                out = {k: jax.device_put(v, self.sharding)
-                       for k, v in host.items()}
+                with teltrace.span("device_loader.put", stage=self._m_put):
+                    out = {k: jax.device_put(v, self.sharding)
+                           for k, v in host.items()}
                 if sync:
-                    jax.block_until_ready(out)
-        self._stall_h2d.observe(time.monotonic() - t0)
+                    self._pool_wait(out)
         self._m_batches.add(1)
         if rows_real is not None:
             self._m_rows.add(rows_real)
@@ -985,14 +997,24 @@ class DeviceLoader:
         self._inflight.append((leaf, buf))
         while len(self._inflight) > self._inflight_depth:
             old_leaf, old_buf = self._inflight.popleft()
-            jax.block_until_ready(old_leaf)
+            self._ring_wait(old_leaf)
             self._pool.put(old_buf)
+
+    def _ring_wait(self, leaf) -> None:
+        with teltrace.span("device_loader.ring_wait",
+                           stage=self._m_ring_wait):
+            jax.block_until_ready(leaf)
+
+    def _pool_wait(self, leaf) -> None:
+        with teltrace.span("device_loader.pool_wait",
+                           stage=self._m_pool_wait):
+            jax.block_until_ready(leaf)
 
     def _drain_inflight(self) -> None:
         while self._inflight:
             leaf, buf = self._inflight.popleft()
             try:
-                jax.block_until_ready(leaf)
+                self._ring_wait(leaf)
             except Exception:
                 pass
             self._pool.put(buf)
@@ -1016,10 +1038,14 @@ class DeviceLoader:
             from ..telemetry.anomaly import StallDetector
             self._stall_pack = StallDetector("device_loader.pack")
             self._stall_h2d = StallDetector("device_loader.h2d")
-        self._m_gen = metrics.generation
+        generation = metrics.generation
         self._m_pack = metrics.stage("device_loader.pack")
         self._m_h2d = metrics.stage("device_loader.h2d")
         self._m_h2d_pool = metrics.stage("device_loader.h2d_pool")
+        self._m_put = metrics.stage("device_loader.put")
+        self._m_ring_wait = metrics.stage("device_loader.ring_wait")
+        self._m_pool_wait = metrics.stage("device_loader.pool_wait")
+        self._m_next_batch = metrics.stage("device_loader.next_batch")
         self._m_batches = metrics.counter("device_loader.batches")
         self._m_rows = metrics.throughput("device_loader.rows")
         self._m_cache_read = metrics.stage("device_loader.cache_read")
@@ -1030,6 +1056,8 @@ class DeviceLoader:
         self._m_cache_bytes_read = metrics.counter("page_cache.bytes_read")
         self._m_cache_bytes_written = metrics.counter(
             "page_cache.bytes_written")
+        # last: a thread that sees the generation may use every handle
+        self._m_gen = generation
 
     # -- consumer side --
     def __iter__(self):
@@ -1040,7 +1068,15 @@ class DeviceLoader:
             yield b
 
     def next_batch(self) -> Optional[Dict[str, jax.Array]]:
-        return self._iter.next()
+        """The next batch, or None at the end of an epoch.  Its span is how
+        long the caller waited for the feed; ``got`` is false for the
+        None."""
+        self._maybe_bind()
+        with teltrace.span("device_loader.next_batch",
+                           stage=self._m_next_batch) as s:
+            batch = self._iter.next()
+            s.attrs["got"] = batch is not None
+        return batch
 
     def before_first(self) -> None:
         self._iter.before_first()

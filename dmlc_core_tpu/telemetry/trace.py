@@ -21,6 +21,9 @@ Usage::
     with span("serving.client.predict", rows=4):        # scoped span
         ...                                             # children nest
 
+    with span("device_loader.pack", stage=timer, stall=detector):
+        ...     # one clock pair: the record, the stage total, the detector
+
     s = start_span("serving.server.request", parent=ctx)  # manual span
     ...                                                   # (async paths)
     s.end(status="OK")
@@ -40,12 +43,13 @@ import time
 from collections import deque
 from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Union
 
-from ..utils.metrics import metrics
+from ..utils.metrics import metrics, profiler_annotation
 from ..utils.parameter import get_env
 
 __all__ = [
     "TraceContext", "Span", "SpanRecorder", "recorder", "current",
-    "current_trace_id", "new_trace_id", "start_span", "span", "activate",
+    "current_trace_id", "new_trace_id", "start_span", "span",
+    "record_completed", "activate",
     "add_event", "format_id", "wire_ids", "from_wire", "set_sampler",
     "get_sampler",
 ]
@@ -198,8 +202,8 @@ class Span:
     paths may race a cleanup path)."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "events", "_t0_wall", "_t0_mono", "_tid", "_thread",
-                 "_ended")
+                 "events", "dur_s", "_t0_wall", "_t0_mono", "_tid",
+                 "_thread", "_ended")
 
     def __init__(self, name: str, trace_id: int, span_id: int,
                  parent_id: Optional[int], attrs: Dict[str, Any]) -> None:
@@ -209,6 +213,8 @@ class Span:
         self.parent_id = parent_id
         self.attrs = attrs
         self.events: List[Dict[str, Any]] = []
+        #: seconds from start to :meth:`end`, on ``time.monotonic``
+        self.dur_s = 0.0
         self._t0_wall = time.time()
         self._t0_mono = time.monotonic()
         t = threading.current_thread()
@@ -236,6 +242,7 @@ class Span:
         self._ended = True
         if attrs:
             self.attrs.update(attrs)
+        self.dur_s = max(0.0, time.monotonic() - self._t0_mono)
         rec = {
             "kind": "span",
             "name": self.name,
@@ -244,7 +251,10 @@ class Span:
             "parent_id": (format_id(self.parent_id)
                           if self.parent_id else None),
             "ts_us": int(self._t0_wall * 1e6),
-            "dur_us": max(0, int((time.monotonic() - self._t0_mono) * 1e6)),
+            # the start on the clock ``dur_us`` was taken from: in-process
+            # arithmetic between records uses this, never the wall clock
+            "mono_us": int(self._t0_mono * 1e6),
+            "dur_us": int(self.dur_s * 1e6),
             "pid": os.getpid(),
             "tid": self._tid,
             "thread": self._thread,
@@ -292,26 +302,50 @@ def start_span(name: str, parent: Optional[TraceContext] = None,
 
 
 @contextlib.contextmanager
-def span(name: str, **attrs: Any) -> Iterator[Span]:
-    """Scoped span: child of the ambient context, active for the block,
-    ended on exit (exceptions recorded as ``error`` before re-raising)."""
-    s = start_span(name, **attrs)
-    token = _current.set(s)
-    try:
-        yield s
-    except BaseException as e:
-        s.end(error=f"{type(e).__name__}: {e}")
-        raise
-    finally:
+def span(name: str, stage: Any = None, stall: Any = None,
+         **attrs: Any) -> Iterator[Span]:
+    """Scoped span: child of the ambient context (or of ``parent=``),
+    active for the block, ended on exit (exceptions recorded as ``error``
+    before re-raising).
+
+    For its whole extent it is also a ``jax.profiler.TraceAnnotation`` of
+    the same name, so it lies on the host plane of any running profile, on
+    the device trace's clock.  The one duration the record carries is also
+    added to ``stage`` (a ``StageTimer``) and handed to ``stall`` (a
+    ``StallDetector``) when the call site passes them: one pair of clock
+    reads, three sinks."""
+    with profiler_annotation(name):
+        s = start_span(name, **attrs)
+        token = _current.set(s)
         try:
-            _current.reset(token)
-        except ValueError:
-            # a span opened inside a generator dies wherever the
-            # generator is finalized: GC can close an abandoned iterator
-            # from another thread's context, where this token is foreign.
-            # The span still ends; only the ambient-context pop is moot.
-            pass
-        s.end()
+            yield s
+        except BaseException as e:
+            s.end(error=f"{type(e).__name__}: {e}")
+            raise
+        finally:
+            try:
+                _current.reset(token)
+            except ValueError:
+                # a span opened inside a generator dies wherever the
+                # generator is finalized: GC can close an abandoned
+                # iterator from another thread's context, where this token
+                # is foreign.  The span still ends; only the
+                # ambient-context pop is moot.
+                pass
+            s.end()
+            if stage is not None:
+                stage.add(s.dur_s)
+            if stall is not None:
+                stall.observe(s.dur_s)
+
+
+def record_completed(name: str, dur_s: float, **attrs: Any) -> None:
+    """Record a span that ended just now and took ``dur_s``: for work timed
+    by someone else (JAX reports a compile's duration once it is over)."""
+    s = start_span(name, **attrs)
+    s._t0_wall -= dur_s
+    s._t0_mono -= dur_s
+    s.end()
 
 
 @contextlib.contextmanager

@@ -20,6 +20,15 @@ not microseconds) with nothing in the metrics naming the culprit.
   noted, since they are the adjacent failure mode with the same
   operator response (extend the ladder).
 
+The watchdog sees only what the serving engine tells it.
+:func:`install_compile_listeners` hears every backend compile of the
+process from JAX itself (``jax.monitoring``): ``xla.backend_compiles`` and
+``xla.persistent_cache_hits`` (counters), ``xla.backend_compile_seconds``
+(histogram), and one ``xla.backend_compile`` span record per compile, so
+"did anything compile while this ran" is a question for the span ring.
+``utils.compile_cache.enable_compile_cache`` — which every entry point
+calls before its first compile — installs them.
+
 Alerts bump ``xla.retrace_alerts``, latch the ``xla.retrace_alert``
 gauge, and leave a note in the flight recorder (via ``sys.modules`` —
 this module never imports ``flight``).
@@ -39,8 +48,10 @@ from typing import Any, Dict, Optional
 
 from ..utils.logging import log_warning
 from ..utils.metrics import MetricsRegistry, metrics
+from . import trace
 
-__all__ = ["RetraceWatchdog", "watchdog", "sample_memory"]
+__all__ = ["RetraceWatchdog", "watchdog", "sample_memory",
+           "install_compile_listeners"]
 
 
 def _flight_mod():
@@ -149,6 +160,39 @@ class RetraceWatchdog:
 
 #: process-global watchdog (the serving engine feeds it)
 watchdog = RetraceWatchdog()
+
+# JAX keeps a listener for the life of the process and cannot drop one, so
+# "installed" is a fact about the process
+_listeners_installed = False
+_listeners_lock = threading.Lock()
+
+
+def _on_duration(event: str, duration: float, **_kw: Any) -> None:
+    # fires once the compile (or the load from the persistent cache) is
+    # over: the record is placed at (now - duration, duration)
+    if event == "/jax/core/compile/backend_compile_duration":
+        metrics.counter("xla.backend_compiles").add(1)
+        metrics.histogram("xla.backend_compile_seconds").observe(duration)
+        trace.record_completed("xla.backend_compile", duration)
+
+
+def _on_event(event: str, **_kw: Any) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        metrics.counter("xla.persistent_cache_hits").add(1)
+
+
+def install_compile_listeners() -> None:
+    """Count and record every backend compile of this process (see the
+    module doc).  Idempotent."""
+    global _listeners_installed
+    import jax.monitoring
+    with _listeners_lock:
+        if _listeners_installed:
+            return
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
+        _listeners_installed = True
+
 
 _mem_warned = False
 

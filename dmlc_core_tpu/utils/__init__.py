@@ -24,7 +24,7 @@ from .checkpoint import (  # noqa: F401
 from .orbax_compat import save_orbax, restore_orbax  # noqa: F401
 from .metrics import (  # noqa: F401
     Counter, Gauge, Histogram, ThroughputMeter, StageTimer, MetricsRegistry,
-    metrics, trace_span, profile_trace,
+    metrics,
 )
 from .retry import (  # noqa: F401
     Deadline, DeadlineExpired, RetryPolicy, RetriesExhausted,

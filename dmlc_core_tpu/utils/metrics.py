@@ -17,12 +17,10 @@ first-class and queryable:
   as a context manager or decorator; exposes count/total/mean.
 * :class:`MetricsRegistry` — process-global named registry with
   ``snapshot()`` (one dict, JSON-serializable) and ``report()`` logging.
-* :func:`trace_span` — context manager emitting a ``jax.profiler``
-  TraceAnnotation when JAX is importable (shows up on the TPU trace
-  timeline), and a no-op otherwise; the idiomatic replacement for the
-  reference's printf timing.
-* :func:`profile_trace` — wrap a block in ``jax.profiler``
-  start_trace/stop_trace for offline TensorBoard inspection.
+* :func:`profiler_annotation` — the ``jax.profiler.TraceAnnotation`` that
+  :meth:`StageTimer.time` and ``telemetry.trace.span`` hold for their
+  extent, so every timed stage shows on the host plane of a profile, on
+  the device trace's clock.
 """
 
 from __future__ import annotations
@@ -39,7 +37,7 @@ from .logging import log_info
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "ThroughputMeter", "StageTimer",
-    "MetricsRegistry", "metrics", "trace_span", "profile_trace",
+    "MetricsRegistry", "metrics", "profiler_annotation",
 ]
 
 
@@ -418,6 +416,28 @@ class ThroughputMeter:
                                      for s in states)}
 
 
+# jax.profiler, resolved once per process; False caches a failed import
+_profiler_mod: Any = None
+
+
+def profiler_annotation(name: Optional[str]) -> Any:
+    """A ``jax.profiler.TraceAnnotation(name)`` to hold with ``with``; a
+    null context for no name, and in a process that has not imported JAX:
+    no profile can be running there, and a timer must not be what drags
+    JAX in.  With no profile running, entering one costs well under a
+    microsecond."""
+    global _profiler_mod
+    prof = _profiler_mod
+    if prof is None and name and "jax" in sys.modules:
+        try:
+            import jax.profiler as prof
+        except Exception:
+            prof = False
+        _profiler_mod = prof
+    return prof.TraceAnnotation(name) if prof and name \
+        else contextlib.nullcontext()
+
+
 class StageTimer:
     """Accumulated wall time for one pipeline stage.
 
@@ -428,24 +448,32 @@ class StageTimer:
 
     or decorate a function with the timer itself
     (``@metrics.stage("parse")``). Reports count / total / mean seconds.
+    A timer the registry made knows its ``name`` and holds a profiler
+    annotation of that name for each timed block.
     """
 
-    def __init__(self, clock: Callable[[], float] = time.monotonic) -> None:
+    def __init__(self, clock: Callable[[], float] = time.monotonic,
+                 name: Optional[str] = None) -> None:
         self._clock = clock
+        self.name = name
         self._count = 0
         self._total = 0.0
         self._lock = threading.Lock()
 
+    def add(self, seconds: float) -> None:
+        """Account one block that the caller timed itself."""
+        with self._lock:
+            self._count += 1
+            self._total += seconds
+
     @contextlib.contextmanager
     def time(self) -> Iterator[None]:
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            dt = self._clock() - t0
-            with self._lock:
-                self._count += 1
-                self._total += dt
+        with profiler_annotation(self.name):
+            t0 = self._clock()
+            try:
+                yield
+            finally:
+                self.add(self._clock() - t0)
 
     def __call__(self, fn: Callable) -> Callable:
         def wrapped(*a, **kw):
@@ -496,12 +524,12 @@ class MetricsRegistry:
         #: this (one int read, no lock) and re-fetch when it changes
         self.generation = 0
 
-    def _get(self, name: str, cls, **kw):
+    def _get(self, key: str, cls, **kw):
         with self._lock:
-            m = self._m.get(name)
+            m = self._m.get(key)
             if m is None:
                 m = cls(**kw)
-                self._m[name] = m
+                self._m[key] = m
             return m
 
     def counter(self, name: str) -> Counter:
@@ -517,7 +545,7 @@ class MetricsRegistry:
         return self._get(name, ThroughputMeter, window_sec=window_sec)
 
     def stage(self, name: str) -> StageTimer:
-        return self._get(name, StageTimer)
+        return self._get(name, StageTimer, name=name)
 
     def snapshot(self) -> Dict[str, Dict[str, Any]]:
         with self._lock:
@@ -547,48 +575,3 @@ class MetricsRegistry:
 
 #: process-global registry (modules grab sub-metrics by name)
 metrics = MetricsRegistry()
-
-
-# jax.profiler resolved once at first trace_span() use; False caches the
-# negative case so a JAX-less process pays the failed import exactly once
-_profiler_mod: Any = None
-
-
-def _resolve_profiler() -> Any:
-    global _profiler_mod
-    if _profiler_mod is None:
-        try:
-            import jax.profiler as _prof
-            _profiler_mod = _prof
-        except Exception:
-            _profiler_mod = False
-    return _profiler_mod or None
-
-
-@contextlib.contextmanager
-def trace_span(name: str) -> Iterator[None]:
-    """Annotate a host-side span on the jax.profiler timeline; no-op when
-    JAX is unavailable. The idiomatic upgrade of printf timing (SURVEY §5)."""
-    ann = None
-    prof = _resolve_profiler()
-    if prof is not None:
-        try:
-            ann = prof.TraceAnnotation(name)
-        except Exception:
-            pass
-    if ann is None:
-        yield
-        return
-    with ann:
-        yield
-
-
-@contextlib.contextmanager
-def profile_trace(log_dir: str) -> Iterator[None]:
-    """Capture a jax.profiler trace (view in TensorBoard / Perfetto)."""
-    import jax.profiler as _prof
-    _prof.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        _prof.stop_trace()

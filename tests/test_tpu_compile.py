@@ -65,6 +65,17 @@ def _hbm(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
+def _names_scopes(compiled, scopes):
+    """The TPU compiler keeps every ``jax.named_scope`` of the program in
+    some instruction's ``op_name`` (a fusion reads its root's), so a device
+    trace is read by these names and not by ``fusion.N``."""
+    import re
+    op_names = set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+    for scope in scopes:
+        at = re.compile(r"(?:^|[/(])" + re.escape(scope) + r"(?:[/)]|$)")
+        assert any(at.search(n) for n in op_names), (scope, sorted(op_names))
+
+
 def _batch(rows=ROWS, nnz=NNZ):
     S, f32, i32 = jax.ShapeDtypeStruct, jnp.float32, jnp.int32
     return {"ids": S((nnz,), i32), "vals": S((nnz,), f32),
@@ -127,6 +138,9 @@ def case_decoder(meta):
                            donate_argnums=(0,)).lower(buf).compile()
         assert _hbm(compiled) < HBM_BYTES
         assert "tpu_custom_call" not in compiled.as_text()
+        assert "HloModule jit__unpack" in compiled.as_text()
+        _names_scopes(compiled, ["wire_decode/ids", "wire_decode/vals",
+                                 "wire_decode/segments"])
     return run
 
 
@@ -150,6 +164,10 @@ def case_train_step(kstep):
         assert _hbm(compiled) < HBM_BYTES
         # D=32: the flat layout's ops.csr path, no Pallas kernel inside
         assert "tpu_custom_call" not in compiled.as_text()
+        _names_scopes(compiled, [
+            "loss_and_grad", "optimizer_update", "apply_updates", "csr_gather",
+            "csr_segment_sum", "loss", "transpose(jvp(csr_gather))"]
+            + (["wire_decode/segments"] if kstep > 1 else []))
     return run
 
 
