@@ -710,8 +710,8 @@ int parse_parallel(const char* data, int64_t len, bool want_fields, int nthreads
 // _put_fused_buf).  v2 layout — row_ptr instead of per-value segments, and
 // the nnz region sized to the *actual* values rounded up to `quantum`
 // (bucket B), so a rows-limited batch ships ~half the bytes of the padded
-// v1 layout and the per-value segment ids are reconstructed on device with
-// one searchsorted (free next to the transfer):
+// v1 layout and the per-value segment ids are rebuilt on device from
+// row_ptr (a 1 scattered at every row's end, then one prefix sum):
 //   [0,        B)            ids      int32   (pad 0)
 //   [B,        2B)           vals     f32 bits (pad 0.0 -> scratch row)
 //   [2B,       2B+rows+1)    row_ptr  int32   (pad rows repeat nnz)

@@ -19,10 +19,11 @@ the steady state allocates nothing (the reference's recycling free list,
 
 The fused buffer uses the v2 layout (``ids[B]|vals[B]|row_ptr|labels|
 weights``, B = actual nnz rounded up to a bucket): one int32 transfer per
-batch sized to the data, with per-value ``segments`` reconstructed on device
-by a single ``searchsorted`` over ``row_ptr`` — 4·B bytes cheaper on the
-wire than shipping segments, which matters because host→device bandwidth is
-the pipeline's narrowest link.
+batch sized to the data, with per-value ``segments`` rebuilt on device from
+``row_ptr`` — 4·B bytes cheaper on the wire than shipping segments.  The
+rebuild is search-free: a 1 scattered at every row's end, then one prefix
+sum (0.04 ms a 4096 × 163840 batch on a v5e; a binary search over
+``row_ptr`` is a ``while`` of scalar gathers there, 15 ms — PERF.md, PR 29).
 
 With a sharding whose mesh spans multiple devices, ``device_put`` scatters
 the batch across them (data-parallel input sharding ≙ the reference's
@@ -79,9 +80,9 @@ def _host_segments(view: np.ndarray, rows: int, nnz: int,
                    words: int) -> np.ndarray:
     """Per-value row ids computed host-side from the buffer's row_ptr
     region (pad → ``rows`` scratch row, same contract as the on-device
-    searchsorted).  Used on the CPU backend, where "on-device" searchsorted
-    would run on the host core anyway — at ~50× the cost of np.repeat
-    (measured 16.9ms vs 0.3ms per 393k-value batch)."""
+    rebuild).  Used on the CPU backend, where the "on-device" rebuild would
+    run on the host core anyway and np.repeat does it in 0.3 ms per
+    393k-value batch."""
     voff = words - 3 * rows - 1
     rp = view[voff:voff + rows + 1]
     seg = np.full(nnz, rows, np.int32)
@@ -97,8 +98,8 @@ def make_decoder(rows: int, meta: int):
     are w-bit unpacked with two gathers + shifts, values decode through the
     shipped dictionary (u16 code gather) — both pure VPU work that rides
     along with the transfer.  ``segments`` (row id per value, padding →
-    ``rows`` scratch row — same contract as ops.csr) come from one
-    searchsorted over ``row_ptr`` unless precomputed host-side.
+    ``rows`` scratch row — same contract as ops.csr) are a prefix sum over
+    the row ends scattered from ``row_ptr`` unless precomputed host-side.
 
     Shared by the per-batch jitted unpack (:func:`_get_unpack`) and the
     k-step fused trainer (models.train.make_train_step_fused), which calls
@@ -145,9 +146,17 @@ def make_decoder(rows: int, meta: int):
                     voff = iw + nnz
             rp = b[voff:voff + rows + 1]
             with jax.named_scope("segments"):
-                segments = segs if segs is not None else jnp.searchsorted(
-                    rp[1:], jnp.arange(nnz, dtype=jnp.int32),
-                    side="right").astype(jnp.int32)
+                if segs is not None:
+                    segments = segs
+                else:
+                    # segments[i] = #{r >= 1: row_ptr[r] <= i}: count the
+                    # rows that end at each position, then a prefix sum.
+                    # row_ptr is non-decreasing and its padding rows repeat
+                    # the batch's nnz, so padding reads ``rows``; an end at
+                    # ``nnz`` itself is out of range and dropped.
+                    ends = jnp.zeros(nnz, jnp.int32).at[rp[1:]].add(
+                        1, mode="drop", indices_are_sorted=True)
+                    segments = jnp.cumsum(ends, dtype=jnp.int32)
             return {
                 "ids": ids,
                 "vals": vals,

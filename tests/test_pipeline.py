@@ -198,8 +198,8 @@ def test_device_loader_rowmajor_layout(libsvm_file):
 
 def test_fused_h2d_matches_per_array(tmp_path):
     """The single-transfer fused path (v2 layout: row_ptr shipped, segments
-    reconstructed on device by searchsorted) must produce bitwise-identical
-    batch contents to the packed host arrays."""
+    rebuilt on device from it; host-side on the CPU backend) must produce
+    bitwise-identical batch contents to the packed host arrays."""
     import numpy as np
     from dmlc_core_tpu.pipeline.device_loader import _fused_put
     rows, nnz = 64, 256
@@ -214,6 +214,94 @@ def test_fused_h2d_matches_per_array(tmp_path):
     for k, v in host.items():
         np.testing.assert_array_equal(np.asarray(fused[k]), v, err_msg=k)
         assert fused[k].dtype == v.dtype, k
+
+
+_DEC_ROWS, _DEC_NNZ = 64, 256
+
+
+def _decoder_rows(case):
+    """Row specs (label, ids, vals) whose row_ptr has the named shape."""
+    rng = np.random.default_rng(5)
+
+    def row(i, n):
+        idx = sorted(rng.choice(1000, n, replace=False).tolist())
+        return (float(i % 2), idx, rng.random(n).astype(np.float32))
+
+    counts = {
+        "empty_rows": [0 if i % 3 == 0 else 5 for i in range(_DEC_ROWS)],
+        "partial_batch": [int(rng.integers(0, 6)) for _ in range(50)],
+        "all_empty": [0] * _DEC_ROWS,
+        "ends_at_nnz": [_DEC_NNZ // _DEC_ROWS] * _DEC_ROWS,
+        "one_row_holds_all": [_DEC_NNZ],
+    }[case]
+    return [row(i, n) for i, n in enumerate(counts)]
+
+
+def _wire_buffer(host, rows, nnz, id_bits):
+    """(buf, meta) of a packed host dict: the v2 wire (``id_bits`` 0) or a
+    compact v3 one with ``id_bits``-wide ids and raw f32 values."""
+    from dmlc_core_tpu.pipeline.device_loader import _host_fused
+    v2 = _host_fused(host, rows, nnz)
+    if not id_bits:
+        return v2, nnz
+    iw = (nnz * id_bits + 31) // 32
+    ids = host["ids"].astype(np.uint64)
+    bitpos = np.arange(nnz, dtype=np.uint64) * np.uint64(id_bits)
+    word = (bitpos >> np.uint64(5)).astype(np.int64)
+    off = bitpos & np.uint64(31)
+    packed = np.zeros(iw + 1, np.uint64)      # +1: the last id's spill
+    np.bitwise_or.at(packed, word, (ids << off) & np.uint64(0xFFFFFFFF))
+    np.bitwise_or.at(packed, word + 1, (ids << off) >> np.uint64(32))
+    buf = np.concatenate([packed[:iw].astype(np.uint32).view(np.int32),
+                          v2[nnz:]])
+    return buf, nnz | (id_bits << 32)
+
+
+@pytest.mark.parametrize("id_bits", [0, 10], ids=["v2", "v3"])
+@pytest.mark.parametrize("case", ["empty_rows", "partial_batch", "all_empty",
+                                  "ends_at_nnz", "one_row_holds_all"])
+def test_decoder_rebuilds_segments_on_device(case, id_bits):
+    """``make_decoder`` called WITHOUT host segments — what the chip runs
+    and ``_put_fused_buf`` never does on the CPU backend — rebuilds
+    ``segments`` bit for bit as ``_host_segments`` does (padding → the
+    scratch row ``rows``), whatever the shape of ``row_ptr``."""
+    import jax
+    from dmlc_core_tpu.pipeline.device_loader import (_fused_words_meta,
+                                                      _host_segments,
+                                                      make_decoder)
+    rows, nnz = _DEC_ROWS, _DEC_NNZ
+    host = pack_flat(block_of(_decoder_rows(case)), rows, nnz)
+    if case in ("ends_at_nnz", "one_row_holds_all"):
+        assert host["row_ptr"][rows] == nnz     # the out-of-range row end
+    buf, meta = _wire_buffer(host, rows, nnz, id_bits)
+    assert len(buf) == _fused_words_meta(rows, meta)
+    out = jax.jit(make_decoder(rows, meta))(buf)
+    assert out["segments"].dtype == np.int32
+    np.testing.assert_array_equal(
+        np.asarray(out["segments"]),
+        _host_segments(buf, rows, nnz, len(buf)))
+    for k, v in host.items():               # segments too: pack_flat's own
+        np.testing.assert_array_equal(np.asarray(out[k]), v, err_msg=k)
+        assert out[k].dtype == v.dtype, k
+
+
+@pytest.mark.parametrize("id_bits", [0, 10], ids=["v2", "v3"])
+def test_decoder_rebuilds_segments_inside_scan(id_bits):
+    """The decoder as ``make_train_step_fused`` calls it: in the body of a
+    ``lax.scan`` over stacked wire buffers, no host segments."""
+    import jax
+    from dmlc_core_tpu.pipeline.device_loader import make_decoder
+    rows, nnz = _DEC_ROWS, _DEC_NNZ
+    hosts = [pack_flat(block_of(_decoder_rows(c)), rows, nnz)
+             for c in ("partial_batch", "ends_at_nnz")]
+    bufs, metas = zip(*(_wire_buffer(h, rows, nnz, id_bits) for h in hosts))
+    decode = make_decoder(rows, metas[0])
+    _, out = jax.jit(lambda stacked: jax.lax.scan(
+        lambda c, b: (c, decode(b)), 0, stacked))(np.stack(bufs))
+    for i, host in enumerate(hosts):
+        for k, v in host.items():
+            np.testing.assert_array_equal(np.asarray(out[k][i]), v,
+                                          err_msg=f"{k}[{i}]")
 
 
 def test_ids_overflow_raises_and_id_mod_hashes():

@@ -137,10 +137,14 @@ def case_decoder(meta):
         compiled = jax.jit(make_decoder(ROWS, meta),
                            donate_argnums=(0,)).lower(buf).compile()
         assert _hbm(compiled) < HBM_BYTES
-        assert "tpu_custom_call" not in compiled.as_text()
-        assert "HloModule jit__unpack" in compiled.as_text()
+        text = compiled.as_text()
+        assert "tpu_custom_call" not in text
+        assert "HloModule jit__unpack" in text
         _names_scopes(compiled, ["wire_decode/ids", "wire_decode/vals",
                                  "wire_decode/segments"])
+        # segments come from a scatter and a prefix sum: a search over
+        # row_ptr would be a loop of scalar gathers (15 ms a batch on a v5e)
+        assert " while(" not in text
     return run
 
 
