@@ -61,6 +61,24 @@ def _dcn(p: "TrainParams"):
                  layers=p.layers, l2=p.l2, task=p.task)
 
 
+@MODEL_REGISTRY.register("hybrid_moe_lm",
+                         "linear + latent attention, routed experts: "
+                         "scores packed token documents (forward only)")
+def _hybrid_moe_lm(p: "TrainParams"):
+    from .hybrid_lm import HybridMoELM, load_arch
+    if not p.arch:
+        raise ParamError("model=hybrid_moe_lm needs arch=<json file of the "
+                         "architecture's published keys>")
+    model = HybridMoELM(load_arch(p.arch))
+    if p.task != "score" or p.features != model.vocab:
+        raise ParamError(
+            f"model=hybrid_moe_lm scores documents by mean log-probability "
+            f"over {model.vocab} vocabulary rows: set task=score and "
+            f"features={model.vocab} (got task={p.task}, "
+            f"features={p.features})")
+    return model
+
+
 class TrainParams(Parameter):
     """All knobs of a training run (printable via ``--help``/doc_string)."""
 
@@ -97,7 +115,14 @@ class TrainParams(Parameter):
     dim = field(int, default=16, lower_bound=1, help="factor dimension")
     layers = field(int, default=2, lower_bound=1,
                    help="depth: deepfm tower / dcn cross layers")
-    task = field(str, default="binary", enum=["binary", "regression"])
+    arch = field(str, default="",
+                 help="JSON file of a published architecture's config keys "
+                      "(+ held_experts, vocab_rows), for models described "
+                      "by one (hybrid_moe_lm)")
+    task = field(str, default="binary",
+                 enum=["binary", "regression", "score"],
+                 help="score: the model's output is the row's score as it "
+                      "stands (a document's mean log-probability)")
     epochs = field(int, default=1, lower_bound=1)
     batch_rows = field(int, default=4096, lower_bound=1)
     nnz_cap = field(int, default=131072, lower_bound=1)
@@ -157,6 +182,26 @@ def _parse_argv(argv):
     return conf
 
 
+def _scorer(model):
+    """(jitted ``(params, batch) -> (scores, counters)``, ``note``) — the
+    scoring loop's two halves.  A model that counts on the device
+    (``forward_counted``) hands its counters back beside the scores;
+    ``note(counters)``, called once the scores have been read (the same
+    dispatch: no extra sync), adds them to the span ring as one
+    ``lm.batch`` event.  Every other model has none to note."""
+    import jax
+
+    counted = getattr(model, "forward_counted", None)
+    if counted is None:
+        fwd = jax.jit(model.forward)
+        return (lambda params, batch: (fwd(params, batch), None),
+                lambda counters: None)
+    from ..telemetry import trace
+    return (jax.jit(counted),
+            lambda counters: trace.add_event(
+                "lm.batch", **model.counter_record(counters)))
+
+
 def _predict(p: TrainParams, model, template_params, fmt: str,
              needs_fields: bool) -> int:
     """Restore the latest checkpoint and write one score per input row to
@@ -186,7 +231,7 @@ def _predict(p: TrainParams, model, template_params, fmt: str,
               f"model={p.model} requested", file=sys.stderr)
         return 2
     params = state["params"]
-    fwd = jax.jit(model.forward)
+    fwd, note = _scorer(model)
     n = 0
     with open_stream(p.output, "w") as out:
         loader = _make_loader(p, p.data, fmt, needs_fields)
@@ -200,7 +245,7 @@ def _predict(p: TrainParams, model, template_params, fmt: str,
             # still get its score (ADVICE r3).
             held = None
             for batch in loader:
-                scores = fwd(params, batch)
+                scores, counters = fwd(params, batch)
                 if p.task == "binary":
                     scores = jax.nn.sigmoid(scores)
                 if held is not None:
@@ -208,6 +253,7 @@ def _predict(p: TrainParams, model, template_params, fmt: str,
                         out.write(b"%.6f\n" % float(v))
                     n += len(held)
                 held = np.asarray(scores)
+                note(counters)
             if held is not None:
                 total = int(loader.stats.rows)
                 for v in held[:max(0, total - n)]:
@@ -243,7 +289,15 @@ def main(argv=None) -> int:
 
     enable_compile_cache()
 
-    model = MODEL_REGISTRY[p.model](p)
+    try:
+        model = MODEL_REGISTRY[p.model](p)
+    except (DMLCError, ValueError, OSError) as e:   # the model's own file
+        print(f"dmlc-train: {e}", file=sys.stderr)
+        return 2
+    if p.mode == "train" and not hasattr(model, "loss"):
+        print(f"dmlc-train: model={p.model} is forward only (it has no "
+              f"loss): use mode=predict", file=sys.stderr)
+        return 2
     needs_fields = p.model == "ffm"
     fmt = p.format
     if fmt == "auto":

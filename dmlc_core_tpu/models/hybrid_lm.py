@@ -1,0 +1,346 @@
+"""A hybrid linear-attention / latent-attention mixture-of-experts language
+model that scores packed documents, forward only.
+
+The batch is the loader's own flat-CSR dict read another way: a row is a
+**document**, ``ids[nnz_cap]`` are its **tokens in order** (repeats and
+all), ``row_ptr`` / ``segments`` are the document boundaries of one packed
+stream, ``vals`` and ``labels`` are ignored, and padding tokens
+(``segments == batch_rows``) belong to no document.  ``forward`` returns one
+score a row: the mean log-probability of each next token given the tokens
+before it in the document, ``1/(n-1) sum_t log p(x_{t+1} | x_{<=t})``.
+
+The architecture arrives as the model's published ``config.json`` keys
+(``kimi_linear``-style) plus two that say what this holder keeps of a layer
+shared between chips: ``held_experts = [lo, hi]`` and ``vocab_rows``.
+Pre-norm residual blocks, RMSNorm, untied embedding and head:
+
+* layers in ``linear_attn_config.kda_layers`` (numbered from 1) mix tokens
+  with a gated delta rule with per-channel decay (``ops.kda``) behind a
+  4-tap causal depthwise convolution and SiLU, L2-normalised ``q``/``k``,
+  a low-rank decay gate and a low-rank output gate (rank = the head size,
+  as the published implementation has it);
+* layers in ``full_attn_layers`` use latent attention without positions
+  (``mla_use_nope``): keys and values expand from a normalised latent of
+  ``kv_lora_rank``; the ``qk_rope_head_dim`` columns that would carry a
+  rotation stay, shared by all heads, unrotated;
+* the first ``first_k_dense_replace`` layers have a dense SwiGLU, the rest a
+  sigmoid-routed mixture plus shared experts (``ops.moe``).
+
+``dtype`` (bfloat16 as published) is the type of parameters and
+activations; router scores and the choice, softmax and log-softmax, RMSNorm
+statistics, the decay (in log space), the recurrent state and every
+accumulation are float32.  The ``[T, vocab_rows]`` logits exist one block
+of tokens at a time.
+"""
+
+from __future__ import annotations
+
+import json
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.doc_attention import doc_causal_attention
+from ..ops.kda import kda_chunked
+from ..ops.moe import held_experts_sum, route, swiglu
+
+__all__ = ["HybridMoELM", "load_arch"]
+
+Params = Dict[str, object]
+F32 = jnp.float32
+KDA_CHUNK = 64
+HEAD_BLOCK = 1024
+
+
+def load_arch(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def _rms(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    xf = x.astype(F32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (y * w.astype(F32)).astype(x.dtype)
+
+
+def _mm(x: jax.Array, w: jax.Array) -> jax.Array:
+    return jnp.dot(x, w, preferred_element_type=F32).astype(x.dtype)
+
+
+def _doc_conv(x: jax.Array, taps: jax.Array, pos: jax.Array) -> jax.Array:
+    """Causal depthwise convolution over the stream that sees zeros before
+    a document's first token: ``y_t = sum_j taps[j] x_{t-(K-1)+j}``, a term
+    kept only where that many tokens of the document lie behind ``t``."""
+    kk = taps.shape[0]
+    y = x.astype(F32) * taps[-1].astype(F32)
+    for back in range(1, kk):
+        shifted = jnp.pad(x, ((back, 0), (0, 0)))[:x.shape[0]]
+        y = y + jnp.where((pos >= back)[:, None], shifted.astype(F32), 0.0) \
+            * taps[kk - 1 - back].astype(F32)
+    return y.astype(x.dtype)
+
+
+class HybridMoELM:
+    """Registered as ``hybrid_moe_lm``; see the module text."""
+
+    def __init__(self, arch: dict):
+        need = {"moe_router_activation_func": "sigmoid", "num_expert_group": 1,
+                "topk_group": 1, "q_lora_rank": None, "mla_use_nope": True,
+                "moe_renormalize": True, "tie_word_embeddings": False,
+                "hidden_act": "silu", "moe_layer_freq": 1}
+        for key, want in need.items():
+            if arch.get(key, want) != want:
+                raise ValueError(f"hybrid_moe_lm computes {key}={want!r} "
+                                 f"only, the architecture says "
+                                 f"{arch[key]!r}")
+        a = arch
+        self.dtype = jnp.dtype(a.get("dtype", "bfloat16"))
+        self.hidden = int(a["hidden_size"])
+        self.layers = int(a["num_hidden_layers"])
+        self.eps = float(a["rms_norm_eps"])
+        lin = a["linear_attn_config"]
+        self.kda_layers = {int(x) for x in lin["kda_layers"]}
+        self.kda_heads = int(lin["num_heads"])
+        self.kda_dim = int(lin["head_dim"])
+        self.conv_taps = int(lin["short_conv_kernel_size"])
+        self.gate_rank = self.kda_dim
+        self.heads = int(a["num_attention_heads"])
+        self.kv_rank = int(a["kv_lora_rank"])
+        self.d_nope = int(a["qk_nope_head_dim"])
+        self.d_rope = int(a["qk_rope_head_dim"])
+        self.d_v = int(a["v_head_dim"])
+        self.dense_layers = int(a["first_k_dense_replace"])
+        self.dense_width = int(a["intermediate_size"])
+        self.expert_width = int(a["moe_intermediate_size"])
+        self.experts = int(a["num_experts"])
+        self.top_k = int(a["num_experts_per_token"])
+        self.shared = int(a["num_shared_experts"])
+        self.route_scale = float(a["routed_scaling_factor"])
+        lo, hi = a.get("held_experts", [0, self.experts])
+        self.held: Tuple[int, int] = (int(lo), int(hi))
+        if not 0 <= self.held[0] < self.held[1] <= self.experts:
+            raise ValueError(f"held_experts {self.held} is no range of the "
+                             f"{self.experts} experts")
+        self.vocab = int(a.get("vocab_rows", a["vocab_size"]))
+
+    def mixer(self, layer: int) -> str:
+        return "kda" if layer in self.kda_layers else "mla"
+
+    # -- parameters -------------------------------------------------------
+    def _layer_shapes(self, layer: int) -> Dict[str, tuple]:
+        h = self.hidden
+        s: Dict[str, tuple] = {"norm1": (h,), "norm2": (h,)}
+        if self.mixer(layer) == "kda":
+            n, r = self.kda_heads * self.kda_dim, self.gate_rank
+            s.update(wq=(h, n), wk=(h, n), wv=(h, n),
+                     conv_q=(self.conv_taps, n), conv_k=(self.conv_taps, n),
+                     conv_v=(self.conv_taps, n),
+                     decay_down=(h, r), decay_up=(r, n), decay_bias=(n,),
+                     decay_rate=(self.kda_heads,),
+                     w_beta=(h, self.kda_heads),
+                     gate_down=(h, r), gate_up=(r, n),
+                     out_norm=(self.kda_dim,), wo=(n, h))
+        else:
+            s.update(wq=(h, self.heads * (self.d_nope + self.d_rope)),
+                     wkv_a=(h, self.kv_rank + self.d_rope),
+                     kv_norm=(self.kv_rank,),
+                     wkv_b=(self.kv_rank,
+                            self.heads * (self.d_nope + self.d_v)),
+                     wo=(self.heads * self.d_v, h))
+        if layer <= self.dense_layers:
+            s.update(w_gu=(h, 2 * self.dense_width),
+                     w_down=(self.dense_width, h))
+        else:
+            g, w = self.held[1] - self.held[0], self.expert_width
+            s.update(router=(h, self.experts), router_bias=(self.experts,),
+                     e_gu=(g, h, 2 * w), e_down=(g, w, h),
+                     s_gu=(h, 2 * w * self.shared),
+                     s_down=(w * self.shared, h))
+        return s
+
+    def shapes(self) -> Dict[str, object]:
+        out: Dict[str, object] = {
+            "embed": (self.vocab, self.hidden), "final_norm": (self.hidden,),
+            "head": (self.hidden, self.vocab)}
+        for layer in range(1, self.layers + 1):
+            out[f"layer_{layer:02d}"] = self._layer_shapes(layer)
+        return out
+
+    def init(self, rng: jax.Array) -> Params:
+        """Norm weights 1, ``router_bias`` 0, ``decay_rate`` (log of the
+        per-head rate) 0, ``decay_bias`` -2; matrices ``N(0, 1/fan_in)``;
+        the embedding ``N(0, 1)``."""
+        flat, treedef = jax.tree_util.tree_flatten_with_path(
+            self.shapes(), is_leaf=lambda x: isinstance(x, tuple))
+        keys = jax.random.split(rng, len(flat))
+        leaves = []
+        for key, (path, shape) in zip(keys, flat):
+            name = path[-1].key
+            if "norm" in name:
+                leaf = jnp.ones(shape, F32)
+            elif name in ("router_bias", "decay_rate"):
+                leaf = jnp.zeros(shape, F32)
+            elif name == "decay_bias":
+                leaf = jnp.full(shape, -2.0, F32)
+            elif name == "embed" or name.startswith("conv"):
+                leaf = jax.random.normal(key, shape, F32)
+            else:
+                leaf = jax.random.normal(key, shape, F32) * shape[-2] ** -0.5
+            leaves.append(leaf.astype(self.dtype))
+        return jax.tree_util.tree_unflatten(treedef, leaves)
+
+    # -- sublayers --------------------------------------------------------
+    def _kda(self, p, x, seg, pos):
+        t = x.shape[0]
+        nh, d = self.kda_heads, self.kda_dim
+        with jax.named_scope("kda/project"):
+            q, k, v = _mm(x, p["wq"]), _mm(x, p["wk"]), _mm(x, p["wv"])
+        with jax.named_scope("kda/conv"):
+            heads = lambda y, taps: jax.nn.silu(                  # noqa: E731
+                _doc_conv(y, taps, pos).astype(F32)).reshape(t, nh, d)
+            q, k = heads(q, p["conv_q"]), heads(k, p["conv_k"])
+            v = heads(v, p["conv_v"]).astype(x.dtype)
+            unit = lambda y: y * jax.lax.rsqrt(                   # noqa: E731
+                jnp.sum(y * y, -1, keepdims=True) + 1e-6)
+            q = (unit(q) * d ** -0.5).astype(x.dtype)
+            k = unit(k).astype(x.dtype)
+        with jax.named_scope("kda/gates"):
+            raw = jnp.dot(_mm(x, p["decay_down"]), p["decay_up"],
+                          preferred_element_type=F32)
+            rate = jnp.exp(p["decay_rate"].astype(F32))[None, :, None]
+            g = -rate * jax.nn.softplus(
+                raw + p["decay_bias"].astype(F32)).reshape(t, nh, d)
+            beta = jax.nn.sigmoid(jnp.dot(x, p["w_beta"],
+                                          preferred_element_type=F32))
+            gate = jax.nn.sigmoid(jnp.dot(
+                _mm(x, p["gate_down"]), p["gate_up"],
+                preferred_element_type=F32)).reshape(t, nh, d)
+        with jax.named_scope("kda/scan"):
+            o = kda_chunked(q, k, v, g, beta, seg, KDA_CHUNK)
+        with jax.named_scope("kda/out"):
+            o = _rms(o, p["out_norm"], self.eps) * gate
+            return _mm(o.astype(x.dtype).reshape(t, nh * d), p["wo"])
+
+    def _mla(self, p, x, seg, doc_start):
+        t = x.shape[0]
+        nh, dn, dr, dv = self.heads, self.d_nope, self.d_rope, self.d_v
+        with jax.named_scope("mla/project"):
+            q = _mm(x, p["wq"]).reshape(t, nh, dn + dr)
+            q = (q.astype(F32) * (dn + dr) ** -0.5).astype(x.dtype)
+            latent, k_shared = jnp.split(_mm(x, p["wkv_a"]),
+                                         [self.kv_rank], axis=-1)
+            kv = _mm(_rms(latent, p["kv_norm"], self.eps),
+                     p["wkv_b"]).reshape(t, nh, dn + dv)
+            k = jnp.concatenate(
+                [kv[..., :dn],
+                 jnp.broadcast_to(k_shared[:, None, :], (t, nh, dr))], -1)
+            v = kv[..., dn:]
+        with jax.named_scope("mla/attention"):
+            o = doc_causal_attention(q, k, v, seg, doc_start)
+        with jax.named_scope("mla/out"):
+            return _mm(o.astype(x.dtype).reshape(t, nh * dv), p["wo"])
+
+    def _moe(self, p, x, live):
+        with jax.named_scope("moe/router"):
+            chosen, weights = route(x, p["router"], p["router_bias"],
+                                    self.top_k, self.route_scale)
+        routed, counters = held_experts_sum(
+            x, chosen, weights, live, p["e_gu"], p["e_down"], self.held)
+        with jax.named_scope("moe/shared"):
+            shared = swiglu(x, p["s_gu"], p["s_down"])
+        return routed + shared, counters, chosen
+
+    # -- the whole forward ------------------------------------------------
+    def _hidden(self, params, batch):
+        """Final-norm hidden states ``[T, H]``, the per-layer counters and
+        the experts each token chose in each mixture layer."""
+        ids, seg, row_ptr = batch["ids"], batch["segments"], batch["row_ptr"]
+        rows = row_ptr.shape[0] - 1
+        t = ids.shape[0]
+        live = seg < rows
+        # a token's document start; padding is one document behind the last
+        doc_start = row_ptr[jnp.minimum(seg, rows)]
+        pos = jnp.arange(t, dtype=jnp.int32) - doc_start
+        with jax.named_scope("lm_embed"):
+            x = params["embed"][ids]
+        counters, choices = {}, {}
+        for layer in range(1, self.layers + 1):
+            name = f"layer_{layer:02d}"
+            p = params[name]
+            y = _rms(x, p["norm1"], self.eps)
+            if self.mixer(layer) == "kda":
+                x = x + self._kda(p, y, seg, pos)
+            else:
+                x = x + self._mla(p, y, seg, doc_start)
+            y = _rms(x, p["norm2"], self.eps)
+            if layer <= self.dense_layers:
+                with jax.named_scope("dense_mlp"):
+                    x = x + swiglu(y, p["w_gu"], p["w_down"])
+            else:
+                out, counters[name], choices[name] = self._moe(p, y, live)
+                x = x + out
+        return _rms(x, params["final_norm"], self.eps), counters, choices
+
+    def forward_counted(self, params: Params, batch: Dict[str, jax.Array]):
+        """(scores ``[batch_rows]`` float32, counters): per mixture layer
+        the assignments that reached held experts, the largest and the mean
+        load of a held expert and the live tokens none of whose experts is
+        held; the batch's tokens and documents."""
+        seg, row_ptr = batch["segments"], batch["row_ptr"]
+        rows = row_ptr.shape[0] - 1
+        t = seg.shape[0]
+        hid, counters, _ = self._hidden(params, batch)
+        with jax.named_scope("lm_head"):
+            target = jnp.roll(batch["ids"], -1)
+            pad = -t % HEAD_BLOCK
+            blocks = lambda a: jnp.pad(                           # noqa: E731
+                a, [(0, pad)] + [(0, 0)] * (a.ndim - 1)).reshape(
+                    (t + pad) // HEAD_BLOCK, HEAD_BLOCK, *a.shape[1:])
+
+            def block_logp(args):
+                h_b, target_b = args
+                logits = jnp.dot(h_b, params["head"],
+                                 preferred_element_type=F32)
+                picked = jnp.take_along_axis(logits, target_b[:, None], 1)
+                return picked[:, 0] - jax.nn.logsumexp(logits, axis=-1)
+
+            logp = jax.lax.map(block_logp, (blocks(hid), blocks(target)))
+            logp = logp.reshape(-1)[:t]
+            # token t predicts t+1 where both lie in one document
+            counted = (seg == jnp.roll(seg, -1)) & (seg < rows)
+            counted = counted.at[-1].set(False)
+            total = jax.ops.segment_sum(jnp.where(counted, logp, 0.0), seg,
+                                        num_segments=rows + 1)[:rows]
+            n = jnp.diff(row_ptr)
+            scores = total / jnp.maximum(n - 1, 1).astype(F32)
+        counters["tokens"] = row_ptr[rows]
+        counters["documents"] = jnp.sum(n > 0)
+        return scores, counters
+
+    def forward(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
+        return self.forward_counted(params, batch)[0]
+
+    def probe(self, params: Params, batch: Dict[str, jax.Array],
+              positions: jax.Array):
+        """For checks, not for speed: the logits ``[P, vocab_rows]`` float32
+        at stream ``positions`` and each mixture layer's chosen experts
+        ``{layer: [T, k]}``, through the same sublayers as ``forward``."""
+        hid, _, choices = self._hidden(params, batch)
+        logits = jnp.dot(hid[positions], params["head"],
+                         preferred_element_type=F32)
+        return logits, choices
+
+    @staticmethod
+    def counter_record(counters) -> dict:
+        """One batch's counters, fetched to the host, as one flat record:
+        what the scoring loop adds to the span ring as an ``lm.batch``
+        event."""
+        rec = {}
+        for name, value in jax.device_get(counters).items():
+            if isinstance(value, dict):
+                rec.update({f"{name}.{k}": float(v)
+                            for k, v in value.items()})
+            else:
+                rec[name] = float(value)
+        return rec

@@ -719,6 +719,10 @@ int parse_parallel(const char* data, int64_t len, bool want_fields, int nthreads
 //   [...,      +rows)        weights  f32 bits (padding rows weigh 0)
 // words(B) = 2*B + 3*rows + 1.
 //
+// A row's ids are staged in source order, repeats included, and never
+// sorted or merged, in both layouts: a row may be a document and its ids
+// its tokens (value-less libsvm tokens carry 1.0).
+//
 // Replaces the per-batch numpy pack path (reference equivalent: the consumer
 // loop materialising RowBlocks, basic_row_iter.h:61-82 — here rows stream
 // straight into device-transfer staging).  A batch closes when either

@@ -25,6 +25,11 @@ rebuild is search-free: a 1 scattered at every row's end, then one prefix
 sum (0.04 ms a 4096 × 163840 batch on a v5e; a binary search over
 ``row_ptr`` is a ``while`` of scalar gathers there, 15 ms — PERF.md, PR 29).
 
+Both layouts carry a row's ids in source order with repeats (the compact
+wire bit-packs positions, it does not sort or deduplicate), so a batch may
+be a packed stream of token documents: ``ids`` the tokens, ``row_ptr`` /
+``segments`` the document boundaries (``pipeline.packing``).
+
 With a sharding whose mesh spans multiple devices, ``device_put`` scatters
 the batch across them (data-parallel input sharding ≙ the reference's
 ``ResetPartition(rank, nsplit)`` expressed on the device mesh instead of the

@@ -22,6 +22,14 @@ arrays before hitting the TPU.  Two layouts:
 
 Padding rows carry ``weight 0`` so losses ignore them without masking logic.
 
+**Order is part of the contract.**  Every packer copies a row's ids in the
+order the source gave them, repeats included, and never sorts or merges
+them; a block without values (``values is None``: libsvm tokens written as
+bare ids) packs ``vals`` of 1.  So a row may be a *document* and its ids its
+*tokens*: ``row_ptr`` / ``segments`` are then the document boundaries of one
+packed token stream (``models.hybrid_lm``).  The native packer and both wire
+layouts keep the same contract (``tests/test_token_feed.py``).
+
 Truncation is **surfaced** (ISSUE 6 satellite): any pack that drops
 values bumps the process-global ``pipeline.pack.truncated_values`` /
 ``pipeline.pack.truncated_rows`` counters and logs a rate-limited

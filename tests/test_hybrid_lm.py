@@ -1,0 +1,336 @@
+"""The hybrid linear/latent-attention mixture-of-experts document scorer at
+small widths on the CPU (hidden 64, 4 heads of 16, 8 experts top-2 + 1
+shared, 5 layers in the published pattern, 512 vocabulary rows; float32),
+against the plain reference (``tests/lm_reference.py``, the same text as
+the benchmark's ``reference_lm.py``)."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import lm_reference
+from dmlc_core_tpu.data.row_block import RowBlock
+from dmlc_core_tpu.models.hybrid_lm import HybridMoELM
+from dmlc_core_tpu.ops.doc_attention import doc_causal_attention
+from dmlc_core_tpu.ops.kda import kda_chunked
+from dmlc_core_tpu.pipeline.packing import pack_flat
+
+ARCH = {
+    "model_type": "kimi_linear", "hidden_size": 64, "num_hidden_layers": 5,
+    "rms_norm_eps": 1e-5, "hidden_act": "silu",
+    "linear_attn_config": {"kda_layers": [1, 2, 3, 5, 6, 7],
+                           "full_attn_layers": [4, 8], "head_dim": 16,
+                           "num_heads": 4, "short_conv_kernel_size": 4},
+    "num_attention_heads": 4, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "q_lora_rank": None,
+    "mla_use_nope": True, "first_k_dense_replace": 1,
+    "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_experts": 8, "num_experts_per_token": 2, "num_shared_experts": 1,
+    "moe_router_activation_func": "sigmoid", "moe_renormalize": True,
+    "routed_scaling_factor": 2.446, "num_expert_group": 1, "topk_group": 1,
+    "tie_word_embeddings": False, "vocab_size": 1024, "vocab_rows": 512,
+    "held_experts": [0, 4], "dtype": "float32",
+}
+ROWS, CAP = 8, 640
+# document lengths of one batch: boundaries inside 64-token chunks, a
+# one-token document, a document longer than several chunks, a full stream
+TEMPLATES = {
+    "mixed": [70, 33, 129, 1, 90, 60],
+    "one_long": [600],
+    "many_short": [5, 1, 2, 64, 63, 65, 3, 128],
+    "full": [100, 28, 200, 312],
+}
+
+
+def make_batch(lengths, seed=1, vocab=512):
+    """A loader batch (``pack_flat``'s own dict) of token documents."""
+    rng = np.random.default_rng(seed)
+    docs = [rng.integers(0, vocab, n) for n in lengths]
+    blk = RowBlock(
+        offsets=np.concatenate([[0], np.cumsum(lengths)]),
+        labels=np.zeros(len(docs), np.float32),
+        indices=np.concatenate(docs).astype(np.uint64), values=None)
+    host = pack_flat(blk, ROWS, CAP, id_mod=vocab)
+    return {k: jnp.asarray(v) for k, v in host.items()}, host
+
+
+def spiced(params, seed=5):
+    """``init``'s zeros and ones made random, so the decay rates, the decay
+    and router biases and the norm weights are exercised."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(flat))
+    out = []
+    for key, (path, leaf) in zip(keys, flat):
+        name = path[-1].key
+        noise = jax.random.normal(key, leaf.shape, leaf.dtype)
+        if "norm" in name:
+            leaf = leaf + 0.2 * noise
+        elif name == "router_bias":
+            leaf = 0.05 * noise
+        elif name == "decay_rate":
+            leaf = jnp.abs(noise)
+        elif name == "decay_bias":
+            leaf = leaf + noise
+        out.append(leaf)
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+@pytest.fixture(scope="module")
+def model():
+    return HybridMoELM(ARCH)
+
+
+@pytest.fixture(scope="module")
+def params(model):
+    return spiced(model.init(jax.random.PRNGKey(0)))
+
+
+@pytest.fixture(scope="module")
+def scorer(model):
+    return jax.jit(model.forward_counted), jax.jit(model.probe)
+
+
+@pytest.mark.parametrize("template", sorted(TEMPLATES))
+def test_forward_agrees_with_the_reference(model, params, scorer, template):
+    lengths = TEMPLATES[template]
+    batch, host = make_batch(lengths)
+    total = sum(lengths)
+    positions = sorted({0, total - 1} | set(np.cumsum(lengths)[:-1].tolist())
+                       | set((np.cumsum(lengths) - 1).tolist()))
+    ref = lm_reference.Reference(ARCH).run(
+        params, host["ids"], host["row_ptr"][:len(lengths) + 1], positions)
+    scores, counters = scorer[0](params, batch)
+    scores = np.asarray(scores)
+    np.testing.assert_allclose(scores[:len(lengths)], ref["scores"],
+                               atol=2e-5)
+    assert (scores[len(lengths):] == 0).all()          # padding rows
+    logits, chosen = scorer[1](params, batch, jnp.asarray(positions))
+    np.testing.assert_allclose(np.asarray(logits), ref["logits"], atol=1e-4)
+    for name, want in ref["chosen"].items():
+        clear = ref["margin"][name] > 1e-5
+        got = np.sort(np.asarray(chosen[name])[:total], -1)
+        assert (got[clear] == np.sort(want, -1)[clear]).all()
+    assert int(counters["tokens"]) == total
+    assert int(counters["documents"]) == len(lengths)
+
+
+def recurrence(q, k, v, g, beta, first):
+    """The delta rule token by token, state zeroed at a document's first
+    token (float64 on the host)."""
+    t, h, dk = q.shape
+    out = np.zeros((t, h, v.shape[-1]))
+    s = np.zeros((h, dk, v.shape[-1]))
+    for i in range(t):
+        if first[i]:
+            s[:] = 0
+        s = np.exp(g[i])[:, :, None] * s
+        s = s + beta[i][:, None, None] * k[i][:, :, None] * (
+            v[i] - np.einsum("hd,hde->he", k[i], s))[:, None, :]
+        out[i] = np.einsum("hde,hd->he", s, q[i])
+    return out
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 64])
+@pytest.mark.parametrize("template", ["mixed", "many_short", "one_long"])
+def test_chunked_kda_is_the_recurrence(chunk, template):
+    lengths = TEMPLATES[template]
+    t, h, d = sum(lengths), 3, 16
+    rng = np.random.default_rng(chunk + t)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(rng.normal(size=(t, h, d))) * d ** -0.5
+    k = unit(rng.normal(size=(t, h, d)))
+    v = rng.normal(size=(t, h, d))
+    # decays from almost none to e^-30 a token: no chunk may overflow
+    g = -np.exp(rng.uniform(-6, 3.4, size=(t, h, d)))
+    beta = rng.uniform(0.05, 0.95, size=(t, h))
+    seg = np.repeat(np.arange(len(lengths)), lengths)
+    first = np.concatenate([[True], seg[1:] != seg[:-1]])
+    f32 = lambda x: jnp.asarray(x, jnp.float32)            # noqa: E731
+    got = kda_chunked(f32(q), f32(k), f32(v), f32(g), f32(beta),
+                      jnp.asarray(seg, jnp.int32), chunk)
+    want = recurrence(q, k, v, g, beta, first)
+    np.testing.assert_allclose(np.asarray(got), want, atol=3e-5)
+
+
+@pytest.mark.parametrize("block", [16, 64, 512])
+@pytest.mark.parametrize("template", ["mixed", "many_short", "full"])
+def test_block_diagonal_attention_is_one_document_at_a_time(block, template):
+    lengths = TEMPLATES[template]
+    t, h, dqk, dv = sum(lengths), 2, 24, 16
+    rng = np.random.default_rng(block)
+    q = rng.normal(size=(t, h, dqk)) * dqk ** -0.5
+    k = rng.normal(size=(t, h, dqk))
+    v = rng.normal(size=(t, h, dv))
+    bounds = np.concatenate([[0], np.cumsum(lengths)])
+    seg = np.repeat(np.arange(len(lengths)), lengths)
+    want = np.zeros((t, h, dv))
+    for s, e in zip(bounds[:-1], bounds[1:]):
+        sc = np.einsum("qhd,khd->hqk", q[s:e], k[s:e])
+        sc = np.where(np.tril(np.ones((e - s, e - s), bool)), sc, -np.inf)
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        want[s:e] = np.einsum("hqk,khd->qhd", p / p.sum(-1, keepdims=True),
+                              v[s:e])
+    f32 = lambda x: jnp.asarray(x, jnp.float32)            # noqa: E731
+    got = doc_causal_attention(
+        f32(q), f32(k), f32(v), jnp.asarray(seg, jnp.int32),
+        jnp.asarray(bounds[seg], jnp.int32), block)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", ["layer_02", "layer_04"])
+@pytest.mark.parametrize("split", [4, 2, 6])
+def test_the_shares_add_up_to_the_uncut_layer(model, params, layer, split):
+    """Two holders of ``split`` and ``8 - split`` of the 8 experts: the parts
+    their layers give, with the shared expert (computed alike by both)
+    counted once, add up to the uncut reference layer."""
+    rng = np.random.default_rng(split)
+    t = 200
+    x = jnp.asarray(rng.normal(size=(t, 64)), jnp.float32)
+    live = jnp.ones(t, bool)
+    p = params[layer]
+    whole = dict(p)                     # the holder [0, 4) keeps 4: make 8
+    key = jax.random.PRNGKey(split)
+    whole["e_gu"] = jax.random.normal(key, (8, 64, 64)) / 8.0
+    whole["e_down"] = jax.random.normal(key, (8, 32, 64)) / 5.6
+    parts, loads = [], 0
+    for lo, hi in ((0, split), (split, 8)):
+        share = HybridMoELM(dict(ARCH, held_experts=[lo, hi]))
+        mine = dict(whole, e_gu=whole["e_gu"][lo:hi],
+                    e_down=whole["e_down"][lo:hi])
+        out, counters, _ = share._moe(mine, x, live)
+        parts.append(np.asarray(out))
+        loads += int(counters["assignments"])
+    ref = lm_reference.Reference(dict(ARCH, held_experts=[0, 8]))
+    uncut, _, _ = ref.moe(whole, x)
+    shared = np.asarray(ref.swiglu(x, p["s_gu"], p["s_down"]))
+    np.testing.assert_allclose(parts[0] + parts[1] - shared,
+                               np.asarray(uncut), atol=2e-5)
+    assert loads == t * ARCH["num_experts_per_token"]     # nothing dropped
+
+
+@pytest.mark.parametrize("template", ["mixed", "full"])
+def test_sliced_vocabulary_is_log_softmax_over_its_columns(template):
+    """A holder of rows 0..511 of a 1024-row vocabulary scores with the
+    log-softmax over its own 512 columns of the full logits."""
+    lengths = TEMPLATES[template]
+    full = HybridMoELM(dict(ARCH, vocab_rows=1024))
+    sliced = HybridMoELM(ARCH)
+    p_full = spiced(full.init(jax.random.PRNGKey(3)))
+    p_slice = dict(p_full, embed=p_full["embed"][:512],
+                   head=p_full["head"][:, :512])
+    batch, host = make_batch(lengths)
+    total = sum(lengths)
+    logits, _ = jax.jit(full.probe)(p_full, batch, jnp.arange(total))
+    logp = jax.nn.log_softmax(np.asarray(logits)[:, :512], axis=-1)
+    ids, rp = host["ids"], host["row_ptr"]
+    want = [np.mean([logp[t, ids[t + 1]]
+                     for t in range(rp[r], rp[r + 1] - 1)] or [0.0])
+            for r in range(len(lengths))]      # a one-token document: 0
+    got = np.asarray(jax.jit(sliced.forward)(p_slice, batch))
+    np.testing.assert_allclose(got[:len(lengths)], want, atol=2e-5)
+
+
+@pytest.mark.parametrize("template", ["mixed", "many_short"])
+def test_padding_tokens_reach_no_expert(model, params, scorer, template):
+    lengths = TEMPLATES[template]
+    batch, _ = make_batch(lengths)
+    _, counters = scorer[0](params, batch)
+    total, k = sum(lengths), ARCH["num_experts_per_token"]
+    other = HybridMoELM(dict(ARCH, held_experts=[4, 8]))
+    _, theirs = jax.jit(other.forward_counted)(params, batch)
+    for name in ("layer_02",):          # same input up to the first mixture
+        assert int(counters[name]["assignments"]) \
+            + int(theirs[name]["assignments"]) == total * k
+    for name, c in counters.items():
+        if isinstance(c, dict):
+            assert 0 <= int(c["unserved_tokens"]) <= total
+            assert int(c["load_max"]) * 4 >= int(c["assignments"])
+            assert float(c["load_mean"]) * 4 == pytest.approx(
+                float(c["assignments"]))
+
+
+@pytest.mark.parametrize("key,value", [
+    ("moe_router_activation_func", "softmax"), ("q_lora_rank", 1536),
+    ("mla_use_nope", False), ("num_expert_group", 8),
+    ("held_experts", [4, 12])])
+def test_an_architecture_it_does_not_compute_is_refused(key, value):
+    with pytest.raises(ValueError):
+        HybridMoELM(dict(ARCH, **{key: value}))
+
+
+def test_the_two_copies_of_the_reference_are_one_text():
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "lm_reference.py")) as f:
+        mine = f.read()
+    with open(os.path.join(here, "..", "benchmarks", "chip",
+                           "reference_lm.py")) as f:
+        assert f.read() == mine
+
+
+# -- the normal path: registry, loader, predict -----------------------------
+
+def write_docs(path, lengths, seed=9):
+    rng = np.random.default_rng(seed)
+    docs = [rng.integers(0, 512, n) for n in lengths]
+    with open(path, "w") as f:
+        for d in docs:
+            f.write("0 " + " ".join(map(str, d)) + "\n")
+    return docs
+
+
+@pytest.fixture()
+def run_dir(tmp_path):
+    arch = tmp_path / "arch.json"
+    arch.write_text(json.dumps(ARCH))
+    lengths = [70, 33, 129, 1, 90, 60, 200, 57, 12, 300, 41]
+    docs = write_docs(tmp_path / "docs.libsvm", lengths)
+    return tmp_path, str(arch), docs
+
+
+def predict_args(tmp, arch_file, **over):
+    args = dict(mode="predict", model="hybrid_moe_lm", arch=arch_file,
+                task="score", features=512, batch_rows=ROWS, nnz_cap=CAP,
+                data=f"file://{tmp}/docs.libsvm", ckpt_dir=f"{tmp}/ckpt",
+                output=f"{tmp}/scores.txt")
+    args.update(over)
+    return [f"{k}={v}" for k, v in args.items()]
+
+
+def test_predict_scores_documents_through_the_cli(run_dir):
+    from dmlc_core_tpu.models import cli
+    from dmlc_core_tpu.utils import CheckpointManager
+    tmp, arch, docs = run_dir
+    p = cli.TrainParams()
+    p.init(dict(a.split("=", 1) for a in predict_args(tmp, arch)))
+    model = cli.MODEL_REGISTRY[p.model](p)
+    params = spiced(model.init(jax.random.PRNGKey(p.seed)))
+    CheckpointManager(p.ckpt_dir).save(7, {"params": params},
+                                       meta={"model": p.model})
+    assert cli.main(predict_args(tmp, arch)) == 0
+    got = np.loadtxt(f"{tmp}/scores.txt")
+    assert got.shape == (len(docs),)
+    ref = lm_reference.Reference(ARCH)
+    for lo in range(0, len(docs), ROWS):
+        part = docs[lo:lo + ROWS]
+        rp = np.concatenate([[0], np.cumsum([len(d) for d in part])])
+        want = ref.run(params, np.concatenate(part), rp)["scores"]
+        np.testing.assert_allclose(got[lo:lo + len(part)], want, atol=2e-5)
+    # the counters of every batch are in the span ring
+    from dmlc_core_tpu.telemetry import trace
+    recs = [r for r in trace.recorder.snapshot() if r["name"] == "lm.batch"]
+    assert len(recs) >= 2
+    assert sum(r["attrs"]["documents"] for r in recs[-2:]) == len(docs)
+
+
+@pytest.mark.parametrize("over,why", [
+    ({"task": "binary"}, "task=score"), ({"features": 1024}, "features=512"),
+    ({"arch": ""}, "arch="), ({"mode": "train"}, "forward only")])
+def test_the_cli_refuses_what_the_model_is_not(run_dir, capsys, over, why):
+    from dmlc_core_tpu.models import cli
+    tmp, arch, _ = run_dir
+    assert cli.main(predict_args(tmp, arch, **over)) == 2
+    assert why in capsys.readouterr().err

@@ -215,6 +215,44 @@ def case_mesh_step(topo):
     assert _hbm(compiled) < HBM_BYTES      # bytes on each device
 
 
+def case_document_scorer(topo):
+    """The document scorer at the benchmark's published widths and timed
+    sizes (layers 1-5, 128 of 256 experts, half the vocabulary, 16
+    documents in 32 768 tokens, bfloat16): it fits the chip beside its
+    8.57 GB of parameters, the experts are a grouped product and neither
+    the ``[T, T]`` scores nor the ``[T, V]`` logits exist whole."""
+    import re
+
+    from dmlc_core_tpu.models.hybrid_lm import HybridMoELM, load_arch
+    here = os.path.dirname(os.path.abspath(__file__))
+    model = HybridMoELM(load_arch(os.path.join(
+        here, "..", "benchmarks", "chip", "configs",
+        "kimi_linear_48b_ep2_l5.json")))
+    one = SingleDeviceSharding(topo.devices[0])
+    S, f32, i32 = jax.ShapeDtypeStruct, jnp.float32, jnp.int32
+    t, rows = 32768, 16
+    params = jax.tree.map(lambda shape: S(shape, model.dtype, sharding=one),
+                          model.shapes(),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    batch = _on(one, dict(_batch(rows, t), row_ptr=S((rows + 1,), i32)))
+    compiled = jax.jit(model.forward_counted).lower(params, batch).compile()
+    assert sum(np.prod(leaf.shape) for leaf in jax.tree.leaves(params)) \
+        == 4_282_936_192
+    assert _hbm(compiled) < HBM_BYTES
+    text = compiled.as_text()
+    assert "ragged-dot" in text                  # experts: grouped products
+    top = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]", text,
+                     re.M)
+    for dims in top:
+        d = [int(x) for x in dims.split(",")]
+        assert not (t in d and model.vocab in d), dims     # [T, V]
+        assert d.count(t) < 2, dims                        # [T, T]
+    _names_scopes(compiled, [
+        "lm_embed", "kda/conv", "kda/gates", "kda/scan", "mla/project",
+        "mla/attention", "moe/router", "moe/dispatch", "moe/experts",
+        "moe/shared", "moe/combine", "dense_mlp", "lm_head"])
+
+
 CASES = {
     "gather_embed_128": case_kernel(lambda one, w: _gather(one, w, False)),
     "gather_fm_128": case_kernel(lambda one, w: _gather(one, w, True)),
@@ -230,6 +268,7 @@ CASES = {
     "serving_bucket_padded": case_serving_bucket(False),
     "serving_bucket_ragged": case_serving_bucket(True),
     "fm_mesh_dp2_mp2_step": case_mesh_step,
+    "document_scorer_forward": case_document_scorer,
 }
 
 
