@@ -742,8 +742,11 @@ int parse_parallel(const char* data, int64_t len, bool want_fields, int nthreads
 //     few-distinct (binary features, 4-decimal quantized floats) — chosen
 //     per batch only when codes+dict < raw f32, else raw fallback.
 // Layout v3: [ids packed w-bit][codes u16 | raw vals][dict][row_ptr][labels]
-// [weights]; decode on device is shifts+gathers (see device_loader
-// _get_unpack v3).  Reconstruction is bit-exact; code 0 is reserved for
+// [weights]; decode on device (device_loader make_decoder) is shifts only:
+// widths are multiples of 4 (ids) and 2 (codes), so a stream repeats every
+// 32/gcd(w,32) <= 16 values and each place in a group has one word and one
+// shift, fixed when the program is traced.  The one gather left is the
+// dictionary lookup.  Reconstruction is bit-exact; code 0 is reserved for
 // 0.0f so nnz padding decodes to 0.0 exactly like v2.  The emit meta is
 // B | (id_width << 32) | (log2(dict_words) << 40); id_width 0 = v2 layout,
 // dict_bits 0 = raw values.

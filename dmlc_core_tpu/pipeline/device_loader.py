@@ -38,6 +38,7 @@ byte range).
 
 from __future__ import annotations
 
+import math
 import threading
 from collections import deque
 from typing import Dict, Iterator, Optional
@@ -100,9 +101,11 @@ def make_decoder(rows: int, meta: int):
     """Pure (traceable) decode of one fused wire buffer → batch dict.
 
     v2 (id_width 0): slices + bitcasts, aliasing-friendly.  Compact v3: ids
-    are w-bit unpacked with two gathers + shifts, values decode through the
-    shipped dictionary (u16 code gather) — both pure VPU work that rides
-    along with the transfer.  ``segments`` (row id per value, padding →
+    and value codes are bit-unpacked with shifts the trace fixes — a w-bit
+    stream repeats every 32/gcd(w, 32) values, so each place in a group has
+    one word and one shift and nothing is indexed — and values decode
+    through the shipped dictionary, the one gather left in the program
+    (its indices are data).  ``segments`` (row id per value, padding →
     ``rows`` scratch row — same contract as ops.csr) are a prefix sum over
     the row ends scattered from ``row_ptr`` unless precomputed host-side.
 
@@ -121,19 +124,30 @@ def make_decoder(rows: int, meta: int):
             u32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.uint32)  # noqa: E731
 
             def unpack_bits(region, width):
+                # bit i*width recurs every P values over Q words, so place
+                # j of every group has one word and one shift: P
+                # shift-or-mask rows over a [Q, G] view, no computed index
+                # (an iota-indexed pu[word] is a scalar gather, 1.16 ms a
+                # 163840-value stream on a v5e — PERF.md, PR 31)
+                if width == 32:
+                    return region
+                g = math.gcd(width, 32)
+                P, Q = 32 // g, width // g
+                G = -(-nnz // P)
                 pu = u32(region)
-                i = jnp.arange(nnz, dtype=jnp.uint32)
-                bitpos = i * jnp.uint32(width)
-                word = (bitpos >> 5).astype(jnp.int32)
-                off = bitpos & jnp.uint32(31)
-                lo = pu[word] >> off
-                hi = pu[jnp.minimum(word + 1, len(region) - 1)] << (
-                    jnp.where(off > 0, jnp.uint32(32) - off,
-                              jnp.uint32(0)))
-                hi = jnp.where(off > 0, hi, jnp.uint32(0))
-                mask = jnp.uint32(
-                    0xFFFFFFFF if width >= 32 else (1 << width) - 1)
-                return ((lo | hi) & mask).astype(jnp.int32)
+                if G * Q > len(region):  # zero words under a ragged tail
+                    pu = jnp.pad(pu, (0, G * Q - len(region)))
+                words = pu.reshape(G, Q).T
+                mask = jnp.uint32((1 << width) - 1)
+                places = []
+                for j in range(P):
+                    a, off = (j * width) >> 5, (j * width) & 31
+                    v = words[a] >> off
+                    if off + width > 32:  # straddles into the next word
+                        v = v | (words[a + 1] << (32 - off))
+                    places.append(v & mask)
+                out = jnp.stack(places).T.reshape(-1)[:nnz]
+                return out.astype(jnp.int32)
 
             iw = nnz if w == 0 else (nnz * w + 31) // 32
             with jax.named_scope("ids"):
@@ -975,7 +989,10 @@ class DeviceLoader:
                             parent=self._trace, sync=False)):
             if item[0] == "fused":
                 _, buf, nnz, rows_real = item
-                with teltrace.span("device_loader.put", stage=self._m_put):
+                # which wire the batch rode: (id_width, dict_bits) of its
+                # emit meta — (0, 0) is v2, (w, 0) the raw-value fallback
+                with teltrace.span("device_loader.put", stage=self._m_put,
+                                   wire=_decode_meta(nnz)[1:]):
                     out = _put_fused_buf(buf, self.batch_rows, nnz)
                 # wait on the WHOLE batch before recycling: the CPU direct
                 # path issues independent per-array puts, so readiness of

@@ -237,6 +237,21 @@ def _decoder_rows(case):
     return [row(i, n) for i, n in enumerate(counts)]
 
 
+def _pack_bits(values, width):
+    """numpy inverse of the decoder's ``unpack_bits``: ``values`` back to
+    back, ``width`` bits each, LSB first, in ceil(n * width / 32) words."""
+    n = len(values)
+    nw = (n * width + 31) // 32
+    v = np.asarray(values).astype(np.uint64)
+    bitpos = np.arange(n, dtype=np.uint64) * np.uint64(width)
+    word = (bitpos >> np.uint64(5)).astype(np.int64)
+    off = bitpos & np.uint64(31)
+    packed = np.zeros(nw + 1, np.uint64)      # +1: the last value's spill
+    np.bitwise_or.at(packed, word, (v << off) & np.uint64(0xFFFFFFFF))
+    np.bitwise_or.at(packed, word + 1, (v << off) >> np.uint64(32))
+    return packed[:nw].astype(np.uint32).view(np.int32)
+
+
 def _wire_buffer(host, rows, nnz, id_bits):
     """(buf, meta) of a packed host dict: the v2 wire (``id_bits`` 0) or a
     compact v3 one with ``id_bits``-wide ids and raw f32 values."""
@@ -244,17 +259,47 @@ def _wire_buffer(host, rows, nnz, id_bits):
     v2 = _host_fused(host, rows, nnz)
     if not id_bits:
         return v2, nnz
-    iw = (nnz * id_bits + 31) // 32
-    ids = host["ids"].astype(np.uint64)
-    bitpos = np.arange(nnz, dtype=np.uint64) * np.uint64(id_bits)
-    word = (bitpos >> np.uint64(5)).astype(np.int64)
-    off = bitpos & np.uint64(31)
-    packed = np.zeros(iw + 1, np.uint64)      # +1: the last id's spill
-    np.bitwise_or.at(packed, word, (ids << off) & np.uint64(0xFFFFFFFF))
-    np.bitwise_or.at(packed, word + 1, (ids << off) >> np.uint64(32))
-    buf = np.concatenate([packed[:iw].astype(np.uint32).view(np.int32),
-                          v2[nnz:]])
+    buf = np.concatenate([_pack_bits(host["ids"], id_bits), v2[nnz:]])
     return buf, nnz | (id_bits << 32)
+
+
+@pytest.mark.parametrize("nnz", [208, 203], ids=["whole_groups", "ragged_tail"])
+@pytest.mark.parametrize("dbits", [0, 2, 4, 6, 8, 10, 12, 14, 16])
+@pytest.mark.parametrize("w", [8, 12, 16, 20, 24, 28, 32])
+def test_decoder_unpacks_every_emitted_width(w, dbits, nnz):
+    """Every (id width, dictionary bits) the native packer can emit, at an
+    ``nnz`` of whole 16-value groups and at one whose last group is cut
+    (the decoder pads the region with zero words): ids and values come
+    back bit for bit.  The last id and the last code have their top bit
+    set, so a straddle into the next word that was dropped would show."""
+    import jax
+    from dmlc_core_tpu.pipeline.device_loader import (_fused_words_meta,
+                                                      make_decoder)
+    rows = 8
+    rng = np.random.default_rng(w * 64 + dbits)
+    ids = rng.integers(0, 1 << w, nnz, dtype=np.uint64)
+    ids[-1] |= 1 << (w - 1)
+    if dbits:
+        codes = rng.integers(0, 1 << dbits, nnz, dtype=np.uint64)
+        codes[-1] |= 1 << (dbits - 1)
+        table = rng.standard_normal(1 << dbits).astype(np.float32)
+        vals = table[codes]
+        val_words = [_pack_bits(codes, dbits), table.view(np.int32)]
+    else:
+        vals = rng.standard_normal(nnz).astype(np.float32)
+        val_words = [vals.view(np.int32)]
+    row_ptr = np.minimum(np.arange(rows + 1) * (nnz // rows + 1), nnz)
+    buf = np.concatenate(
+        [_pack_bits(ids, w)] + val_words
+        + [row_ptr.astype(np.int32), np.zeros(2 * rows, np.int32)])
+    meta = nnz | (w << 32) | (dbits << 40)
+    assert len(buf) == _fused_words_meta(rows, meta)
+    out = jax.jit(make_decoder(rows, meta))(buf)
+    assert out["ids"].dtype == np.int32 and out["vals"].dtype == np.float32
+    np.testing.assert_array_equal(
+        np.asarray(out["ids"]).view(np.uint32), ids.astype(np.uint32))
+    np.testing.assert_array_equal(
+        np.asarray(out["vals"]).view(np.uint32), vals.view(np.uint32))
 
 
 @pytest.mark.parametrize("id_bits", [0, 10], ids=["v2", "v3"])

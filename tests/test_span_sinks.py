@@ -195,6 +195,8 @@ PATHS = {
                lambda ld: not ld._use_native_pack()),
     "ragged": ({}, dict(ragged=True), lambda ld: ld.ragged),
     "pool": ({}, dict(put_threads=2), lambda ld: ld._use_native_pack()),
+    "compact": ({}, dict(wire_compact=True),
+                lambda ld: ld._use_native_pack()),
 }
 
 
@@ -207,7 +209,7 @@ def _inside(child, parent):
 @pytest.mark.parametrize("path", sorted(PATHS))
 def test_loader_spans_on_every_pack_path(tmp_path, clean_ring, path):
     from dmlc_core_tpu import native
-    if path in ("streampack", "native", "pool") and not native.has_packer():
+    if path not in ("python", "ragged") and not native.has_packer():
         pytest.skip("native packer not built")
     parser_kw, loader_kw, on_path = PATHS[path]
     metrics.reset()
@@ -239,6 +241,15 @@ def test_loader_spans_on_every_pack_path(tmp_path, clean_ring, path):
     put = records("device_loader.put")
     assert len(put) == batches
     assert all(_inside(r, by_id[r["parent_id"]]) for r in put)
+    # a fused batch's put says which wire it rode, (id_width, dict_bits):
+    # (0, 0) is v2; these ids need 13 bits, which the packer rounds to 16
+    wires = {tuple(r["attrs"].get("wire", ())) for r in put}
+    if path in ("python", "ragged"):
+        assert wires == {()}
+    elif path == "compact":
+        assert wires and all(w == 16 for w, _ in wires)
+    else:
+        assert wires == {(0, 0)}
     # the fused paths keep a ring of in-flight batches (or, in pool mode,
     # wait for each); per-array batches are left to JAX and wait for none
     wait_name = "device_loader.pool_wait" if pool else \

@@ -32,6 +32,8 @@ HBM_BYTES = 16 * 10 ** 9           # one v5e chip
 ROWS, NNZ, F, D = 4096, 131072, 1 << 20, 32      # the smoke's shapes
 V2_META = 98304                    # v2 wire: a mid nnz bucket, raw ids
 V3_META = 98304 | (20 << 32)       # compact wire: 20-bit ids, raw f32 vals
+# the benchmark's CTR cells: 24-bit ids, 10-bit dictionary codes
+CTR_META = 163840 | (24 << 32) | (10 << 40)
 
 
 @pytest.fixture(scope="module")
@@ -128,7 +130,7 @@ def case_refused_width(width):
     return run
 
 
-def case_decoder(meta):
+def case_decoder(meta, gathers):
     def run(topo):
         one = SingleDeviceSharding(topo.devices[0])
         buf = jax.ShapeDtypeStruct((_fused_words_meta(ROWS, meta),),
@@ -145,6 +147,11 @@ def case_decoder(meta):
         # segments come from a scatter and a prefix sum: a search over
         # row_ptr would be a loop of scalar gathers (15 ms a batch on a v5e)
         assert " while(" not in text
+        # bit-packed streams unpack with shifts the trace fixes; the one
+        # gather a program may hold is the value dictionary's, whose
+        # indices are data.  Word indices computed from an iota are two
+        # scalar gathers a stream (1.16 ms each on a v5e)
+        assert text.count(" gather(") == gathers
     return run
 
 
@@ -261,8 +268,9 @@ CASES = {
     "refused_width_16": case_refused_width(16),
     "refused_width_32": case_refused_width(32),
     "refused_width_64": case_refused_width(64),
-    "decoder_v2_donated": case_decoder(V2_META),
-    "decoder_compact_donated": case_decoder(V3_META),
+    "decoder_v2_donated": case_decoder(V2_META, gathers=0),
+    "decoder_compact_donated": case_decoder(V3_META, gathers=0),
+    "decoder_compact_dict_donated": case_decoder(CTR_META, gathers=1),
     "fm_train_step_kstep1": case_train_step(1),
     "fm_train_step_kstep8": case_train_step(8),
     "serving_bucket_padded": case_serving_bucket(False),
