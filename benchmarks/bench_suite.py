@@ -910,15 +910,8 @@ def bench_integrity() -> dict:
 
     bits = np.float32(1.0).view(np.int32)          # weights default
 
-    host_cache: dict = {}
-
     def host_sums(path: str, fmt: str, want_fields: bool) -> dict:
-        """Host-side reference checksums, cached per (path, fmt, fields):
-        the flat and rowmajor sub-checks over the same corpus share one
-        parse pass instead of re-checksumming ~64 MB each."""
-        ck = (path, fmt, want_fields)
-        if ck in host_cache:
-            return host_cache[ck]
+        """Host-side reference checksums of one corpus."""
         keys = ("ids", "vals", "labels", "weights") + (
             ("fields",) if want_fields else ())
         host = dict.fromkeys(keys + ("nnz", "rows"), 0)
@@ -947,11 +940,9 @@ def bench_integrity() -> dict:
                 host["rows"] += blk.size
         finally:
             p.close()
-        host_cache[ck] = host
         return host
 
-    def check_one(path: str, fmt: str, want_fields: bool,
-                  layout: str = "flat") -> dict:
+    def check_one(path: str, fmt: str, want_fields: bool) -> dict:
         keys = ("ids", "vals", "labels", "weights") + (
             ("fields",) if want_fields else ())
         host = host_sums(path, fmt, want_fields)
@@ -965,29 +956,21 @@ def bench_integrity() -> dict:
                 out.append(jnp.sum(b["fields"]))
             if "row_ptr" in b:
                 out.append(b["row_ptr"][-1])
-            elif "segments" in b:
+            else:
                 # per-array path ships segments, not row_ptr; padding
                 # entries point at the scratch row (== batch_rows)
                 out.append(jnp.sum(
                     (b["segments"] < b["labels"].shape[0])
                     .astype(jnp.int32)))
-            else:
-                # rowmajor [B, K]: no per-value row structure on device,
-                # so nnz is not device-derivable — this sentinel is
-                # dropped by zip(keys, s) and nnz is EXCLUDED from the
-                # mismatch compare for this layout (nnz_keys below); the
-                # reported nnz is the host-side count
-                out.append(jnp.int32(-1))
             return tuple(out)
 
         dev = dict.fromkeys(keys + ("nnz",), 0)
-        # nnz_cap (= K per row in rowmajor) sized so no row is truncated
-        # anywhere: host ref has no truncation
-        nnz_cap = 64 if layout == "rowmajor" else 262144
+        # nnz_cap sized so no row is truncated anywhere: host ref has no
+        # truncation
         loader = DeviceLoader(create_parser(f"file://{path}", 0, 1, fmt),
-                              batch_rows=4096, nnz_cap=nnz_cap, prefetch=4,
+                              batch_rows=4096, nnz_cap=262144, prefetch=4,
                               put_threads=4, wire_compact=not want_fields,
-                              fields=want_fields, layout=layout)
+                              fields=want_fields)
         try:
             for b in loader:
                 s = [int(np.asarray(x)) for x in batch_sums(b)]
@@ -998,9 +981,8 @@ def bench_integrity() -> dict:
         finally:
             loader.close()
 
-        nnz_keys = () if layout == "rowmajor" else ("nnz",)
         mismatch = {k: {"host": host[k], "device": dev[k]}
-                    for k in keys + nnz_keys if host[k] != dev[k]}
+                    for k in keys + ("nnz",) if host[k] != dev[k]}
         if rows != host["rows"]:
             mismatch["rows"] = {"host": host["rows"], "device": rows}
         out = {"ok": not mismatch, "rows": host["rows"],
@@ -1013,15 +995,11 @@ def bench_integrity() -> dict:
     libfm = "/tmp/bench_suite.libfm"
     _gen_libsvm(libsvm)
     _gen_libsvm(libfm, libfm=True)
-    # three sub-checks cover every transfer path a consumer can
-    # configure: fused compact wire (libsvm flat), per-array fields path
-    # (libfm, fields=True — field arrays bypass the fused wire by
-    # design), and the rowmajor [B, K] layout the embedding-bag engines
-    # consume (nnz not device-derivable there; value sums still exact)
+    # two sub-checks cover both transfer paths a consumer can configure:
+    # the fused compact wire (libsvm) and the per-array fields path
+    # (libfm, fields=True — field arrays bypass the fused wire by design)
     res = {"libsvm_compact": check_one(libsvm, "libsvm", False),
-           "libfm_fields": check_one(libfm, "libfm", True),
-           "libsvm_rowmajor": check_one(libsvm, "libsvm", False,
-                                        layout="rowmajor")}
+           "libfm_fields": check_one(libfm, "libfm", True)}
     ok = all(v["ok"] for v in res.values())
     return {"metric": "ingest_integrity", "value": 1.0 if ok else 0.0,
             "unit": "ok", "paths": res}

@@ -4,7 +4,9 @@ Records to benchmarks/TPU_MICRO.json:
   * platform + device kind (proof of TPU execution)
   * bf16 matmul sustained TFLOP/s (MXU utilisation sanity)
   * host→device bandwidth for the fused int32 ingest buffer
-  * embed_bag_pallas vs embed_bag_reference wall-clock across K regimes
+  * the compact wire's decode alone, and beside its consumer in one and two
+    dispatches
+  * sequence-parallel attention and the GPipe tick on a one-device mesh
 
 Usage: python benchmarks/tpu_micro.py [out.json]
 Exits nonzero when JAX finds no accelerator.
@@ -186,183 +188,6 @@ def main() -> int:
         result[f"h2d_{mb}mb_gbps"] = round(mb / 1024 / dt, 3)
         log(f"h2d {mb}MB: {result[f'h2d_{mb}mb_gbps']} GB/s")
 
-    # Kernel timings use the same chained discipline as the matmul: each
-    # step's vals carry a tiny dependence on the previous output, so the
-    # runtime cannot dedupe or overlap the executions (r02's independent
-    # dispatches read 19us for a 1GB gather — off by orders of magnitude).
-    chain_steps = 8
-
-    def timed_chained(f, ids, vals, table, outs=1):
-        @jax.jit
-        def run(v0, ids, table):
-            def body(_, v):
-                # ids must depend on the carry too, or XLA hoists the
-                # (loop-invariant) gather out of the chain and the timing
-                # measures only the reduction.  The predicate is never
-                # true, so the actual indices are unchanged.
-                bump = (v[:, :1] > jnp.float32(1e30)).astype(jnp.int32)
-                out = f(ids + bump, v, table)
-                # the carry must consume EVERY output column: r04's
-                # out[:, :1] carry let XLA dead-code-eliminate the other
-                # 127 gather columns and read 2.8us for a 16MB gather
-                if outs > 1:
-                    lead = (out[0].sum(axis=1, keepdims=True)
-                            + out[1].sum(axis=1, keepdims=True))
-                else:
-                    lead = out.sum(axis=1, keepdims=True)
-                # the perturbation must survive f32 addition: 1e-30*lead
-                # underflows below ulp(1.0)~1.2e-7 and makes the carry a
-                # bitwise identity, re-enabling the dispatch dedupe this
-                # feedback exists to defeat.  1e-6*lead (~1e-5 at these
-                # magnitudes) actually changes v while leaving the timed
-                # math unaffected.
-                return v + lead * jnp.float32(1e-6)
-            return jax.lax.fori_loop(0, chain_steps, body, v0)
-        return timed_fb(run, vals, ids, table, iters=3) / chain_steps
-
-    # --- embed_bag: pallas vs XLA across K regimes (VERDICT #10) ---
-    try:
-        from dmlc_core_tpu.ops.pallas_embed import (embed_bag_pallas,
-                                                    embed_bag_reference)
-        vocab, dim, rows = 100_000, 128, 4096
-        key = jax.random.PRNGKey(0)
-        table = jax.random.normal(key, (vocab, dim), jnp.float32)
-
-        # Correctness gate reference: einsum at HIGHEST precision (full-f32
-        # MXU passes).  The production XLA path uses default precision,
-        # which at K>=64 lowers to bf16-mantissa MXU passes — the 03:14
-        # window showed it drifting ~bf16-eps·sqrt(K) from exact (max abs
-        # 0.067 at K=64), so gating the f32-accumulating pallas kernel
-        # against DEFAULT-precision XLA at 2e-4 rejected a correct kernel.
-        @jax.jit
-        def embed_exact(ids, vals, table):
-            return jnp.einsum("bk,bkd->bd", vals, table[ids],
-                              precision=jax.lax.Precision.HIGHEST)
-
-        pallas_vs_xla = {}
-        for k in (8, 64, 512):
-            ids = jax.random.randint(key, (rows, k), 0, vocab, jnp.int32)
-            vals = jnp.ones((rows, k), jnp.float32)
-            t_ref = timed_chained(embed_bag_reference, ids, vals, table)
-            exact = np.asarray(embed_exact(ids, vals, table))
-            # record (not gate) the production path's precision drift
-            xla_dev = float(np.max(np.abs(
-                np.asarray(embed_bag_reference(ids, vals, table)) - exact)))
-            try:
-                # correctness before speed: the kernel must match the
-                # exact-precision reference before its timing means
-                # anything (1e-4: f32 accumulation-order slop only)
-                np.testing.assert_allclose(
-                    np.asarray(embed_bag_pallas(ids, vals, table)),
-                    exact, rtol=1e-4, atol=1e-4)
-                t_pal = timed_chained(embed_bag_pallas, ids, vals, table)
-            except Exception as e:  # mosaic compile failure etc.
-                t_pal = None
-                log(f"pallas K={k} failed: {type(e).__name__}: {e}")
-            pallas_vs_xla[str(k)] = {
-                "xla_us": round(t_ref * 1e6, 1),
-                "pallas_us": (round(t_pal * 1e6, 1)
-                              if t_pal is not None else None),
-                "xla_maxdev_vs_exact": round(xla_dev, 5),
-            }
-            log(f"embed_bag K={k}: xla {t_ref*1e6:.0f}us "
-                f"pallas {t_pal*1e6:.0f}us" if t_pal is not None else
-                f"embed_bag K={k}: xla {t_ref*1e6:.0f}us pallas FAILED")
-        result["embed_bag_pallas_vs_xla"] = pallas_vs_xla
-    except Exception as e:  # noqa: BLE001
-        result["embed_bag_error"] = f"{type(e).__name__}: {e}"
-        log(f"embed_bag bench failed: {e}")
-
-    # --- fused FM two-output kernel (the one FactorizationMachine uses) ---
-    try:
-        from dmlc_core_tpu.ops.pallas_embed import fm_terms_pallas
-
-        def fm_xla(ids, vals, table):
-            g = table[ids]
-            return (jnp.einsum("bk,bkd->bd", vals, g),
-                    jnp.einsum("bk,bkd->bd", vals * vals, g * g))
-
-        @jax.jit
-        def fm_exact(ids, vals, table):
-            g = table[ids]
-            hi = jax.lax.Precision.HIGHEST
-            return (jnp.einsum("bk,bkd->bd", vals, g, precision=hi),
-                    jnp.einsum("bk,bkd->bd", vals * vals, g * g,
-                               precision=hi))
-
-        fm_vs = {}
-        for k in (8, 64):
-            ids = jax.random.randint(key, (rows, k), 0, vocab, jnp.int32)
-            vals = jnp.ones((rows, k), jnp.float32)
-            t_ref = timed_chained(fm_xla, ids, vals, table, outs=2)
-            r_x = fm_exact(ids, vals, table)
-            # production default-precision drift vs exact, worst of the
-            # two outputs (same signal xla_maxdev_vs_exact records for
-            # embed_bag — a regression here must not hide in the gate)
-            fm_dev = max(
-                float(np.max(np.abs(np.asarray(a) - np.asarray(b))))
-                for a, b in zip(fm_xla(ids, vals, table), r_x))
-            try:
-                r_p = fm_terms_pallas(ids, vals, table)
-                for a, b in zip(r_p, r_x):
-                    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                               rtol=1e-4, atol=1e-4)
-                t_pal = timed_chained(fm_terms_pallas, ids, vals, table,
-                                      outs=2)
-            except Exception as e:  # mosaic compile failure etc.
-                t_pal = None
-                log(f"fm_terms pallas K={k} failed: {type(e).__name__}: {e}")
-            fm_vs[str(k)] = {
-                "xla_us": round(t_ref * 1e6, 1),
-                "pallas_us": (round(t_pal * 1e6, 1)
-                              if t_pal is not None else None),
-                "xla_maxdev_vs_exact": round(fm_dev, 5),
-            }
-            log(f"fm_terms K={k}: xla {t_ref*1e6:.0f}us "
-                f"pallas {t_pal*1e6:.0f}us" if t_pal is not None else
-                f"fm_terms K={k}: xla {t_ref*1e6:.0f}us pallas FAILED")
-        result["fm_terms_pallas_vs_xla"] = fm_vs
-    except Exception as e:  # noqa: BLE001
-        result["fm_terms_error"] = f"{type(e).__name__}: {e}"
-        log(f"fm_terms bench failed: {e}")
-
-    # --- D-sweep (VERDICT r4 #7): the last plausible Mosaic-win shape.
-    # The r4 verdict on the DMA kernel was latency-bound 512-byte row
-    # fetches (D=128 f32); D=512 quadruples the bytes per DMA, the regime
-    # where a deep ring could finally pay.  One shape, gated on
-    # correctness like the others — this either finds the win or closes
-    # the kernel line with hardware evidence at the most favourable shape.
-    try:
-        dim2 = 512
-        table2 = jax.random.normal(key, (vocab, dim2), jnp.float32)
-        ids2 = jax.random.randint(key, (rows, 8), 0, vocab, jnp.int32)
-        vals2 = jnp.ones((rows, 8), jnp.float32)
-
-        @jax.jit
-        def embed_exact2(ids, vals, table):
-            return jnp.einsum("bk,bkd->bd", vals, table[ids],
-                              precision=jax.lax.Precision.HIGHEST)
-
-        t_ref = timed_chained(embed_bag_reference, ids2, vals2, table2)
-        try:
-            np.testing.assert_allclose(
-                np.asarray(embed_bag_pallas(ids2, vals2, table2)),
-                np.asarray(embed_exact2(ids2, vals2, table2)),
-                rtol=1e-4, atol=1e-4)
-            t_pal = timed_chained(embed_bag_pallas, ids2, vals2, table2)
-        except Exception as e:  # noqa: BLE001
-            t_pal = None
-            log(f"pallas D=512 failed: {type(e).__name__}: {e}")
-        result["embed_bag_D512_K8"] = {
-            "xla_us": round(t_ref * 1e6, 1),
-            "pallas_us": round(t_pal * 1e6, 1) if t_pal is not None else None,
-        }
-        log(f"embed_bag D=512 K=8: xla {t_ref*1e6:.0f}us pallas "
-            + (f"{t_pal*1e6:.0f}us" if t_pal is not None else "FAILED"))
-    except Exception as e:  # noqa: BLE001
-        result["embed_bag_D512_error"] = f"{type(e).__name__}: {e}"
-        log(f"embed_bag D512 bench failed: {e}")
-
     # --- wire-v3 decode: cost + fusion headroom (VERDICT r4 #7) ---
     # The proposed fused decode+gather Mosaic kernel can win AT MOST
     # (decode cost) + (two-dispatch - fused-jit gap): the first is what a
@@ -380,7 +205,8 @@ def main() -> int:
 
         decode = make_decoder(rows_w, meta)
         decode_j = jax.jit(decode)
-        table16 = jax.random.normal(key, (1 << wbits, 16), jnp.float32)
+        table16 = jax.random.normal(jax.random.PRNGKey(0), (1 << wbits, 16),
+                                    jnp.float32)
 
         def consume(d):
             return fm_pairwise(d["ids"], d["vals"], d["segments"], table16,

@@ -535,14 +535,13 @@ def phase_serve(corpus: str, sz: Sizes, ckpt: str, preds: np.ndarray) -> None:
 def phase_kernels(sz: Sizes, seed: int) -> None:
     """The ragged gather kernels at the width the engine rule sends to
     Pallas on a TPU (``ops.ragged_csr``; users reach them through
-    ``embed.table`` and the rowmajor layouts): ``engine="auto"`` must
-    resolve as the rule says, the program must hold ``tpu_custom_call``
-    exactly when it says Pallas, and the result must match XLA's."""
+    ``embed.table``): ``engine="auto"`` must resolve as the rule says, the
+    program must hold ``tpu_custom_call`` exactly when it says Pallas, and
+    the result must match XLA's."""
     import jax
     import jax.numpy as jnp
 
     from dmlc_core_tpu.ops import ragged_csr
-    from dmlc_core_tpu.ops.pallas_embed import mosaic_row_dma_ok
 
     D, F = KERNEL_DIM, sz.kernel_features
     rows, cap = sz.batch_rows, sz.nnz_cap
@@ -557,7 +556,7 @@ def phase_kernels(sz: Sizes, seed: int) -> None:
                      ("ragged_fm_pairwise", ragged_csr.ragged_fm_pairwise)):
         facts: dict = {}
         with phase(f"kernel {name} D={D}", facts):
-            want = "pallas" if on_tpu and mosaic_row_dma_ok(D) else "xla"
+            want = "pallas" if on_tpu and ragged_csr.mosaic_row_dma_ok(D) else "xla"
             engine = ragged_csr._resolve_engine("auto", D)
             if engine != want:
                 raise AssertionError(f"{name}: auto -> {engine}, rule "
@@ -582,7 +581,7 @@ def phase_kernels(sz: Sizes, seed: int) -> None:
     # and the rule's other side, at the width the smoke trains
     say(f"[kernel] engine rule at D={sz.dim}: "
         f"{ragged_csr._resolve_engine('auto', sz.dim)} "
-        f"(mosaic_row_dma_ok={mosaic_row_dma_ok(sz.dim)})")
+        f"(mosaic_row_dma_ok={ragged_csr.mosaic_row_dma_ok(sz.dim)})")
 
 
 def run_one_chip(work: str, sz: Sizes, seed: int) -> None:

@@ -15,8 +15,6 @@ wants: the sparse gather happens once, every cross layer is dense compute.
 TPU formulation: the L cross layers run as one ``lax.scan`` over stacked
 ``[L, D, D]`` weights (same compiled-once pattern as DeepFM's tower —
 ``deep.py _tower_sequential``), so depth never unrolls into L XLA ops.
-Both batch layouts are first-class, matching the rest of the family:
-flat CSR (segment-sum path) and row-padded (embedding-bag path).
 """
 
 from __future__ import annotations
@@ -26,7 +24,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from .sparse import Params, _is_rowmajor, _rowmajor_matvec, task_loss
+from .sparse import Params, task_loss
 from ..ops.csr import csr_dense_matvec, csr_embed_sum
 
 __all__ = ["DCNv2"]
@@ -36,20 +34,18 @@ class DCNv2:
     """Cross network (v2, full-matrix) + linear wide term.
 
     ``layers`` is the cross depth (each layer captures one higher
-    interaction order).  ``engine`` selects the row-major embedding-bag
-    engine like the rest of the family ("auto" = XLA; pallas opt-in).
+    interaction order).
     """
 
     def __init__(self, num_features: int, dim: int = 16, layers: int = 3,
                  l2: float = 0.0, init_scale: float = 0.01,
-                 task: str = "binary", engine: str = "auto"):
+                 task: str = "binary"):
         self.num_features = num_features
         self.dim = dim
         self.layers = layers
         self.l2 = l2
         self.init_scale = init_scale
         self.task = task
-        self.engine = engine
 
     def init(self, rng: jax.Array) -> Params:
         k1, k2, k3 = jax.random.split(rng, 3)
@@ -74,14 +70,8 @@ class DCNv2:
         }
 
     def _embed(self, params: Params, batch: Dict[str, jax.Array]):
-        """(linear[B], x0[B,D]) for either batch layout — one sparse
-        gather; everything after is dense."""
-        if _is_rowmajor(batch):
-            from ..ops.pallas_embed import embed_bag
-            linear = _rowmajor_matvec(batch, params["w"])
-            x0 = embed_bag(batch["ids"], batch["vals"], params["v"],
-                           engine=self.engine)
-            return linear, x0
+        """(linear[B], x0[B,D]) — one sparse gather; everything after is
+        dense."""
         num_rows = batch["labels"].shape[0]
         ids, vals, segs = batch["ids"], batch["vals"], batch["segments"]
         linear = csr_dense_matvec(ids, vals, segs, params["w"], num_rows)

@@ -19,7 +19,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from .sparse import Params, _is_rowmajor, _rowmajor_matvec, task_loss
+from .sparse import Params, task_loss
 from ..ops.csr import csr_dense_matvec, csr_embed_sum
 
 __all__ = ["DeepFM"]
@@ -45,14 +45,13 @@ class DeepFM:
 
     def __init__(self, num_features: int, dim: int = 16, layers: int = 2,
                  l2: float = 0.0, init_scale: float = 0.01,
-                 task: str = "binary", engine: str = "auto"):
+                 task: str = "binary"):
         self.num_features = num_features
         self.dim = dim
         self.layers = layers
         self.l2 = l2
         self.init_scale = init_scale
         self.task = task
-        self.engine = engine
         self._tower = _tower_sequential
 
     def with_pipelined_tower(self, mesh, axis: str = "pp",
@@ -70,7 +69,7 @@ class DeepFM:
             return run(tower, xs).reshape(h.shape)
 
         clone = DeepFM(self.num_features, self.dim, self.layers, self.l2,
-                       self.init_scale, self.task, self.engine)
+                       self.init_scale, self.task)
         clone._tower = tower_pp
         return clone
 
@@ -95,13 +94,7 @@ class DeepFM:
         }
 
     def _terms(self, params: Params, batch: Dict[str, jax.Array]):
-        """(linear[B], s1[B,D], s2[B,D]) for either batch layout."""
-        if _is_rowmajor(batch):
-            from ..ops.pallas_embed import fm_embed_terms
-            linear = _rowmajor_matvec(batch, params["w"])
-            s1, s2 = fm_embed_terms(batch["ids"], batch["vals"],
-                                    params["v"], engine=self.engine)
-            return linear, s1, s2
+        """(linear[B], s1[B,D], s2[B,D])."""
         num_rows = batch["labels"].shape[0]
         ids, vals, segs = batch["ids"], batch["vals"], batch["segments"]
         linear = csr_dense_matvec(ids, vals, segs, params["w"], num_rows)

@@ -20,10 +20,9 @@ and the pairwise term is half that.  Cost: one [·, nf, d] gather of the
 factor table plus an einsum over [B, nf, nf, d] — choose ``num_fields``
 accordingly (G is B·nf²·d floats; typical CTR data has nf ≲ 40).
 
-Both batch layouts are supported, matching the rest of the model family:
-flat CSR (``ids/vals/fields[nnz] + segments``) and row-padded
-(``ids/vals/fields[B, K]``).  Padding entries carry id 0, val 0, field 0 —
-zero value means they contribute nothing to any sum.
+Batches are flat CSR like the rest of the model family's
+(``ids/vals/fields[nnz] + segments``).  Padding entries carry id 0, val 0,
+field 0 — zero value means they contribute nothing to any sum.
 """
 
 from __future__ import annotations
@@ -33,7 +32,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from .sparse import Params, _is_rowmajor, _rowmajor_matvec, task_loss
+from .sparse import Params, task_loss
 from ..ops.csr import csr_dense_matvec
 
 __all__ = ["FieldAwareFM"]
@@ -76,18 +75,6 @@ class FieldAwareFM:
         }
 
     # -- pairwise term ----------------------------------------------------
-    def _pair_rowmajor(self, params: Params, ids, vals, fields) -> jax.Array:
-        nf = self.num_fields
-        f = jnp.clip(fields, 0, nf - 1)
-        V = params["v"][ids]                       # [B, K, nf, d]
-        onehot = jax.nn.one_hot(f, nf, dtype=vals.dtype)   # [B, K, nf]
-        G = jnp.einsum("bk,bkg,bkfd->bgfd", vals, onehot, V)
-        cross = jnp.einsum("bgfd,bfgd->b", G, G)
-        own = jnp.take_along_axis(
-            V, f[:, :, None, None], axis=2)[:, :, 0, :]    # [B, K, d]
-        diag = jnp.sum((vals * vals)[..., None] * own * own, axis=(1, 2))
-        return 0.5 * (cross - diag)
-
     def _pair_flat(self, params: Params, ids, vals, fields, segments,
                    num_rows: int) -> jax.Array:
         nf = self.num_fields
@@ -111,11 +98,6 @@ class FieldAwareFM:
     # -- public surface ---------------------------------------------------
     def forward(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
         fields = _check_fields(batch)
-        if _is_rowmajor(batch):
-            linear = _rowmajor_matvec(batch, params["w"])
-            pair = self._pair_rowmajor(params, batch["ids"], batch["vals"],
-                                       fields)
-            return params["w0"] + linear + pair
         num_rows = batch["labels"].shape[0]
         linear = csr_dense_matvec(batch["ids"], batch["vals"],
                                   batch["segments"], params["w"], num_rows)

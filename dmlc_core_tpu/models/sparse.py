@@ -26,20 +26,6 @@ __all__ = ["SparseLogReg", "FactorizationMachine", "weighted_bce",
 Params = Dict[str, jax.Array]
 
 
-def _is_rowmajor(batch: Dict[str, jax.Array]) -> bool:
-    """Both batch layouts are first-class: flat CSR (``ids[nnz]`` +
-    ``segments``) feeds the XLA segment-sum ops; row-padded ``ids[B,K]``
-    (``DeviceLoader(layout='rowmajor')``) feeds the Pallas embedding-bag
-    kernel."""
-    return batch["ids"].ndim == 2
-
-
-def _rowmajor_matvec(batch: Dict[str, jax.Array], w: jax.Array) -> jax.Array:
-    # per-row sparse dot with a 1-D weight vector: the gather is [B,K] —
-    # tiny next to the factor table — so XLA handles it on every engine
-    return jnp.einsum("bk,bk->b", batch["vals"], w[batch["ids"]])
-
-
 def weighted_bce(logits: jax.Array, labels: jax.Array,
                  weights: jax.Array) -> jax.Array:
     """Per-example-weighted binary cross-entropy on {0,1} or {-1,1} labels.
@@ -73,7 +59,7 @@ def task_loss(out: jax.Array, batch: Dict[str, jax.Array], task: str,
 
 
 class SparseLogReg:
-    """w·x + b over flat-CSR or rowmajor batches (the reference ecosystem's
+    """w·x + b over flat-CSR batches (the reference ecosystem's
     canonical linear-model consumer — xgboost/mxnet read RowBlocks the same
     way)."""
 
@@ -88,8 +74,6 @@ class SparseLogReg:
         }
 
     def forward(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
-        if _is_rowmajor(batch):
-            return _rowmajor_matvec(batch, params["w"]) + params["b"]
         num_rows = batch["labels"].shape[0]
         z = csr_dense_matvec(batch["ids"], batch["vals"], batch["segments"],
                              params["w"], num_rows)
@@ -110,14 +94,12 @@ class FactorizationMachine:
     """
 
     def __init__(self, num_features: int, dim: int = 16, l2: float = 0.0,
-                 init_scale: float = 0.01, task: str = "binary",
-                 engine: str = "auto"):
+                 init_scale: float = 0.01, task: str = "binary"):
         self.num_features = num_features
         self.dim = dim
         self.l2 = l2
         self.init_scale = init_scale
         self.task = task
-        self.engine = engine
 
     def init(self, rng: jax.Array) -> Params:
         return {
@@ -128,16 +110,6 @@ class FactorizationMachine:
         }
 
     def forward(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
-        if _is_rowmajor(batch):
-            # the factor-table gathers are the hot op: one fused kernel
-            # yields BOTH FM reductions per gathered row (pallas on TPU);
-            # imported lazily so flat-CSR users never touch pallas machinery
-            from ..ops.pallas_embed import fm_embed_terms
-            linear = _rowmajor_matvec(batch, params["w"])
-            s1, s2 = fm_embed_terms(batch["ids"], batch["vals"],
-                                    params["v"], engine=self.engine)
-            pair = 0.5 * jnp.sum(s1 * s1 - s2, axis=-1)
-            return params["w0"] + linear + pair
         num_rows = batch["labels"].shape[0]
         linear = csr_dense_matvec(batch["ids"], batch["vals"],
                                   batch["segments"], params["w"], num_rows)

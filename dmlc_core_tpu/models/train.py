@@ -126,8 +126,8 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
     bs = batch_sharding(mesh)
     # params/opt_state shardings are inferred from the input arrays
     # themselves (shard_params places them); the batch is pinned as a
-    # pytree PREFIX so both layouts (flat CSR and rowmajor) shard their
-    # leading batch/nnz axis over 'dp' without key-set coupling
+    # pytree PREFIX so every array of it shards its leading batch/nnz
+    # axis over 'dp' without key-set coupling
     return jax.jit(
         step,
         in_shardings=(None, None, bs),

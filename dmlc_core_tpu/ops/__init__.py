@@ -8,8 +8,6 @@ from .csr import csr_dense_matvec, csr_embed_sum, fm_pairwise  # noqa: F401
 # would shadow the function). Import them from the submodule:
 #   from dmlc_core_tpu.ops.ring_attention import ring_attention
 __all__ = ["csr_dense_matvec", "csr_embed_sum", "fm_pairwise",
-           "embed_bag", "embed_bag_pallas", "embed_bag_reference",
-           "fm_embed_terms",
            "ragged_segment_sum", "ragged_dense_matvec",
            "ragged_embed_sum", "ragged_fm_pairwise",
            "mask_ragged", "mask_batch",
@@ -22,10 +20,6 @@ def __getattr__(name):
     # needed for the pure-XLA paths
     import importlib
     lazy = {
-        "embed_bag": "pallas_embed",
-        "embed_bag_pallas": "pallas_embed",
-        "fm_embed_terms": "pallas_embed",
-        "embed_bag_reference": "pallas_embed",
         "ragged_segment_sum": "ragged_csr",
         "ragged_dense_matvec": "ragged_csr",
         "ragged_embed_sum": "ragged_csr",

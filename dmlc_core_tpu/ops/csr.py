@@ -11,10 +11,6 @@ jit-friendly: static shapes, no data-dependent control flow.
 * :func:`fm_pairwise`      — factorization-machine second-order term via the
   (Σ)²−Σ() identity, MXU/VPU-friendly.
 
-The Pallas TPU kernel for the embedding-bag hot path lives in
-:mod:`dmlc_core_tpu.ops.pallas_embed`; these lax/XLA versions are the
-reference semantics and the CPU/interpret fallback.
-
 Every gather runs under ``jax.named_scope("csr_gather")`` and every row
 reduction under ``csr_segment_sum``: the names ride each HLO op's
 ``op_name`` (the backward scatter carries ``transpose(jvp(csr_gather))``),

@@ -23,19 +23,18 @@ Two engines, same semantics:
   sweep in ``tests/test_ragged.py`` asserts ``array_equal``, not just
   allclose.  The tail is still *reduced* (full-capacity FLOPs), so this
   engine retires the host/wire tax but not the device FLOPs.
-* **pallas** — a DMA-ring gather kernel (the :mod:`.pallas_embed`
-  ring, re-targeted at the flat layout) whose per-entry work is
-  predicated on ``i < nnz_used``: tail entries issue **no DMA and no
-  FLOP**, so the device cost tracks true nnz.  Chunked pallas_calls
-  keep the ids/segments/vals scalar prefetch under the SMEM budget
-  proven on hardware (``pallas_embed._SMEM_SCALARS_CAP``); partial
+* **pallas** — a DMA-ring gather kernel over the flat layout whose
+  per-entry work is predicated on ``i < nnz_used``: tail entries issue
+  **no DMA and no FLOP**, so the device cost tracks true nnz.  Chunked
+  pallas_calls keep the ids/segments/vals scalar prefetch under an SMEM
+  budget (``_SMEM_SCALARS_CAP``); partial
   per-chunk accumulators are summed outside, so the pallas result is
   allclose (not bit-identical — different summation order).
 
 Engine selection is a stated function of backend and shape: ``auto``
 resolves to pallas on a TPU backend when the table width is a multiple
-of the 128-lane tile (``pallas_embed.mosaic_row_dma_ok`` — Mosaic refuses
-the per-row DMA at any other width) and to xla otherwise;
+of the 128-lane tile (:func:`mosaic_row_dma_ok` — Mosaic refuses the
+per-row DMA at any other width) and to xla otherwise;
 ``DMLC_RAGGED_ENGINE=xla|pallas`` pins globally.  A pinned or explicit
 ``pallas`` is handed to the compiler as is: at a width Mosaic refuses,
 its error reaches the caller — nothing downgrades quietly.  The
@@ -54,18 +53,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_embed import mosaic_row_dma_ok
-
 __all__ = ["ragged_segment_sum", "ragged_dense_matvec", "ragged_embed_sum",
            "ragged_embed_grad", "ragged_fm_pairwise", "mask_ragged",
            "mask_batch"]
 
-# DMA ring depth + per-operand SMEM scalar budget: the values proven on
-# hardware by pallas_embed (TPU_MICRO_r04) — this module ships THREE
-# scalar operands (ids, segments, vals) where pallas_embed ships two, so
-# the per-operand cap keeps the same total headroom margin.
+# DMA ring depth (a single ~512 B row DMA in flight is latency-bound) and
+# the per-operand cap on scalar-prefetched entries: three operands (ids,
+# segments, vals) share the 1 MB of SMEM a v5e core has, 384 KB at this
+# cap; a longer batch is split into chunks of this many entries.
 _SLOTS = 8
 _SMEM_SCALARS_CAP = 32768
+
+# f32 lane tile of the TPU's HBM/VMEM layout.  The kernel fetches one table
+# row per DMA (``table_ref.at[pl.ds(idx, 1), :]``), and Mosaic refuses a
+# slice of a tiled HBM ref whose minor dimension is not a whole number of
+# lane tiles ("Slice shape along dimension 1 must be aligned to tiling
+# (128)") — tests/test_tpu_compile.py compiles both sides of this rule for
+# a described v5e.
+_LANES = 128
+
+
+def mosaic_row_dma_ok(D: int) -> bool:
+    """The engine rule's shape half: the per-row DMA kernel lowers on Mosaic
+    only when the embedding width is a multiple of the 128-lane tile.
+    ``engine="auto"`` sends every other width to XLA; an explicit
+    ``engine="pallas"`` (or the env pin) at such a width is passed to the
+    compiler, whose error reaches the caller."""
+    return D % _LANES == 0
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """Host→HBM staging pipeline (TPU-native consumer side of the ingest ladder)."""
 
-from .packing import pack_flat, pack_rowmajor, batch_slices, PackStats  # noqa: F401
+from .packing import pack_flat, batch_slices, PackStats  # noqa: F401
 from .device_loader import DeviceLoader  # noqa: F401
 from .ingest_service import (serve_ingest, RemoteIngestLoader,  # noqa: F401
                              ingest_worker_main)
@@ -12,7 +12,7 @@ from .fingerprint import autotune_key, host_shape  # noqa: F401
 from .data_service import (Dispatcher, DataServiceWorker,  # noqa: F401
                            DataServiceLoader)
 
-__all__ = ["pack_flat", "pack_rowmajor", "batch_slices", "PackStats",
+__all__ = ["pack_flat", "batch_slices", "PackStats",
            "serve_ingest", "RemoteIngestLoader", "ingest_worker_main",
            "DeviceLoader", "PageCacheReader", "PageCacheWriter",
            "open_page_reader", "page_path",

@@ -53,7 +53,7 @@ from ..utils.parameter import parse_lenient_bool
 from . import fingerprint as fingerprint_mod
 from . import page_cache
 from .packing import (PackStats, batch_slices, pack_flat, pack_ragged,
-                      pack_rowmajor, ragged_slices)
+                      ragged_slices)
 
 __all__ = ["DeviceLoader", "make_decoder"]
 
@@ -415,9 +415,7 @@ class DeviceLoader:
     ----------
     source:        ParserBase or RowBlockIter (anything yielding RowBlocks).
     batch_rows:    rows per device batch (static shape).
-    nnz_cap:       flat layout: value capacity per batch; rowmajor layout:
-                   per-row capacity ``k_cap``.
-    layout:        'flat' (segment-sum ops) or 'rowmajor' (pallas kernel).
+    nnz_cap:       value capacity per batch.
     sharding:      optional ``jax.sharding.NamedSharding`` for the batch
                    arrays (batch axis over 'dp' typically).
     prefetch:      device batches to keep in flight (double buffer = 2).
@@ -448,8 +446,8 @@ class DeviceLoader:
                    (``("fused", buf, meta, rows)``) without touching any
                    device — the producer side of the disaggregated ingest
                    service (:mod:`dmlc_core_tpu.pipeline.ingest_service`).
-                   Requires the fused path (flat layout, no sharding, no
-                   fields).  Recycle consumed buffers via ``recycle(buf)``.
+                   Requires the fused path (no sharding, no fields).
+                   Recycle consumed buffers via ``recycle(buf)``.
     ragged:        pack by **cumulative true nnz** against ``nnz_cap``
                    instead of padding every batch to it: batches keep the
                    flat-CSR capacity shapes but carry ``nnz_used`` /
@@ -457,8 +455,8 @@ class DeviceLoader:
                    (``pack_ragged``) — consumers mask via
                    ``ops.ragged_csr`` (``mask_batch``) or the ragged
                    kernels.  Never truncates: a row that alone exceeds
-                   ``nnz_cap`` raises.  Requires the flat layout and no
-                   sharding, forces the python per-array path (the fused
+                   ``nnz_cap`` raises.  Requires no sharding, forces the
+                   python per-array path (the fused
                    wire formats carry no prefix words), and disables the
                    page cache (fused-path only; the ``ragged``
                    fingerprint field keeps stale padded pages from ever
@@ -482,7 +480,6 @@ class DeviceLoader:
     """
 
     def __init__(self, source, batch_rows: int, nnz_cap: int,
-                 layout: str = "flat",
                  sharding: Optional[jax.sharding.Sharding] = None,
                  prefetch: int = 2, drop_remainder: bool = False,
                  id_mod: int = 0, put_threads="auto",
@@ -490,20 +487,19 @@ class DeviceLoader:
                  emit: str = "device", cache="auto",
                  ragged: bool = False, cache_queue_pages: int = 0,
                  cache_readahead: Optional[int] = None):
-        check(layout in ("flat", "rowmajor"), f"bad layout {layout!r}")
         check(emit in ("device", "host"), f"bad emit {emit!r}")
         if ragged:
-            check(layout == "flat" and sharding is None,
-                  "ragged=True requires the flat layout and no sharding "
+            check(sharding is None,
+                  "ragged=True requires no sharding "
                   "(prefix scalars don't shard over a batch axis)")
             check(emit == "device",
                   "ragged=True is incompatible with emit='host' (the "
                   "fused wire layouts carry no nnz_used prefix)")
         self.ragged = bool(ragged)
         if emit == "host":
-            check(layout == "flat" and sharding is None and not fields,
+            check(sharding is None and not fields,
                   "emit='host' requires the fused path "
-                  "(flat layout, no sharding, no fields)")
+                  "(no sharding, no fields)")
         from .tuned import resolve as _resolve_tuned
         put_threads, wire_compact = _resolve_tuned(
             jax.default_backend(), put_threads, wire_compact)
@@ -511,7 +507,6 @@ class DeviceLoader:
         self.source = source
         self.batch_rows = batch_rows
         self.nnz_cap = nnz_cap
-        self.layout = layout
         self.sharding = sharding
         self.drop_remainder = drop_remainder
         self.id_mod = id_mod
@@ -565,9 +560,8 @@ class DeviceLoader:
 
     def _use_native_pack(self) -> bool:
         from .. import native
-        return (self.layout == "flat" and self.sharding is None
-                and not self.fields and not self.ragged
-                and native.has_packer())
+        return (self.sharding is None and not self.fields
+                and not self.ragged and native.has_packer())
 
     def _use_streampack(self) -> bool:
         """Fused native parse→pack: text chunks straight into wire batches,
@@ -595,15 +589,15 @@ class DeviceLoader:
     def _resolve_cache(self, cache) -> Optional[str]:
         if cache in (None, False, ""):
             return None
-        fused = (self.layout == "flat" and self.sharding is None
-                 and not self.fields and not self.ragged)
+        fused = (self.sharding is None and not self.fields
+                 and not self.ragged)
         if cache == "auto":
             if not fused:
                 return None
             cf = self._src_attr("cache_file")
             return page_cache.page_path(cf) if cf else None
         check(fused, "cache= requires the fused path "
-                     "(flat layout, no sharding, no fields)")
+                     "(no sharding, no fields)")
         return str(cache)
 
     def _src_attr(self, name: str, default=None):
@@ -631,7 +625,7 @@ class DeviceLoader:
             split,
             page_format=page_cache.FORMAT_VERSION,
             batch_rows=self.batch_rows, nnz_cap=self.nnz_cap,
-            layout=self.layout, id_mod=self.id_mod,
+            id_mod=self.id_mod,
             wire_compact=self.wire_compact,
             drop_remainder=self.drop_remainder,
             # the ragged field (ISSUE 6) shifts every pre-ragged
@@ -716,7 +710,7 @@ class DeviceLoader:
 
     def _host_items(self) -> Iterator:
         """Yield host-side items: ('fused', buf, B, rows|None) for the
-        one-transfer path, ('arrays', dict) for sharded/rowmajor batches.
+        one-transfer path, ('arrays', dict) for sharded/field batches.
         With a page cache configured, a valid cache replays mmap'd fused
         pages and a miss rebuilds it write-through."""
         if self._cache_path is None:
@@ -754,8 +748,7 @@ class DeviceLoader:
         if self._use_native_pack():
             yield from self._host_items_native()
             return
-        fused = (self.layout == "flat" and self.sharding is None
-                 and not self.fields)
+        fused = self.sharding is None and not self.fields
         carry = None
         for blk in self._blocks():
             for piece in batch_slices(blk, self.batch_rows):
@@ -838,15 +831,10 @@ class DeviceLoader:
     def _pack_host(self, block, fused: bool):
         with self._pack_span(self._stall_pack,
                              rows=getattr(block, "size", self.batch_rows)):
-            if self.layout == "flat":
-                host = pack_flat(block, self.batch_rows, self.nnz_cap,
-                                 self.stats, id_mod=self.id_mod,
-                                 want_segments=not fused,
-                                 want_fields=self.fields)
-            else:
-                host = pack_rowmajor(block, self.batch_rows, self.nnz_cap,
-                                     self.stats, id_mod=self.id_mod,
-                                     want_fields=self.fields)
+            host = pack_flat(block, self.batch_rows, self.nnz_cap,
+                             self.stats, id_mod=self.id_mod,
+                             want_segments=not fused,
+                             want_fields=self.fields)
             host["_rows"] = getattr(block, "size", self.batch_rows)
             if fused:
                 buf = _host_fused(host, self.batch_rows, self.nnz_cap,

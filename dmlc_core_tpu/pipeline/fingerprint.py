@@ -74,9 +74,8 @@ def split_files(split) -> list:
 
 
 def pack_fingerprint(split, *, page_format: int, batch_rows: int,
-                     nnz_cap: int, layout: str, id_mod: int,
-                     wire_compact: bool, drop_remainder: bool,
-                     ragged: bool, pack_path: str,
+                     nnz_cap: int, id_mod: int, wire_compact: bool,
+                     drop_remainder: bool, ragged: bool, pack_path: str,
                      text_format, csv) -> Optional[Dict[str, Any]]:
     """Source identity (file list + sizes + mtimes) plus the full pack
     config, as one JSON-ready dict.  Returns None when the split has no
@@ -93,7 +92,6 @@ def pack_fingerprint(split, *, page_format: int, batch_rows: int,
                  int(getattr(split, "num_parts", 1))],
         "batch_rows": int(batch_rows),
         "nnz_cap": int(nnz_cap),
-        "layout": layout,
         "id_mod": int(id_mod),
         "wire_compact": bool(wire_compact),
         "drop_remainder": bool(drop_remainder),

@@ -9,8 +9,6 @@ arrays before hitting the TPU.  Two layouts:
   ``segment == batch_rows`` so a trailing scratch row absorbs them — see
   ``ops.csr``), plus ``labels/weights[batch_rows]``.  Rows whose values
   overflow ``nnz_cap`` are truncated (counted in ``truncated``).
-* :func:`pack_rowmajor` — row-padded ``ids/vals[batch_rows, k_cap]`` for the
-  Pallas embedding-bag kernel.
 * :func:`pack_ragged` — same flat layout as :func:`pack_flat` but **no
   tail zeroing and no truncation**: the nnz-sized arrays are
   ``np.empty`` capacity buffers valid only up to an explicit ``nnz_used``
@@ -50,8 +48,8 @@ from ..data.row_block import RowBlock
 from ..utils.logging import IdOverflowError, log_warning
 from ..utils.metrics import metrics
 
-__all__ = ["pack_flat", "pack_rowmajor", "pack_ragged", "batch_slices",
-           "ragged_slices", "dedup_ids", "PackStats", "IdOverflowError"]
+__all__ = ["pack_flat", "pack_ragged", "batch_slices", "ragged_slices",
+           "dedup_ids", "PackStats", "IdOverflowError"]
 
 
 @dataclass
@@ -245,57 +243,6 @@ def pack_flat(block: RowBlock, batch_rows: int, nnz_cap: int,
            "labels": labels, "weights": weights}
     if want_segments:
         out["segments"] = segments
-    if want_fields:
-        out["fields"] = fields
-    return out
-
-
-def pack_rowmajor(block: RowBlock, batch_rows: int, k_cap: int,
-                  stats: Optional[PackStats] = None,
-                  id_mod: int = 0,
-                  want_fields: bool = False) -> Dict[str, np.ndarray]:
-    """Row-padded [batch_rows, k_cap] batch for the Pallas embedding kernel.
-    ``want_fields=True``: also emit ``fields[batch_rows, k_cap]`` (libfm
-    field ids, int32, padding 0) for the FFM model."""
-    n = block.size
-    assert n <= batch_rows, (n, batch_rows)
-    if want_fields and block.fields is None:
-        raise ValueError(
-            "want_fields=True but the source RowBlock has no fields — "
-            "parse with format='libfm'")
-    ids = np.zeros((batch_rows, k_cap), np.int32)
-    vals = np.zeros((batch_rows, k_cap), np.float32)
-    fields = (np.zeros((batch_rows, k_cap), np.int32)
-              if want_fields else None)
-    offsets = block.offsets.astype(np.int64)
-    truncated = 0
-    trunc_rows = 0
-    for r in range(n):
-        b, e = int(offsets[r]), int(offsets[r + 1])
-        k = min(e - b, k_cap)
-        truncated += (e - b) - k
-        trunc_rows += (e - b) > k
-        ids[r, :k] = _ids32(block.indices[b:b + k], id_mod)
-        if block.values is not None:
-            vals[r, :k] = block.values[b:b + k]
-        else:
-            vals[r, :k] = 1.0
-        if want_fields:
-            fields[r, :k] = block.fields[b:b + k]
-    labels = np.zeros(batch_rows, np.float32)
-    weights = np.zeros(batch_rows, np.float32)
-    labels[:n] = block.labels
-    weights[:n] = (block.weights if block.weights is not None
-                   else np.ones(n, np.float32))
-    _note_truncation(truncated, trunc_rows, "pack_rowmajor")
-    if stats is not None:
-        stats.rows += n
-        stats.padded_rows += batch_rows - n
-        stats.truncated_values += truncated
-        stats.truncated_rows += trunc_rows
-        stats.true_nnz += int(offsets[n] - offsets[0]) - truncated
-        stats.padded_nnz += batch_rows * k_cap
-    out = {"ids": ids, "vals": vals, "labels": labels, "weights": weights}
     if want_fields:
         out["fields"] = fields
     return out

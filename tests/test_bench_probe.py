@@ -204,11 +204,10 @@ def test_integrity_config_bit_exact_on_cpu():
     assert bs.METRIC_OF["integrity"] == "ingest_integrity"
     r = bs.bench_integrity()
     assert r["value"] == 1.0, r.get("paths")
-    for name in ("libsvm_compact", "libfm_fields", "libsvm_rowmajor"):
+    for name in ("libsvm_compact", "libfm_fields"):
         sub = r["paths"][name]
         assert sub["ok"], (name, sub.get("mismatch"))
-        # host-derived in every path (rowmajor included) — a degenerate
-        # zero-feature corpus would make the checksums vacuous
+        # a degenerate zero-feature corpus would make the checksums vacuous
         assert sub["rows"] > 0 and sub["nnz"] > 0
 
 

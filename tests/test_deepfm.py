@@ -1,5 +1,6 @@
-"""DeepFM: layout parity, pipelined-tower parity over a 'pp' mesh, and
-learning a nonlinearity the plain FM cannot express."""
+"""DeepFM: pipelined-tower parity over a 'pp' mesh, and learning a
+nonlinearity the plain FM cannot express (the forward against a dense
+reference: ``test_models.py``)."""
 
 import numpy as np
 import pytest
@@ -26,34 +27,6 @@ def _flat_batch(rng, B, F, cap):
             "weights": jnp.ones((B,), jnp.float32)}
 
 
-def _rowmajor_of(flat, B, K):
-    ids = np.zeros((B, K), np.int32)
-    vals = np.zeros((B, K), np.float32)
-    fill = np.zeros(B, np.int32)
-    segs = np.asarray(flat["segments"])
-    fi = np.asarray(flat["ids"])
-    fv = np.asarray(flat["vals"])
-    for j in range(len(fi)):
-        r = int(segs[j])
-        if r < B and fv[j] != 0:
-            ids[r, fill[r]], vals[r, fill[r]] = fi[j], fv[j]
-            fill[r] += 1
-    return {"ids": jnp.asarray(ids), "vals": jnp.asarray(vals),
-            "labels": flat["labels"], "weights": flat["weights"]}
-
-
-def test_deepfm_layouts_agree():
-    rng = np.random.default_rng(0)
-    B, F = 16, 40
-    flat = _flat_batch(rng, B, F, cap=128)
-    rm = _rowmajor_of(flat, B, K=8)
-    model = DeepFM(num_features=F, dim=8, layers=2, engine="xla")
-    params = model.init(jax.random.PRNGKey(0))
-    np.testing.assert_allclose(model.forward(params, flat),
-                               model.forward(params, rm),
-                               rtol=2e-5, atol=2e-5)
-
-
 def test_deepfm_pipelined_tower_matches_sequential():
     devices = jax.devices()
     if len(devices) < 4:
@@ -63,7 +36,7 @@ def test_deepfm_pipelined_tower_matches_sequential():
     rng = np.random.default_rng(1)
     B, F = 16, 40
     flat = _flat_batch(rng, B, F, cap=128)
-    model = DeepFM(num_features=F, dim=8, layers=4, engine="xla")
+    model = DeepFM(num_features=F, dim=8, layers=4)
     params = model.init(jax.random.PRNGKey(0))
     pp = model.with_pipelined_tower(mesh, "pp", microbatches=4)
     np.testing.assert_allclose(pp.forward(params, flat),
@@ -101,6 +74,6 @@ def test_deepfm_beats_fm_on_nonlinear_target():
             params, state, loss = step(params, state, flat)
         return float(loss)
 
-    fm_loss = fit(FactorizationMachine(num_features=F, dim=8, engine="xla"))
-    deep_loss = fit(DeepFM(num_features=F, dim=8, layers=2, engine="xla"))
+    fm_loss = fit(FactorizationMachine(num_features=F, dim=8))
+    deep_loss = fit(DeepFM(num_features=F, dim=8, layers=2))
     assert deep_loss < fm_loss * 0.9, (fm_loss, deep_loss)

@@ -1,5 +1,5 @@
-"""FieldAwareFM: field-bucket formulation vs brute-force pair loop, both
-batch layouts, end-to-end from libfm text through DeviceLoader(fields=True).
+"""FieldAwareFM: field-bucket formulation vs brute-force pair loop,
+end-to-end from libfm text through DeviceLoader(fields=True).
 Reference parity: the libfm field coordinate (`src/data/libfm_parser.h:36-93`,
 `include/dmlc/data.h:168`) finally has an in-framework consumer."""
 
@@ -36,19 +36,6 @@ def make_case(rng, B, kmax, F, nf):
     return rows
 
 
-def to_rowmajor(rows, B, K):
-    ids = np.zeros((B, K), np.int32)
-    vals = np.zeros((B, K), np.float32)
-    fields = np.zeros((B, K), np.int32)
-    for r, row in enumerate(rows):
-        for c, (i, f, x) in enumerate(row):
-            ids[r, c], fields[r, c], vals[r, c] = i, f, x
-    return {"ids": jnp.asarray(ids), "vals": jnp.asarray(vals),
-            "fields": jnp.asarray(fields),
-            "labels": jnp.zeros((B,), jnp.float32),
-            "weights": jnp.ones((B,), jnp.float32)}
-
-
 def to_flat(rows, B, cap):
     ids, vals, fields, segs = [], [], [], []
     for r, row in enumerate(rows):
@@ -67,7 +54,7 @@ def to_flat(rows, B, cap):
             "weights": jnp.ones((B,), jnp.float32)}
 
 
-def test_ffm_matches_bruteforce_both_layouts():
+def test_ffm_matches_bruteforce():
     rng = np.random.default_rng(7)
     B, K, F, nf, d = 6, 5, 37, 4, 3
     rows = make_case(rng, B, K, F, nf)
@@ -78,16 +65,14 @@ def test_ffm_matches_bruteforce_both_layouts():
 
     expect = brute_ffm(float(params["w0"]), np.asarray(params["w"]),
                        np.asarray(params["v"]), rows)
-    got_rm = model.forward(params, to_rowmajor(rows, B, K))
-    got_fl = model.forward(params, to_flat(rows, B, cap=64))
-    np.testing.assert_allclose(got_rm, expect, rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(got_fl, expect, rtol=2e-4, atol=2e-4)
+    got = model.forward(params, to_flat(rows, B, cap=64))
+    np.testing.assert_allclose(got, expect, rtol=2e-4, atol=2e-4)
 
 
 def test_ffm_field_clip_and_missing_fields():
     model = FieldAwareFM(num_features=10, num_fields=2, dim=2)
     params = model.init(jax.random.PRNGKey(0))
-    batch = to_rowmajor([[(1, 5, 1.0), (2, 0, 1.0)]], 1, 2)  # field 5 ≥ nf
+    batch = to_flat([[(1, 5, 1.0), (2, 0, 1.0)]], 1, cap=4)  # field 5 ≥ nf
     out = model.forward(params, batch)          # clipped, not out-of-bounds
     assert np.isfinite(float(out[0]))
     with pytest.raises(KeyError):
@@ -107,7 +92,7 @@ def test_ffm_trains_on_separable_fields():
         fi, fj = int(rng.integers(0, nf)), int(rng.integers(0, nf))
         rows.append([(int(i), fi, 1.0), (int(j), fj, 1.0)])
         labels.append(1.0 if (fi + fj) % 2 == 0 else 0.0)
-    batch = to_rowmajor(rows, B, K)
+    batch = to_flat(rows, B, cap=B * K)
     batch["labels"] = jnp.asarray(labels, jnp.float32)
 
     model = FieldAwareFM(num_features=F, num_fields=nf, dim=d,
@@ -192,35 +177,25 @@ def test_ffm_end_to_end_from_libfm_text(tmp_path):
         truth.append(sorted((i, f, np.float32(x)) for f, i, x in ent))
     path.write_text("\n".join(lines) + "\n")
 
-    for layout in ("flat", "rowmajor"):
-        loader = DeviceLoader(
-            create_parser(f"file://{path}", 0, 1, "libfm"),
-            batch_rows=8, nnz_cap=64, layout=layout, fields=True)
-        got = []
-        for batch in loader:
-            assert "fields" in batch
-            ids = np.asarray(batch["ids"])
-            vals = np.asarray(batch["vals"])
-            fields = np.asarray(batch["fields"])
-            if layout == "flat":
-                segs = np.asarray(batch["segments"])
-                for r in range(int(np.asarray(batch["labels"]).shape[0])):
-                    m = segs == r
-                    if m.any():
-                        got.append(sorted(
-                            zip(ids[m].tolist(), fields[m].tolist(),
-                                vals[m].tolist())))
-            else:
-                for r in range(ids.shape[0]):
-                    m = vals[r] != 0
-                    if m.any():
-                        got.append(sorted(
-                            zip(ids[r][m].tolist(), fields[r][m].tolist(),
-                                vals[r][m].tolist())))
-        loader.close()
-        got = got[:len(truth)]
-        assert len(got) == len(truth)
-        for g, t in zip(got, truth):
-            assert [(i, f) for i, f, _ in g] == [(i, f) for i, f, _ in t]
-            np.testing.assert_allclose([x for _, _, x in g],
-                                       [x for _, _, x in t], rtol=1e-5)
+    loader = DeviceLoader(
+        create_parser(f"file://{path}", 0, 1, "libfm"),
+        batch_rows=8, nnz_cap=64, fields=True)
+    got = []
+    for batch in loader:
+        assert "fields" in batch
+        ids = np.asarray(batch["ids"])
+        vals = np.asarray(batch["vals"])
+        fields = np.asarray(batch["fields"])
+        segs = np.asarray(batch["segments"])
+        for r in range(int(np.asarray(batch["labels"]).shape[0])):
+            m = segs == r
+            if m.any():
+                got.append(sorted(
+                    zip(ids[m].tolist(), fields[m].tolist(),
+                        vals[m].tolist())))
+    loader.close()
+    assert len(got) == len(truth)
+    for g, t in zip(got, truth):
+        assert [(i, f) for i, f, _ in g] == [(i, f) for i, f, _ in t]
+        np.testing.assert_allclose([x for _, _, x in g],
+                                   [x for _, _, x in t], rtol=1e-5)

@@ -12,10 +12,10 @@ import optax  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
 from dmlc_core_tpu.data import create_parser  # noqa: E402
-from dmlc_core_tpu.models import (FactorizationMachine, SparseLogReg,  # noqa: E402
-                                  batch_sharding, fit_stream, make_eval_step,
-                                  make_train_step, param_shardings,
-                                  shard_params)
+from dmlc_core_tpu.models import (DCNv2, DeepFM, FactorizationMachine,  # noqa: E402
+                                  SparseLogReg, batch_sharding, fit_stream,
+                                  make_eval_step, make_train_step,
+                                  param_shardings, shard_params)
 from dmlc_core_tpu.pipeline import DeviceLoader  # noqa: E402
 
 
@@ -228,75 +228,122 @@ def test_row_sharded_table_matches_single_device(tmp_path):
                         table_shard="bogus")
 
 
-@pytest.mark.parametrize("engine", ["xla", "pallas"])
-def test_rowmajor_forward_matches_flat(engine, tmp_path):
-    """VERDICT r2 #3: the models consume rowmajor batches through the
-    engine-dispatching embedding bag (pallas kernel — interpret mode on
-    CPU) and must agree with the flat-CSR segment-sum path on the same
-    rows."""
-    rng = np.random.default_rng(3)
+# -- the CSR forwards against the same models written out densely ----------
+#
+# The flat batch is densified to X[B, F] in numpy and each model is written
+# out with dense products: an implementation that shares no op with
+# ``ops.csr`` (no gather, no segment sum), for the forward and for
+# ``jax.grad`` of the loss.
+
+def _dense_forward(name, xp, p, X):
+    """Scores [B] of model ``name`` on dense rows ``X[B, F]``; ``xp`` is
+    numpy (float64 reference) or jax.numpy (for ``jax.grad``)."""
+    if name == "logreg":
+        return X @ p["w"] + p["b"]
+    linear = p["w0"] + X @ p["w"]
+    s1 = X @ p["v"]
+    if name == "dcn":
+        x = s1
+        for w, b in zip(p["cross"]["w"], p["cross"]["b"]):
+            x = s1 * (x @ w + b) + x
+        return linear + x @ p["head"]["w"] + p["head"]["b"]
+    pair = 0.5 * ((s1 * s1) - (X * X) @ (p["v"] * p["v"])).sum(-1)
+    if name == "fm":
+        return linear + pair
+    h = s1
+    for w, b in zip(p["tower"]["w"], p["tower"]["b"]):
+        h = xp.tanh(h @ w + b)
+    return linear + pair + h @ p["head"]["w"] + p["head"]["b"]
+
+
+def _dense_loss(name, l2, p, X, labels, weights):
+    """Weighted BCE + l2 as ``task_loss`` defines it, written with
+    softplus; padding rows carry weight 0."""
+    z = _dense_forward(name, jnp, p, X)
+    y = (labels > 0).astype(z.dtype)
+    per = y * jax.nn.softplus(-z) + (1.0 - y) * jax.nn.softplus(z)
+    base = (per * weights).sum() / jnp.maximum(weights.sum(), 1e-9)
+    regs = [p["w"], p["v"]]
+    if name != "fm":
+        regs += [p["cross" if name == "dcn" else "tower"]["w"],
+                 p["head"]["w"]]
+    return base + l2 * sum(jnp.sum(r ** 2) for r in regs)
+
+
+DENSE_F = 96
+
+
+def _dense_model(name, l2=0.0):
+    model = {"logreg": lambda: SparseLogReg(DENSE_F, l2=l2),
+             "fm": lambda: FactorizationMachine(DENSE_F, dim=8, l2=l2),
+             "dcn": lambda: DCNv2(DENSE_F, dim=8, layers=2, l2=l2),
+             "deepfm": lambda: DeepFM(DENSE_F, dim=8, layers=2, l2=l2)}[name]()
+    params = model.init(jax.random.PRNGKey(0))
+    # randomize the zero-initialized leaves: an all-zero w would make the
+    # linear-term comparison vacuously 0 == 0
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(7), len(leaves))
+    return model, jax.tree_util.tree_unflatten(tree, [
+        v + 0.1 * jax.random.normal(k, v.shape, v.dtype)
+        for v, k in zip(leaves, keys)])
+
+
+def _loader_batch(tmp_path, padded):
+    """One flat batch off the loader and its rows as dense X[64, F].
+    ``padded``: 50 rows of 1-6 values in a 64-row, 512-value batch (padded
+    rows and padded values); else 64 rows of 5 values = exactly 320."""
+    rng = np.random.default_rng(11)
     path = tmp_path / "d.libsvm"
     with open(path, "w") as f:
-        for i in range(200):
-            n = int(rng.integers(1, 6))
-            idx = sorted(rng.choice(512, n, replace=False).tolist())
+        for i in range(50 if padded else 64):
+            n = int(rng.integers(1, 7)) if padded else 5
+            idx = sorted(rng.choice(DENSE_F, n, replace=False).tolist())
             f.write(f"{i % 2} " + " ".join(
-                f"{j}:{rng.random():.4f}" for j in idx) + "\n")
-    flat_batches, row_batches = [], []
+                f"{j}:{rng.random() + 0.1:.4f}" for j in idx) + "\n")
     with DeviceLoader(create_parser(str(path)), batch_rows=64,
-                      nnz_cap=1024) as ld:
-        flat_batches = list(ld)
-    with DeviceLoader(create_parser(str(path)), batch_rows=64, nnz_cap=8,
-                      layout="rowmajor") as ld:
-        row_batches = list(ld)
-    assert len(flat_batches) == len(row_batches)
-    for Model, kw in ((SparseLogReg, {}),
-                      (FactorizationMachine, {"dim": 8, "engine": engine})):
-        model = Model(num_features=512, **kw)
-        params = model.init(jax.random.PRNGKey(0))
-        # randomize the zero-initialized leaves: an all-zero w would make
-        # the linear-term comparison vacuously 0 == 0
-        keys = jax.random.split(jax.random.PRNGKey(7), len(params))
-        params = {k: v + 0.1 * jax.random.normal(key, v.shape, v.dtype)
-                  for (k, v), key in zip(sorted(params.items()), keys)}
-        for fb, rb in zip(flat_batches, row_batches):
-            np.testing.assert_allclose(
-                np.asarray(model.forward(params, fb)),
-                np.asarray(model.forward(params, rb)),
-                rtol=2e-4, atol=2e-5)
+                      nnz_cap=512 if padded else 320) as ld:
+        (batch,) = list(ld)
+    ids, vals, segs = (np.asarray(batch[k]) for k in
+                       ("ids", "vals", "segments"))
+    live = segs < 64                 # padding points at the scratch row
+    assert live.all() != padded      # the case is what its name says
+    assert bool(np.asarray(batch["weights"]).all()) != padded
+    X = np.zeros((64, DENSE_F), np.float64)
+    np.add.at(X, (segs[live], ids[live]), vals[live])
+    assert (X != 0).sum() == live.sum()
+    return batch, X
 
 
-def test_rowmajor_pallas_trains(tmp_path):
-    """The rowmajor+pallas path must be TRAINABLE: grads flow through the
-    kernel via its custom VJP (XLA backward), and a short fit reduces the
-    loss — matching the xla-engine result on the same stream."""
-    import optax
-    rng = np.random.default_rng(5)
-    path = tmp_path / "t.libsvm"
-    with open(path, "w") as f:
-        for i in range(512):
-            hot = [1, 2] if i % 2 else [3, 4]
-            f.write(f"{i % 2} " + " ".join(f"{j}:1.0" for j in hot) + "\n")
+@pytest.mark.parametrize("padded", [False, True], ids=["full", "padded"])
+@pytest.mark.parametrize("name", ["logreg", "fm", "dcn", "deepfm"])
+def test_forward_matches_dense_reference(name, padded, tmp_path):
+    model, params = _dense_model(name)
+    batch, X = _loader_batch(tmp_path, padded)
+    ref = _dense_forward(
+        name, np, jax.tree.map(lambda a: np.asarray(a, np.float64), params),
+        X)
+    np.testing.assert_allclose(np.asarray(model.forward(params, batch)), ref,
+                               rtol=2e-4, atol=2e-5)
 
-    def run(engine):
-        model = FactorizationMachine(num_features=16, dim=4, engine=engine)
-        params = model.init(jax.random.PRNGKey(0))
-        opt = optax.adam(5e-2)
-        state = opt.init(params)
-        step = make_train_step(model, opt, donate=False)
-        losses = []
-        with DeviceLoader(create_parser(str(path)), batch_rows=128,
-                          nnz_cap=4, layout="rowmajor") as ld:
-            for epoch in range(6):
-                for b in ld:
-                    params, state, loss = step(params, state, b)
-                    losses.append(float(loss))
-                ld.before_first()
-        return losses
 
-    for engine in ("pallas", "xla"):
-        losses = run(engine)
-        assert losses[-1] < 0.25 * losses[0], (engine, losses[0], losses[-1])
+@pytest.mark.parametrize("name", ["fm", "dcn", "deepfm"])
+def test_grad_matches_dense_reference(name, tmp_path):
+    """``jax.grad`` of ``loss`` through the CSR gather and segment sums
+    equals the dense form's on every leaf, the table's untouched rows
+    (l2 alone) included."""
+    l2 = 1e-3
+    model, params = _dense_model(name, l2=l2)
+    batch, X = _loader_batch(tmp_path, padded=True)
+    loss, got = jax.value_and_grad(model.loss)(params, batch)
+    ref_loss, ref = jax.value_and_grad(
+        lambda p: _dense_loss(name, l2, p, jnp.asarray(X, jnp.float32),
+                              batch["labels"], batch["weights"]))(params)
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
+    for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(ref)):
+        assert float(jnp.abs(r).max()) > 0, path
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=1e-6, err_msg=str(path))
 
 
 def test_streaming_auc_matches_sklearn_style_reference():
@@ -402,38 +449,6 @@ def test_dcn_cross_layer_closed_form():
     x2 = x0 * (x1 @ w2 + b2) + x1            # note: x0, not x1, multiplies
     got = DCNv2._cross(cross, jnp.asarray(x0))
     np.testing.assert_allclose(np.asarray(got), x2, rtol=1e-5, atol=1e-5)
-
-
-def test_dcn_rowmajor_forward_matches_flat(tmp_path):
-    """Both batch layouts produce the same DCN scores on the same rows
-    (the family-wide contract, VERDICT r2 #3)."""
-    from dmlc_core_tpu.models.dcn import DCNv2
-
-    rng = np.random.default_rng(6)
-    path = tmp_path / "d.libsvm"
-    with open(path, "w") as f:
-        for i in range(200):
-            n = int(rng.integers(1, 6))
-            idx = sorted(rng.choice(512, n, replace=False).tolist())
-            f.write(f"{i % 2} " + " ".join(
-                f"{j}:{rng.random():.4f}" for j in idx) + "\n")
-    with DeviceLoader(create_parser(str(path)), batch_rows=64,
-                      nnz_cap=1024) as ld:
-        flat_batches = list(ld)
-    with DeviceLoader(create_parser(str(path)), batch_rows=64, nnz_cap=8,
-                      layout="rowmajor") as ld:
-        row_batches = list(ld)
-    model = DCNv2(num_features=512, dim=8, layers=2)
-    params = model.init(jax.random.PRNGKey(0))
-    keys = jax.random.split(jax.random.PRNGKey(7), len(params))
-    params = {k: jax.tree_util.tree_map(
-        lambda v, key=key: v + 0.1 * jax.random.normal(key, v.shape, v.dtype),
-        v) for (k, v), key in zip(sorted(params.items()), keys)}
-    for fb, rb in zip(flat_batches, row_batches):
-        np.testing.assert_allclose(
-            np.asarray(model.forward(params, fb)),
-            np.asarray(model.forward(params, rb)),
-            rtol=2e-4, atol=2e-5)
 
 
 def test_dcn_registered_in_cli():

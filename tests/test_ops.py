@@ -1,5 +1,4 @@
-"""Device op tests: CSR primitives vs dense references; Pallas kernel
-(interpret mode) vs XLA reference."""
+"""Device op tests: CSR primitives vs dense references."""
 
 import numpy as np
 import pytest
@@ -63,107 +62,3 @@ def test_fm_pairwise_matches_bruteforce():
                 s += float(table[i] @ table[j]) * dense[b, i] * dense[b, j]
         expect.append(s)
     np.testing.assert_allclose(out, expect, rtol=1e-4, atol=1e-5)
-
-
-def test_pallas_embed_bag_interpret_matches_reference():
-    from dmlc_core_tpu.ops.pallas_embed import (embed_bag_pallas,
-                                                embed_bag_reference)
-    rng = np.random.default_rng(3)
-    B, K, F, D = 4, 8, 64, 128
-    ids = jnp.array(rng.integers(0, F, (B, K)), jnp.int32)
-    vals = jnp.array(rng.random((B, K)), jnp.float32)
-    table = jnp.array(rng.random((F, D)), jnp.float32)
-    ref = embed_bag_reference(ids, vals, table)
-    out = embed_bag_pallas(ids, vals, table, interpret=True)
-    np.testing.assert_allclose(out, ref, rtol=1e-5)
-
-
-def test_engine_dispatch_deterministic(monkeypatch):
-    """Default dispatch is a pure function of shape (ADVICE r3: every host
-    on a shared mesh must pick the same engine) and always XLA: the DMA
-    kernel is not measured on this round's chip; default XLA, so pallas is
-    opt-in via DMLC_EMBED_ENGINE=pallas or DMLC_EMBED_AUTOTUNE=1."""
-    from dmlc_core_tpu.ops import pallas_embed as pe
-
-    monkeypatch.delenv("DMLC_EMBED_AUTOTUNE", raising=False)
-    for shape in ((1024, 32, 64), (1024, 32, 8), (8, 32, 512)):
-        assert pe._pallas_profitable(*shape, fused=False) is False
-        # same inputs, same verdict — repeat-call determinism
-        assert pe._pallas_profitable(*shape, fused=False) is False
-
-
-def test_pallas_embed_chunked_matches_reference(monkeypatch):
-    """Batches whose flat ids/vals exceed the SMEM scalar-prefetch budget
-    split into independent row-chunk pallas_calls (1MB+ scalar operands
-    overflow v5e's SMEM; not measured on this round's chip; default XLA).
-    Force a tiny cap so
-    the chunk path runs at test scale; a non-multiple tail chunk included."""
-    from dmlc_core_tpu.ops import pallas_embed as pe
-
-    monkeypatch.setenv("DMLC_PALLAS_SMEM_SCALARS", "64")   # → 8-row chunks
-    rng = np.random.default_rng(5)
-    B, K, F, D = 44, 8, 64, 128          # 5 full chunks + 4-row tail
-    assert pe._chunk_rows(K) == 8
-    ids = jnp.array(rng.integers(0, F, (B, K)), jnp.int32)
-    vals = jnp.array(rng.random((B, K)), jnp.float32)
-    table = jnp.array(rng.random((F, D)), jnp.float32)
-    ref = pe.embed_bag_reference(ids, vals, table)
-    out = pe.embed_bag_pallas(ids, vals, table, interpret=True)
-    np.testing.assert_allclose(out, ref, rtol=1e-5)
-    s1, s2 = pe.fm_terms_pallas(ids, vals, table, interpret=True)
-    g = table[ids]
-    np.testing.assert_allclose(
-        s1, jnp.einsum("bk,bkd->bd", vals, g), rtol=1e-5)
-    np.testing.assert_allclose(
-        s2, jnp.einsum("bk,bkd->bd", vals * vals, g * g), rtol=1e-5)
-
-
-def test_engine_env_pin(monkeypatch):
-    """DMLC_EMBED_ENGINE pins the engine regardless of auto heuristics —
-    the multi-host escape hatch."""
-    from dmlc_core_tpu.ops import pallas_embed as pe
-
-    monkeypatch.setenv("DMLC_EMBED_ENGINE", "xla")
-    assert pe._resolve_engine("auto", 512) == "xla"
-    assert pe._resolve_engine("pallas", 512) == "xla"   # pin beats explicit
-    monkeypatch.setenv("DMLC_EMBED_ENGINE", "bogus")
-    import pytest as _pytest
-    with _pytest.raises(ValueError):
-        pe._resolve_engine("auto", 512)
-
-
-def test_engine_autotune_logic(monkeypatch):
-    """Opt-in timed autotune (DMLC_EMBED_AUTOTUNE=1): picks by measured
-    time, caches per shape, and a kernel failure degrades to XLA instead of
-    raising — exercised on CPU since the real gate only opens on TPU."""
-    from dmlc_core_tpu.ops import pallas_embed as pe
-
-    pe._engine_time_cache.clear()
-    # kernel raises (CPU without interpret) → False, no exception
-    assert pe._pallas_faster_timed(64, 4, 8, fused=False) is False
-    assert pe._engine_time_cache[(4, 8, False)] is False
-
-    # substitute engines with controllable speeds: pallas wins.  The slow
-    # engine must be slow when COMPILED (the autotuner jits the xla side),
-    # so it carries real FLOPs, not a python sleep that traces away.
-    def fast(ids, vals, table):
-        return jnp.zeros((ids.shape[0], table.shape[1]), jnp.float32)
-
-    def slow(ids, vals, table, square=False):
-        x = jnp.ones((400, 400), jnp.float32)
-        for _ in range(30):
-            x = (x @ x) * 1e-3
-        return jnp.zeros((ids.shape[0], table.shape[1]),
-                         jnp.float32) + x[0, 0]
-
-    monkeypatch.setattr(pe, "embed_bag_pallas", fast)
-    monkeypatch.setattr(pe, "embed_bag_reference", slow)
-    pe._engine_time_cache.clear()
-    assert pe._pallas_faster_timed(64, 5, 8, fused=False) is True
-    # cached: flipping the implementations does not change the verdict
-    monkeypatch.setattr(pe, "embed_bag_pallas", slow)
-    assert pe._pallas_faster_timed(64, 5, 8, fused=False) is True
-    # DMLC_EMBED_AUTOTUNE=1 routes _pallas_profitable through the timer
-    monkeypatch.setenv("DMLC_EMBED_AUTOTUNE", "1")
-    assert pe._pallas_profitable(64, 5, 8, fused=False) is True
-    pe._engine_time_cache.clear()

@@ -8,7 +8,7 @@ import pytest
 
 from dmlc_core_tpu.data import RowBlockContainer, create_parser
 from dmlc_core_tpu.pipeline import (DeviceLoader, PackStats, batch_slices,
-                                    pack_flat, pack_rowmajor)
+                                    pack_flat)
 
 
 def block_of(rows):
@@ -51,16 +51,6 @@ def test_waterfill_minimal_truncation():
     assert keep.sum() == 9
     assert _waterfill(np.array([2, 2]), 10).tolist() == [2, 2]  # no-op
     assert _waterfill(np.array([4, 4]), 1).sum() == 1
-
-
-def test_pack_rowmajor():
-    blk = block_of([(1.0, [3, 7, 9], None), (0.0, [2], [2.0])])
-    out = pack_rowmajor(blk, batch_rows=3, k_cap=2)
-    assert out["ids"].shape == (3, 2)
-    np.testing.assert_array_equal(out["ids"][0], [3, 7])   # truncated to k_cap
-    np.testing.assert_array_equal(out["vals"][0], [1, 1])  # implicit 1.0
-    np.testing.assert_array_equal(out["ids"][1], [2, 0])
-    np.testing.assert_array_equal(out["weights"], [1, 1, 0])
 
 
 def test_batch_slices():
@@ -186,14 +176,6 @@ def test_device_loader_drop_remainder(libsvm_file):
     assert len(batches) == 1037 // 128
     for b in batches:
         assert int(np.asarray(b["weights"]).sum()) == 128
-
-
-def test_device_loader_rowmajor_layout(libsvm_file):
-    with DeviceLoader(create_parser(libsvm_file), batch_rows=64, nnz_cap=8,
-                      layout="rowmajor") as loader:
-        b = loader.next_batch()
-        assert b["ids"].shape == (64, 8)
-        assert b["vals"].shape == (64, 8)
 
 
 def test_fused_h2d_matches_per_array(tmp_path):
@@ -358,8 +340,6 @@ def test_ids_overflow_raises_and_id_mod_hashes():
     blk = block_of([(1.0, np.array([1, big], np.uint64), [0.5, 1.5])])
     with pytest.raises(IdOverflowError):
         pack_flat(blk, batch_rows=2, nnz_cap=8)
-    with pytest.raises(IdOverflowError):
-        pack_rowmajor(blk, batch_rows=2, k_cap=8)
     out = pack_flat(blk, batch_rows=2, nnz_cap=8, id_mod=1000)
     np.testing.assert_array_equal(out["ids"][:2], [1, int(big) % 1000])
 
@@ -450,10 +430,10 @@ def test_pack_roundtrip_fuzz():
     """Property fuzz (the reference's recordio-fuzz idea applied to the
     pack layer): random ragged CSR blocks — including empty rows, dense
     rows, valueless features and fields — must reconstruct exactly from
-    BOTH packed layouts when nothing is truncated."""
+    the packed layout when nothing is truncated."""
     import numpy as np
     from dmlc_core_tpu.data.row_block import RowBlockContainer
-    from dmlc_core_tpu.pipeline.packing import pack_flat, pack_rowmajor
+    from dmlc_core_tpu.pipeline.packing import pack_flat
 
     rng = np.random.default_rng(0)
     for trial in range(25):
@@ -487,19 +467,6 @@ def test_pack_roundtrip_fuzz():
             assert flat["weights"][r] == 1.0 + r
         # padding rows weigh zero — silent-loss guard for the loss masks
         assert (flat["weights"][n:] == 0).all()
-
-        kmax = max((len(t[0]) for t in truth), default=1) or 1
-        rm = pack_rowmajor(blk, rows_cap, kmax, want_fields=with_fields)
-        for r, (idx, vals, fields) in enumerate(truth):
-            got = rm["vals"][r][rm["vals"][r] != 0]
-            keep = vals != 0          # rowmajor padding is val==0
-            np.testing.assert_allclose(np.sort(got), np.sort(vals[keep]),
-                                       rtol=1e-6)
-            gi = rm["ids"][r][:len(idx)]
-            np.testing.assert_array_equal(gi, idx)
-            if with_fields:
-                np.testing.assert_array_equal(rm["fields"][r][:len(idx)],
-                                              fields)
 
 
 def test_wire_compact_property_fuzz(tmp_path):

@@ -304,9 +304,6 @@ def test_device_loader_ragged_fingerprint_field():
         assert dl.ragged is True
     finally:
         dl.close()
-    with pytest.raises(Exception):
-        DeviceLoader(Src(), batch_rows=8, nnz_cap=64, ragged=True,
-                     layout="rowmajor")
 
 
 # ---------------------------------------------------------------------------

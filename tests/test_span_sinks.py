@@ -173,14 +173,15 @@ def test_profile_holds_a_host_event_of_the_name(host_events, what, thread):
 
 # ------------------------------------------------------------- the loader
 
-def _text(path, rows=1500, seed=3):
+def _text(path, fmt, rows=1500, seed=3):
     rng = np.random.default_rng(seed)
+    field = "" if fmt == "libsvm" else "3:"
     with open(path, "w") as f:
         for i in range(rows):
             idx = np.sort(rng.choice(5000, size=int(rng.integers(1, 9)),
                                      replace=False))
             f.write(f"{i % 2} " + " ".join(
-                f"{j}:{rng.random():.3f}" for j in idx) + "\n")
+                f"{field}{j}:{rng.random():.3f}" for j in idx) + "\n")
     return f"file://{path}"
 
 
@@ -191,7 +192,8 @@ PATHS = {
     "native": ({}, {},
                lambda ld: ld._use_native_pack()
                and not ld._use_streampack()),
-    "python": ({}, dict(layout="rowmajor", nnz_cap=8),
+    # field batches (libfm text) have no fused wire: the numpy packer
+    "python": ({}, dict(fields=True),
                lambda ld: not ld._use_native_pack()),
     "ragged": ({}, dict(ragged=True), lambda ld: ld.ragged),
     "pool": ({}, dict(put_threads=2), lambda ld: ld._use_native_pack()),
@@ -215,8 +217,9 @@ def test_loader_spans_on_every_pack_path(tmp_path, clean_ring, path):
     metrics.reset()
     kw = dict(batch_rows=128, nnz_cap=2048, wire_compact=False)
     kw.update(loader_kw)
+    fmt = "libfm" if kw.get("fields") else "libsvm"
     loader = DeviceLoader(
-        create_parser(_text(tmp_path / "t.libsvm"), 0, 1, "libsvm",
+        create_parser(_text(tmp_path / f"t.{fmt}", fmt), 0, 1, fmt,
                       **parser_kw), **kw)
     try:
         assert on_path(loader)

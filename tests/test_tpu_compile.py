@@ -24,7 +24,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 from dmlc_core_tpu.models import (FactorizationMachine, make_train_step,
                                   make_train_step_fused, param_shardings)
-from dmlc_core_tpu.ops import pallas_embed, ragged_csr
+from dmlc_core_tpu.ops import ragged_csr
 from dmlc_core_tpu.pipeline.device_loader import (_fused_words_meta,
                                                   make_decoder)
 
@@ -98,15 +98,6 @@ def _gather(one, width, fm):
         *args, num_rows=ROWS, fm=fm, interpret=False)
 
 
-def _bag(one, width, fused):
-    S, f32, i32 = jax.ShapeDtypeStruct, jnp.float32, jnp.int32
-    args = _on(one, (S((ROWS, 32), i32), S((ROWS, 32), f32),
-                     S((F, width), f32)))
-    fn = (pallas_embed.fm_terms_pallas if fused
-          else pallas_embed.embed_bag_pallas)
-    return fn.lower(*args, interpret=False)
-
-
 def case_kernel(lower):
     def run(topo):
         one = SingleDeviceSharding(topo.devices[0])
@@ -119,14 +110,12 @@ def case_kernel(lower):
 def case_refused_width(width):
     def run(topo):
         one = SingleDeviceSharding(topo.devices[0])
-        # the kernels were not repaired for the widths the rule sends to
-        # XLA: pinning Pallas there hands them to Mosaic, whose reason
+        # the kernel was not repaired for the widths the rule sends to
+        # XLA: pinning Pallas there hands it to Mosaic, whose reason
         # reaches the caller
-        assert not pallas_embed.mosaic_row_dma_ok(width)
-        for lower in (lambda: _gather(one, width, False),
-                      lambda: _bag(one, width, True)):
-            with pytest.raises(Exception, match="aligned to tiling"):
-                lower().compile()
+        assert not ragged_csr.mosaic_row_dma_ok(width)
+        with pytest.raises(Exception, match="aligned to tiling"):
+            _gather(one, width, False).compile()
     return run
 
 
@@ -263,8 +252,6 @@ def case_document_scorer(topo):
 CASES = {
     "gather_embed_128": case_kernel(lambda one, w: _gather(one, w, False)),
     "gather_fm_128": case_kernel(lambda one, w: _gather(one, w, True)),
-    "embed_bag_128": case_kernel(lambda one, w: _bag(one, w, False)),
-    "fm_terms_128": case_kernel(lambda one, w: _bag(one, w, True)),
     "refused_width_16": case_refused_width(16),
     "refused_width_32": case_refused_width(32),
     "refused_width_64": case_refused_width(64),
@@ -288,18 +275,13 @@ def test_compiles_for_v5e(case, topo):
 @pytest.mark.parametrize("width", [16, 32, 64, 128, 256])
 def test_engine_rule_is_a_function_of_backend_and_width(width, monkeypatch):
     """``engine="auto"`` never probes: on a TPU backend it is Pallas
-    exactly at the widths Mosaic lowers the row DMA for (ragged ops; the
-    embed-bag default stays XLA until a chip timing says otherwise), XLA
-    on every other backend, and a pin is passed through untouched.  Needs
-    no TPU compiler — the compiles above hold the rule to the compiler."""
+    exactly at the widths Mosaic lowers the row DMA for, XLA on every
+    other backend, and a pin is passed through untouched.  Needs no TPU
+    compiler — the compiles above hold the rule to the compiler."""
     monkeypatch.delenv("DMLC_RAGGED_ENGINE", raising=False)
-    monkeypatch.delenv("DMLC_EMBED_ENGINE", raising=False)
-    monkeypatch.delenv("DMLC_EMBED_AUTOTUNE", raising=False)
     assert ragged_csr._resolve_engine("auto", width) == "xla"   # cpu here
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     want = "pallas" if width % 128 == 0 else "xla"
-    assert pallas_embed.mosaic_row_dma_ok(width) == (want == "pallas")
+    assert ragged_csr.mosaic_row_dma_ok(width) == (want == "pallas")
     assert ragged_csr._resolve_engine("auto", width) == want
-    assert pallas_embed._resolve_engine("auto", width) == "xla"
     assert ragged_csr._resolve_engine("pallas", width) == "pallas"
-    assert pallas_embed._resolve_engine("pallas", width) == "pallas"

@@ -244,7 +244,10 @@ total = float(jax.jit(jnp.sum)(
         jax.sharding.Mesh(np.array(jax.devices()), ("dp",)),
         jax.sharding.PartitionSpec("dp"))))
 assert total == 2 * 2 * 6.0, total
-print("ELASTIC-OK", ctx.rank, mesh.generation, flush=True)
+# one write: the ranks share a pipe, and print() hands an unbuffered stdout
+# (PYTHONUNBUFFERED) each argument separately — the lines interleaved
+sys.stdout.write(f"ELASTIC-OK {ctx.rank} {mesh.generation}\n")
+sys.stdout.flush()
 mesh.close()
 ctx.shutdown()
 '''
