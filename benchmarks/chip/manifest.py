@@ -101,11 +101,19 @@ class Manifest:
             names.add(m["name"])
         if "setup_s" not in e2e:
             raise ManifestError("no setup_s among end_to_end")
+        reported = {c: {e["name"] for e in self.metrics_for(c, "end_to_end")}
+                    for c in self.cells}
         for m in d["per_layer"]:
             _check_metric(m, set(self.cells))
             if m.get("moves") not in e2e:
                 raise ManifestError(f"metric {m['name']}: moves "
                                     f"{m.get('moves')!r} is no end_to_end")
+            # each of its cells has to report the metric it should move
+            for w in m.get("workloads", ()):
+                if m["moves"] not in reported[w]:
+                    raise ManifestError(
+                        f"metric {m['name']}: moves {m['moves']!r}, which "
+                        f"its cell {w!r} does not report")
 
     # -- per-cell views ---------------------------------------------------
     def cell(self, name: str) -> dict:

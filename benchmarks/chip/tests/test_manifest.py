@@ -16,7 +16,9 @@ def test_committed_manifest_is_valid_and_all_files_exist():
         cfg = man.config(name)
         spec = man.traffic(name)
         man.module("traffic", spec["kind"])
-        assert cfg["features"] == cfg["program_args"]["features"]
+        # what a configuration states beside program_args, it states alike
+        for k in set(cfg) & set(cfg["program_args"]):
+            assert cfg[k] == cfg["program_args"][k], (name, k)
         e2e = [m["name"] for m in man.metrics_for(name, "end_to_end")]
         assert "setup_s" in e2e and len(e2e) >= 2
         assert set(spec["reports"]) <= set(e2e)
@@ -27,6 +29,28 @@ def test_committed_manifest_is_valid_and_all_files_exist():
     for m in man.doc["per_layer"]:
         assert os.path.exists(os.path.join(BENCH, "layer_metrics",
                                            m["name"] + ".json"))
+
+
+def test_every_rate_lists_its_cells_and_every_cell_reports_one():
+    man = manifest.Manifest(REPO, BENCH)
+    for m in man.doc["end_to_end"]:
+        assert (m["name"] == "setup_s") != ("workloads" in m), m["name"]
+    for name in man.cells:
+        rates = [m["name"] for m in man.metrics_for(name, "end_to_end")
+                 if m["name"] != "setup_s"]
+        assert len(rates) == 1, (name, rates)
+        assert list(man.traffic(name)["reports"]) == rates
+
+
+def test_a_layer_metric_moves_what_its_own_cells_report():
+    """Loading validates it (the refusal is tested below); here, which
+    rate each family of the committed metrics points at."""
+    man = manifest.Manifest(REPO, BENCH)
+    moves = {m["name"]: m["moves"] for m in man.doc["per_layer"]}
+    assert {v for k, v in moves.items() if k.endswith(".lm")} == {
+        "score_docs_per_s"}
+    assert {v for k, v in moves.items() if k.endswith(".score")} == {
+        "score_rows_per_s"}
 
 
 @pytest.mark.parametrize("name", ["a b", "x/y", "", "-lead", "a,b",
@@ -77,12 +101,16 @@ def test_duplicate_pair_and_unknown_moves_are_refused(tiny_tree):
         json.dump(bad, f)
     with pytest.raises(manifest.ManifestError, match="pair"):
         manifest.Manifest(tiny_tree.repo_root, tiny_tree.bench_dir)
-    bad = json.loads(json.dumps(doc))
-    bad["per_layer"][0]["moves"] = "nothing"
-    with open(doc_path, "w") as f:
-        json.dump(bad, f)
-    with pytest.raises(manifest.ManifestError, match="moves"):
-        manifest.Manifest(tiny_tree.repo_root, tiny_tree.bench_dir)
+    # a metric no end-to-end one, and one that its own cell does not report
+    for moves, said in (("nothing", "is no end_to_end"),
+                        ("score_docs_per_s", "does not report")):
+        bad = json.loads(json.dumps(doc))
+        assert bad["per_layer"][0]["workloads"] == ["fm24_train_text"]
+        bad["per_layer"][0]["moves"] = moves
+        with open(doc_path, "w") as f:
+            json.dump(bad, f)
+        with pytest.raises(manifest.ManifestError, match=said):
+            manifest.Manifest(tiny_tree.repo_root, tiny_tree.bench_dir)
 
 
 def test_config_workload_and_metric_are_added_by_files_alone(tiny_tree):

@@ -50,6 +50,42 @@ def test_traced_run_reports_per_layer_metrics(tiny_tree, cell, monkeypatch,
     assert not any("mfu" in k for k in out["metrics"])
 
 
+@pytest.mark.parametrize("stamps,per,tail", [
+    ([], [], 0),
+    ([(0.4, 5), (0.9, 5), (1.2, 7), (2.999, 1), (3.01, 4)], [10, 7, 1], 4),
+    ([(0.5, 3)], [], 3),
+    ([(1.5, 3), (2.5, 3)], [0, 3], 3),
+])
+def test_per_second_rows_of_a_window(tiny_tree, stamps, per, tail):
+    st = tiny_tree.module("traffic", "score_text")
+    t0 = 1000.25
+    got = st.per_second([(t0 + t, n) for t, n in stamps], t0)
+    assert got == (per, tail)
+    assert sum(got[0]) + got[1] == sum(n for _, n in stamps)
+
+
+def test_score_window_says_its_rate_second_by_second(tiny_tree):
+    """The window's own stamps: every row read back lands in one whole
+    second or in the part of a second the window ended in, and the line
+    goes to standard error (that no metric carries it,
+    ``test_cell_runs_and_is_correct`` holds)."""
+    said = []
+    ctx = run.Context(tiny_tree, "dcn24_score_text", SEED, 2.2, False)
+    ctx.say = said.append
+    ctx.fresh_work_dir()
+    cell = tiny_tree.module("traffic", "score_text").Cell(ctx)
+    try:
+        cell.setup()
+        cell.window(2.2)
+    finally:
+        cell.close()
+    v = ctx.values
+    assert len(v["per_second_rows"]) == 2 and min(v["per_second_rows"]) > 0
+    assert sum(v["per_second_rows"]) + v["tail_rows"] == v["attempted"]
+    line = [m for m in said if "rows in each whole second" in m]
+    assert len(line) == 1 and "slowest" in line[0] and "fastest" in line[0]
+
+
 def test_control_fails_training(tiny_tree):
     import jax.numpy as jnp
     tt = tiny_tree.module("traffic", "train_text")

@@ -12,6 +12,7 @@ the plain reference once the window has closed.
 
 from __future__ import annotations
 
+import statistics
 import time
 
 import numpy as np
@@ -64,6 +65,7 @@ class Cell:
         stages0 = textfeed.stage_seconds()
         rows = bad = batches = 0
         held = None
+        stamps: list = []       # (clock, rows) of every batch read
         t0 = time.perf_counter()
         deadline = t0 + seconds
 
@@ -76,6 +78,7 @@ class Cell:
             n = min(self.feed.rows, self.feed.corpus.rows - lo)
             rows += n
             batches += 1
+            stamps.append((time.perf_counter(), n))
             bad += int(n - np.isfinite(host[:n]).sum())
             if i in self.sample:
                 self.first.setdefault(i, host[:n])
@@ -107,6 +110,14 @@ class Cell:
         ctx.say(f"[window] {batches} batches, {rows} rows in {wall:.3f}s = "
                 f"{rows / wall:.0f} rows/s; {bad} scores not finite; stages "
                 f"{ {k: round(s, 3) for k, s in v['stages'].items()} }")
+        # not a metric: whether a slow run is slow throughout or in episodes
+        per, tail = per_second(stamps, t0)
+        v["per_second_rows"], v["tail_rows"] = per, tail
+        if per:
+            mid = statistics.median(per)
+            ctx.say(f"[window] rows in each whole second: {per}; slowest "
+                    f"{min(per) / mid:.4f}, fastest {max(per) / mid:.4f} of "
+                    f"the median second ({mid:.0f})")
 
     def verify(self) -> list:
         ctx, feed = self.ctx, self.feed
@@ -127,6 +138,23 @@ class Cell:
     def close(self) -> None:
         if self.feed is not None:
             self.feed.close()
+
+
+def per_second(stamps: list, t0: float) -> tuple:
+    """Rows read back in each whole second after ``t0``, and the rows of
+    the part of a second the window ended in: together, every row."""
+    if not stamps:
+        return [], 0
+    whole = int(stamps[-1][0] - t0)
+    per = [0] * whole
+    tail = 0
+    for t, n in stamps:
+        k = int(t - t0)
+        if k < whole:
+            per[k] += n
+        else:
+            tail += n
+    return per, tail
 
 
 def score_gap(ctx, feed, params, first: dict, last: dict, dtype=None):

@@ -34,10 +34,6 @@ class Cell:
         ctx = self.ctx
         self.feed = feed = textfeed.TextFed(ctx)
         p = feed.p
-        if p.kstep != 1:
-            raise RuntimeError(
-                "the train CLI's default dispatch is no longer the per-step "
-                "loop (kstep != 1): this traffic kind must follow it")
         self.lr = float(p.lr)
         opt = optax.adam(p.lr)
         self.step = make_train_step(feed.model, opt)
