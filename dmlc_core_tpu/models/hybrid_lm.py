@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.doc_attention import doc_causal_attention
-from ..ops.kda import kda_chunked
+from ..ops.kda import kda_chunked_counted
 from ..ops.moe import held_experts_sum, route, swiglu
 
 __all__ = ["HybridMoELM", "load_arch"]
@@ -217,10 +217,10 @@ class HybridMoELM:
                 _mm(x, p["gate_down"]), p["gate_up"],
                 preferred_element_type=F32)).reshape(t, nh, d)
         with jax.named_scope("kda/scan"):
-            o = kda_chunked(q, k, v, g, beta, seg, KDA_CHUNK)
+            o, fused = kda_chunked_counted(q, k, v, g, beta, seg, KDA_CHUNK)
         with jax.named_scope("kda/out"):
             o = _rms(o, p["out_norm"], self.eps) * gate
-            return _mm(o.astype(x.dtype).reshape(t, nh * d), p["wo"])
+            return _mm(o.astype(x.dtype).reshape(t, nh * d), p["wo"]), fused
 
     def _mla(self, p, x, seg, doc_start):
         t = x.shape[0]
@@ -264,13 +264,15 @@ class HybridMoELM:
         pos = jnp.arange(t, dtype=jnp.int32) - doc_start
         with jax.named_scope("lm_embed"):
             x = params["embed"][ids]
-        counters, choices = {}, {}
+        counters, choices = {"kda.fused_layers": jnp.int32(0)}, {}
         for layer in range(1, self.layers + 1):
             name = f"layer_{layer:02d}"
             p = params[name]
             y = _rms(x, p["norm1"], self.eps)
             if self.mixer(layer) == "kda":
-                x = x + self._kda(p, y, seg, pos)
+                out, fused = self._kda(p, y, seg, pos)
+                x = x + out
+                counters["kda.fused_layers"] += fused
             else:
                 x = x + self._mla(p, y, seg, doc_start)
             y = _rms(x, p["norm2"], self.eps)
@@ -286,7 +288,8 @@ class HybridMoELM:
         """(scores ``[batch_rows]`` float32, counters): per mixture layer
         the assignments that reached held experts, the largest and the mean
         load of a held expert and the live tokens none of whose experts is
-        held; the batch's tokens and documents."""
+        held; the batch's tokens and documents; ``kda.fused_layers``, how
+        many KDA layers of this program took the chunk kernel."""
         seg, row_ptr = batch["segments"], batch["row_ptr"]
         rows = row_ptr.shape[0] - 1
         t = seg.shape[0]

@@ -16,6 +16,7 @@ import lm_reference
 from dmlc_core_tpu.data.row_block import RowBlock
 from dmlc_core_tpu.models.hybrid_lm import HybridMoELM
 from dmlc_core_tpu.ops.doc_attention import doc_causal_attention
+from dmlc_core_tpu.ops import kda
 from dmlc_core_tpu.ops.kda import kda_chunked
 from dmlc_core_tpu.pipeline.packing import pack_flat
 
@@ -134,26 +135,135 @@ def recurrence(q, k, v, g, beta, first):
     return out
 
 
-@pytest.mark.parametrize("chunk", [8, 16, 64])
-@pytest.mark.parametrize("template", ["mixed", "many_short", "one_long"])
-def test_chunked_kda_is_the_recurrence(chunk, template):
-    lengths = TEMPLATES[template]
-    t, h, d = sum(lengths), 3, 16
-    rng = np.random.default_rng(chunk + t)
+def kda_inputs(lengths, h, d, seed):
+    """Unit ``k``, scaled unit ``q``, decays from almost none to e^-30 a
+    token, float64."""
+    t = sum(lengths)
+    rng = np.random.default_rng(seed)
     unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
     q = unit(rng.normal(size=(t, h, d))) * d ** -0.5
     k = unit(rng.normal(size=(t, h, d)))
     v = rng.normal(size=(t, h, d))
-    # decays from almost none to e^-30 a token: no chunk may overflow
     g = -np.exp(rng.uniform(-6, 3.4, size=(t, h, d)))
     beta = rng.uniform(0.05, 0.95, size=(t, h))
     seg = np.repeat(np.arange(len(lengths)), lengths)
+    return q, k, v, g, beta, seg
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 64])
+@pytest.mark.parametrize("template", ["mixed", "many_short", "one_long"])
+def test_chunked_kda_is_the_recurrence(chunk, template):
+    lengths = TEMPLATES[template]
+    q, k, v, g, beta, seg = kda_inputs(lengths, 3, 16,
+                                       seed=chunk + sum(lengths))
     first = np.concatenate([[True], seg[1:] != seg[:-1]])
     f32 = lambda x: jnp.asarray(x, jnp.float32)            # noqa: E731
     got = kda_chunked(f32(q), f32(k), f32(v), f32(g), f32(beta),
                       jnp.asarray(seg, jnp.int32), chunk)
     want = recurrence(q, k, v, g, beta, first)
     np.testing.assert_allclose(np.asarray(got), want, atol=3e-5)
+
+
+# the templates at kernel-eligible shapes: as they are (T no multiple of the
+# chunk, boundaries in the middle of chunks), and cut so that T is a multiple
+# of 64 and documents start on a chunk's first (64, 256, 320) and last (127)
+# token
+KERNEL_TEMPLATES = {
+    "mixed": (TEMPLATES["mixed"], [64, 63, 129, 1, 63, 64]),
+    "many_short": (TEMPLATES["many_short"], [5, 1, 2, 56, 63, 65, 3, 125]),
+    "one_long": ([300], [320]),
+}
+
+
+@pytest.mark.parametrize("head_blocks", [1, 2])
+@pytest.mark.parametrize("whole", [False, True],
+                         ids=["ragged_t", "whole_chunks"])
+@pytest.mark.parametrize("template", sorted(KERNEL_TEMPLATES))
+def test_kda_kernel_is_the_recurrence(template, whole, head_blocks,
+                                      monkeypatch):
+    """The Pallas kernel, interpreted on the CPU, at shapes the selection
+    rule sends to it on a TPU (128-wide heads, chunks of 64)."""
+    lengths = KERNEL_TEMPLATES[template][whole]
+    t, h, d = sum(lengths), 2, 128
+    assert (t % 64 == 0) == whole
+    if head_blocks == 2:        # room for one head's tiles and state only
+        monkeypatch.setattr(kda, "_VMEM_BUDGET", 400_000)
+    assert h // kda._head_block(h, d, d, 64, 4) == head_blocks
+    q, k, v, g, beta, seg = kda_inputs(lengths, h, d, seed=t + head_blocks)
+    first = np.concatenate([[True], seg[1:] != seg[:-1]])
+    pad = -t % 64
+    f32 = lambda x: jnp.asarray(                               # noqa: E731
+        np.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)), jnp.float32)
+    seg_p = np.concatenate([seg, np.full(pad, np.iinfo(np.int32).max)])
+    assert kda._kernel_fits(f32(q), f32(v), 64)
+    got = kda._kda_kernel(f32(q), f32(k), f32(v), f32(g), f32(beta),
+                          jnp.asarray(seg_p, jnp.int32), 64, interpret=True)
+    want = recurrence(q, k, v, g, beta, first)
+    np.testing.assert_allclose(np.asarray(got)[:t], want, atol=3e-5)
+
+
+def test_kda_kernel_and_jnp_path_agree_in_bfloat16():
+    """``q, k, v`` in bfloat16 as the benchmark's cell has them: both paths
+    round the same products' operands to bfloat16, so they differ where a
+    float32 rounding of what is cast moves a bfloat16 step (1.1e-4 here, of
+    outputs of 6e-3 rms), and either is as far (1.9e-4) from the float64
+    recurrence over the same bfloat16 inputs."""
+    lengths = KERNEL_TEMPLATES["mixed"][1]
+    t, h, d = sum(lengths), 2, 128
+    q, k, v, g, beta, seg = kda_inputs(lengths, h, d, seed=3)
+    bf = lambda x: jnp.asarray(x, jnp.bfloat16)               # noqa: E731
+    args = (bf(q), bf(k), bf(v), jnp.asarray(g, jnp.float32),
+            jnp.asarray(beta, jnp.float32), jnp.asarray(seg, jnp.int32))
+    got = kda._kda_kernel(*args, 64, interpret=True)
+    want = kda._kda_jnp(*args, 64)
+    assert got.dtype == want.dtype == jnp.float32
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got, want, atol=5e-4)
+    f64 = lambda x: np.asarray(                               # noqa: E731
+        bf(x).astype(jnp.float32), np.float64)
+    first = np.concatenate([[True], seg[1:] != seg[:-1]])
+    exact = recurrence(f64(q), f64(k), f64(v), g, beta, first)
+    assert np.abs(got - exact).max() <= 1.25 * np.abs(want - exact).max()
+
+
+@pytest.mark.parametrize("d,chunk,dtype,fits", [
+    (128, 64, "bfloat16", True), (128, 64, "float32", True),
+    (256, 128, "bfloat16", True), (16, 64, "float32", False),
+    (128, 8, "float32", False), (128, 24, "bfloat16", False),
+    (64, 64, "bfloat16", False)])
+def test_kda_kernel_rule_is_a_function_of_the_shapes(d, chunk, dtype, fits):
+    q = jax.ShapeDtypeStruct((chunk * 2, 4, d), jnp.dtype(dtype))
+    assert kda._kernel_fits(q, q, chunk) == fits
+
+
+def test_kda_head_block_is_sized_from_vmem():
+    # the cell: 32 heads of 128, bfloat16 -> 288 KiB a head, 16 heads a step
+    assert kda._head_block(32, 128, 128, 64, 2) == 16
+    assert kda._head_block(32, 128, 128, 64, 4) == 16
+    assert kda._head_block(3, 128, 128, 64, 2) == 3
+    assert kda._head_block(7, 512, 512, 64, 4) == 1       # always a block
+
+
+def test_eligible_shapes_take_the_jnp_path_on_the_cpu():
+    """The selection is made at lowering: the same call that holds the
+    kernel in a program for a TPU runs ``jnp`` here, and says so."""
+    lengths = [70, 33, 26]
+    q, k, v, g, beta, seg = kda_inputs(lengths, 1, 128, seed=7)
+    first = np.concatenate([[True], seg[1:] != seg[:-1]])
+    f32 = lambda x: jnp.asarray(x, jnp.float32)            # noqa: E731
+    got, fused = jax.jit(kda.kda_chunked_counted)(
+        f32(q), f32(k), f32(v), f32(g), f32(beta), jnp.asarray(seg, jnp.int32))
+    assert int(fused) == 0
+    np.testing.assert_allclose(
+        np.asarray(got), recurrence(q, k, v, g, beta, first), atol=3e-5)
+
+
+def test_counters_say_how_many_layers_took_the_kernel(model, params, scorer):
+    batch, _ = make_batch(TEMPLATES["mixed"])
+    _, counters = scorer[0](params, batch)
+    rec = HybridMoELM.counter_record(counters)
+    assert rec["kda.fused_layers"] == 0.0                  # the CPU: none
+    assert rec["tokens"] == sum(TEMPLATES["mixed"])
 
 
 @pytest.mark.parametrize("block", [16, 64, 512])
@@ -324,6 +434,7 @@ def test_predict_scores_documents_through_the_cli(run_dir):
     recs = [r for r in trace.recorder.snapshot() if r["name"] == "lm.batch"]
     assert len(recs) >= 2
     assert sum(r["attrs"]["documents"] for r in recs[-2:]) == len(docs)
+    assert all(r["attrs"]["kda.fused_layers"] == 0 for r in recs[-2:])
 
 
 @pytest.mark.parametrize("over,why", [

@@ -234,15 +234,30 @@ def case_document_scorer(topo):
     compiled = jax.jit(model.forward_counted).lower(params, batch).compile()
     assert sum(np.prod(leaf.shape) for leaf in jax.tree.leaves(params)) \
         == 4_282_936_192
-    assert _hbm(compiled) < HBM_BYTES
+    # 15.41 GB with the temporaries of the jnp chunk (PR 30); the kernel
+    # keeps them in VMEM
+    assert _hbm(compiled) < 15_410_000_000 < HBM_BYTES
     text = compiled.as_text()
     assert "ragged-dot" in text                  # experts: grouped products
+    # every KDA layer's chunk is the Pallas kernel, selected at lowering
+    # (this process's backend is the CPU), under the scope a trace reads
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line and " %kda_chunk" in line]
+    assert len(kernels) == sum(model.mixer(layer) == "kda"
+                               for layer in range(1, model.layers + 1))
+    for line in kernels:
+        assert 'custom_call_target="tpu_custom_call"' in line
+        assert re.search(r'op_name="[^"]*kda/scan/', line), line[:300]
     top = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]", text,
                      re.M)
+    chunks = t // 64
     for dims in top:
         d = [int(x) for x in dims.split(",")]
         assert not (t in d and model.vocab in d), dims     # [T, V]
         assert d.count(t) < 2, dims                        # [T, T]
+        # the chunk's [N, C, H, d] <-> [N, H, C, d] copies went with the
+        # jnp formulation: the kernel cuts its tiles from [T, H * d]
+        assert not (len(d) == 4 and d[0] == chunks), dims
     _names_scopes(compiled, [
         "lm_embed", "kda/conv", "kda/gates", "kda/scan", "mla/project",
         "mla/attention", "moe/router", "moe/dispatch", "moe/experts",
