@@ -9,33 +9,44 @@ stream, ``vals`` and ``labels`` are ignored, and padding tokens
 score a row: the mean log-probability of each next token given the tokens
 before it in the document, ``1/(n-1) sum_t log p(x_{t+1} | x_{<=t})``.
 
-The architecture arrives as the model's published ``config.json`` keys
-(``kimi_linear``-style) plus two that say what this holder keeps of a layer
-shared between chips: ``held_experts = [lo, hi]`` and ``vocab_rows``.
-Pre-norm residual blocks, RMSNorm, untied embedding and head:
+The architecture arrives as the model's published ``config.json`` keys, in
+the ``kimi_linear`` or the ``deepseek_v3`` spelling (``canonical`` maps the
+second onto the first, the one the class reads), plus two that say what
+this holder keeps of a layer shared between chips: ``held_experts =
+[lo, hi]`` and ``vocab_rows``.  Pre-norm residual blocks, RMSNorm, untied
+embedding and head:
 
-* layers in ``linear_attn_config.kda_layers`` (numbered from 1) mix tokens
-  with a gated delta rule with per-channel decay (``ops.kda``) behind a
-  4-tap causal depthwise convolution and SiLU, L2-normalised ``q``/``k``,
-  a low-rank decay gate and a low-rank output gate (rank = the head size,
-  as the published implementation has it);
-* layers in ``full_attn_layers`` use latent attention without positions
-  (``mla_use_nope``): keys and values expand from a normalised latent of
-  ``kv_lora_rank``; the ``qk_rope_head_dim`` columns that would carry a
-  rotation stay, shared by all heads, unrotated;
+* layers in ``linear_attn_config.kda_layers`` (numbered from 1; none where
+  the group is absent or the list empty) mix tokens with a gated delta
+  rule with per-channel decay (``ops.kda``) behind a 4-tap causal depthwise
+  convolution and SiLU, L2-normalised ``q``/``k``, a low-rank decay gate
+  and a low-rank output gate (rank = the head size, as the published
+  implementation has it);
+* every other layer uses latent attention: keys and values expand from a
+  normalised latent of ``kv_lora_rank``; the query is one matrix, or with
+  ``q_lora_rank`` a normalised low-rank pair.  The ``qk_rope_head_dim``
+  columns, the key's shared by all heads, are rotated by the token's
+  position **in its document** — consecutive pairs, frequencies
+  ``rope_theta^(-2i/d)``, under ``rope_scaling`` of type ``yarn`` blended
+  towards ``1/factor`` of themselves below ``beta_fast`` rotations of the
+  original context, the softmax scale times ``mscale^2`` — or, with
+  ``mla_use_nope``, ride unrotated;
 * the first ``first_k_dense_replace`` layers have a dense SwiGLU, the rest a
-  sigmoid-routed mixture plus shared experts (``ops.moe``).
+  sigmoid-routed mixture plus shared experts (``ops.moe``), the choice
+  limited to the ``topk_group`` best of ``num_expert_group`` groups where
+  the architecture has groups.
 
 ``dtype`` (bfloat16 as published) is the type of parameters and
-activations; router scores and the choice, softmax and log-softmax, RMSNorm
-statistics, the decay (in log space), the recurrent state and every
-accumulation are float32.  The ``[T, vocab_rows]`` logits exist one block
-of tokens at a time.
+activations; router scores, group sums and the choice, the rotation,
+softmax and log-softmax, RMSNorm statistics, the decay (in log space), the
+recurrent state and every accumulation are float32.  The
+``[T, vocab_rows]`` logits exist one block of tokens at a time.
 """
 
 from __future__ import annotations
 
 import json
+import math
 from typing import Dict, Tuple
 
 import jax
@@ -45,7 +56,7 @@ from ..ops.doc_attention import doc_causal_attention
 from ..ops.kda import kda_chunked_counted
 from ..ops.moe import held_experts_sum, route, swiglu
 
-__all__ = ["HybridMoELM", "load_arch"]
+__all__ = ["HybridMoELM", "canonical", "load_arch"]
 
 Params = Dict[str, object]
 F32 = jnp.float32
@@ -53,9 +64,78 @@ KDA_CHUNK = 64
 HEAD_BLOCK = 1024
 
 
+# what the class reads <- the same quantity as ``deepseek_v3`` spells it
+SPELLINGS = {
+    "num_experts": "n_routed_experts",
+    "num_experts_per_token": "num_experts_per_tok",
+    "num_shared_experts": "n_shared_experts",
+    "moe_router_activation_func": "scoring_func",
+    "moe_renormalize": "norm_topk_prob",
+    "num_expert_group": "n_group",
+}
+
+
 def load_arch(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def canonical(arch: dict) -> dict:
+    """``arch`` with every key of ``SPELLINGS`` under the name the class
+    reads; a file that gives both spellings has to give them alike."""
+    a = dict(arch)
+    for ours, theirs in SPELLINGS.items():
+        if theirs not in a:
+            continue
+        value = a.pop(theirs)
+        if a.setdefault(ours, value) != value:
+            raise ValueError(f"the architecture says {ours}={a[ours]!r} and "
+                             f"{theirs}={value!r}")
+    return a
+
+
+def rope_frequencies(d_rope: int, theta: float, scaling) -> Tuple[list, float]:
+    """(the ``d_rope / 2`` rotation frequencies, ``mscale``): plain RoPE
+    without ``scaling``, YaRN's blend and softmax factor with it."""
+    half = d_rope // 2
+    freqs = [theta ** (-2.0 * i / d_rope) for i in range(half)]
+    if not scaling:
+        return freqs, 1.0
+    kind = scaling.get("rope_type", scaling.get("type"))
+    if kind != "yarn":
+        raise ValueError(f"hybrid_moe_lm computes rope_scaling of type "
+                         f"'yarn' only, the architecture says {kind!r}")
+    factor = float(scaling["factor"])
+    original = float(scaling["original_max_position_embeddings"])
+
+    def turns_at(n):      # the column that makes n turns over the context
+        return d_rope * math.log(original / (n * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(turns_at(float(scaling.get("beta_fast", 32)))), 0)
+    hi = min(math.ceil(turns_at(float(scaling.get("beta_slow", 1)))),
+             d_rope - 1)
+    width = (hi - lo) or 0.001
+    ramp = [min(max((i - lo) / width, 0.0), 1.0) for i in range(half)]
+    freqs = [f * (1.0 - r) + f / factor * r for f, r in zip(freqs, ramp)]
+
+    def mscale(m):
+        return 0.1 * m * math.log(factor) + 1.0 if factor > 1 and m else 1.0
+
+    both = mscale(float(scaling.get("mscale", 1))) \
+        / mscale(float(scaling.get("mscale_all_dim", 0)))
+    if abs(both - 1.0) > 1e-12:
+        raise ValueError("hybrid_moe_lm computes rope_scaling with mscale = "
+                         "mscale_all_dim only (cos and sin unscaled)")
+    return freqs, mscale(float(scaling.get("mscale_all_dim", 0)))
+
+
+def _rotate(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """The pairs ``(x_2i, x_2i+1)`` of the last axis turned by their angle,
+    float32.  Comes back as ``[re_0 .. ; im_0 ..]``: queries and the key
+    take the same order, and their product does not see it."""
+    a, b = x[..., 0::2].astype(F32), x[..., 1::2].astype(F32)
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
 
 
 def _rms(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
@@ -85,31 +165,38 @@ class HybridMoELM:
     """Registered as ``hybrid_moe_lm``; see the module text."""
 
     def __init__(self, arch: dict):
-        need = {"moe_router_activation_func": "sigmoid", "num_expert_group": 1,
-                "topk_group": 1, "q_lora_rank": None, "mla_use_nope": True,
-                "moe_renormalize": True, "tie_word_embeddings": False,
+        a = canonical(arch)
+        need = {"moe_router_activation_func": "sigmoid",
+                "moe_renormalize": True, "topk_method": "noaux_tc",
+                "tie_word_embeddings": False, "attention_bias": False,
                 "hidden_act": "silu", "moe_layer_freq": 1}
         for key, want in need.items():
-            if arch.get(key, want) != want:
+            if a.get(key, want) != want:
                 raise ValueError(f"hybrid_moe_lm computes {key}={want!r} "
-                                 f"only, the architecture says "
-                                 f"{arch[key]!r}")
-        a = arch
+                                 f"only, the architecture says {a[key]!r}")
         self.dtype = jnp.dtype(a.get("dtype", "bfloat16"))
         self.hidden = int(a["hidden_size"])
         self.layers = int(a["num_hidden_layers"])
         self.eps = float(a["rms_norm_eps"])
-        lin = a["linear_attn_config"]
-        self.kda_layers = {int(x) for x in lin["kda_layers"]}
-        self.kda_heads = int(lin["num_heads"])
-        self.kda_dim = int(lin["head_dim"])
-        self.conv_taps = int(lin["short_conv_kernel_size"])
-        self.gate_rank = self.kda_dim
+        lin = a.get("linear_attn_config") or {}
+        self.kda_layers = {int(x) for x in lin.get("kda_layers", ())}
+        if self.kda_layers:
+            self.kda_heads = int(lin["num_heads"])
+            self.kda_dim = int(lin["head_dim"])
+            self.conv_taps = int(lin["short_conv_kernel_size"])
+            self.gate_rank = self.kda_dim
         self.heads = int(a["num_attention_heads"])
+        self.q_rank = a.get("q_lora_rank") and int(a["q_lora_rank"])
         self.kv_rank = int(a["kv_lora_rank"])
         self.d_nope = int(a["qk_nope_head_dim"])
         self.d_rope = int(a["qk_rope_head_dim"])
         self.d_v = int(a["v_head_dim"])
+        self.rope_freqs, mscale = None, 1.0
+        if not a.get("mla_use_nope", False):
+            self.rope_freqs, mscale = rope_frequencies(
+                self.d_rope, float(a.get("rope_theta", 10000.0)),
+                a.get("rope_scaling"))
+        self.attn_scale = (self.d_nope + self.d_rope) ** -0.5 * mscale ** 2
         self.dense_layers = int(a["first_k_dense_replace"])
         self.dense_width = int(a["intermediate_size"])
         self.expert_width = int(a["moe_intermediate_size"])
@@ -117,6 +204,17 @@ class HybridMoELM:
         self.top_k = int(a["num_experts_per_token"])
         self.shared = int(a["num_shared_experts"])
         self.route_scale = float(a["routed_scaling_factor"])
+        self.groups = int(a.get("num_expert_group", 1))
+        self.groups_kept = int(a.get("topk_group", 1))
+        per_group = self.experts // max(self.groups, 1)
+        if (self.groups < 1 or per_group * self.groups != self.experts
+                or not 1 <= self.groups_kept <= self.groups
+                or self.groups_kept * per_group < self.top_k
+                or (self.groups > 1 and per_group < 2)):
+            raise ValueError(
+                f"{self.experts} experts in {self.groups} groups of which "
+                f"{self.groups_kept} are kept leave no choice of "
+                f"{self.top_k}")
         lo, hi = a.get("held_experts", [0, self.experts])
         self.held: Tuple[int, int] = (int(lo), int(hi))
         if not 0 <= self.held[0] < self.held[1] <= self.experts:
@@ -142,8 +240,13 @@ class HybridMoELM:
                      gate_down=(h, r), gate_up=(r, n),
                      out_norm=(self.kda_dim,), wo=(n, h))
         else:
-            s.update(wq=(h, self.heads * (self.d_nope + self.d_rope)),
-                     wkv_a=(h, self.kv_rank + self.d_rope),
+            dq = self.heads * (self.d_nope + self.d_rope)
+            if self.q_rank:
+                s.update(wq_a=(h, self.q_rank), q_norm=(self.q_rank,),
+                         wq_b=(self.q_rank, dq))
+            else:
+                s.update(wq=(h, dq))
+            s.update(wkv_a=(h, self.kv_rank + self.d_rope),
                      kv_norm=(self.kv_rank,),
                      wkv_b=(self.kv_rank,
                             self.heads * (self.d_nope + self.d_v)),
@@ -222,16 +325,42 @@ class HybridMoELM:
             o = _rms(o, p["out_norm"], self.eps) * gate
             return _mm(o.astype(x.dtype).reshape(t, nh * d), p["wo"]), fused
 
-    def _mla(self, p, x, seg, doc_start):
+    def _turn(self, pos):
+        """(cos, sin) ``[T, d_rope / 2]`` of every token's rotation, or None
+        where the architecture rotates nothing: the same for every layer."""
+        if self.rope_freqs is None:
+            return None
+        with jax.named_scope("mla/rope"):
+            angle = pos.astype(F32)[:, None] * jnp.asarray(
+                self.rope_freqs, F32)[None, :]
+            return jnp.cos(angle), jnp.sin(angle)
+
+    def _mla(self, p, x, seg, doc_start, turn):
         t = x.shape[0]
         nh, dn, dr, dv = self.heads, self.d_nope, self.d_rope, self.d_v
+        if self.q_rank:
+            with jax.named_scope("mla/q_lora"):
+                q = _mm(_rms(_mm(x, p["wq_a"]), p["q_norm"], self.eps),
+                        p["wq_b"])
         with jax.named_scope("mla/project"):
-            q = _mm(x, p["wq"]).reshape(t, nh, dn + dr)
-            q = (q.astype(F32) * (dn + dr) ** -0.5).astype(x.dtype)
+            if not self.q_rank:
+                q = _mm(x, p["wq"])
+            q = q.reshape(t, nh, dn + dr)
             latent, k_shared = jnp.split(_mm(x, p["wkv_a"]),
                                          [self.kv_rank], axis=-1)
             kv = _mm(_rms(latent, p["kv_norm"], self.eps),
                      p["wkv_b"]).reshape(t, nh, dn + dv)
+            if turn is None:
+                q = (q.astype(F32) * self.attn_scale).astype(x.dtype)
+        if turn is not None:
+            with jax.named_scope("mla/rope"):
+                cos, sin = turn
+                q = (jnp.concatenate(
+                    [q[..., :dn].astype(F32),
+                     _rotate(q[..., dn:], cos[:, None], sin[:, None])], -1)
+                    * self.attn_scale).astype(x.dtype)
+                k_shared = _rotate(k_shared, cos, sin).astype(x.dtype)
+        with jax.named_scope("mla/project"):
             k = jnp.concatenate(
                 [kv[..., :dn],
                  jnp.broadcast_to(k_shared[:, None, :], (t, nh, dr))], -1)
@@ -244,9 +373,11 @@ class HybridMoELM:
     def _moe(self, p, x, live):
         with jax.named_scope("moe/router"):
             chosen, weights = route(x, p["router"], p["router_bias"],
-                                    self.top_k, self.route_scale)
+                                    self.top_k, self.route_scale,
+                                    self.groups, self.groups_kept)
         routed, counters = held_experts_sum(
-            x, chosen, weights, live, p["e_gu"], p["e_down"], self.held)
+            x, chosen, weights, live, p["e_gu"], p["e_down"], self.held,
+            self.experts)
         with jax.named_scope("moe/shared"):
             shared = swiglu(x, p["s_gu"], p["s_down"])
         return routed + shared, counters, chosen
@@ -262,6 +393,7 @@ class HybridMoELM:
         # a token's document start; padding is one document behind the last
         doc_start = row_ptr[jnp.minimum(seg, rows)]
         pos = jnp.arange(t, dtype=jnp.int32) - doc_start
+        turn = self._turn(pos)
         with jax.named_scope("lm_embed"):
             x = params["embed"][ids]
         counters, choices = {"kda.fused_layers": jnp.int32(0)}, {}
@@ -274,7 +406,7 @@ class HybridMoELM:
                 x = x + out
                 counters["kda.fused_layers"] += fused
             else:
-                x = x + self._mla(p, y, seg, doc_start)
+                x = x + self._mla(p, y, seg, doc_start, turn)
             y = _rms(x, p["norm2"], self.eps)
             if layer <= self.dense_layers:
                 with jax.named_scope("dense_mlp"):
@@ -287,9 +419,10 @@ class HybridMoELM:
     def forward_counted(self, params: Params, batch: Dict[str, jax.Array]):
         """(scores ``[batch_rows]`` float32, counters): per mixture layer
         the assignments that reached held experts, the largest and the mean
-        load of a held expert and the live tokens none of whose experts is
-        held; the batch's tokens and documents; ``kda.fused_layers``, how
-        many KDA layers of this program took the chunk kernel."""
+        load of a held expert, the live tokens none of whose experts is
+        held and the rows the dispatch gathered for them; the batch's
+        tokens and documents; ``kda.fused_layers``, how many KDA layers of
+        this program took the chunk kernel."""
         seg, row_ptr = batch["segments"], batch["row_ptr"]
         rows = row_ptr.shape[0] - 1
         t = seg.shape[0]

@@ -7,9 +7,18 @@ one that holds its first token's document start up to its own, with the
 running maximum and sum of a streaming softmax in float32.  The walk's
 bounds are read from the batch, so the work follows the documents' lengths
 (the sum of their squares) and not ``T`` squared.
+
+A block is 512 tokens, halved while the float32 scores of one pair of
+blocks, ``[H, block, block]``, pass 48 MiB (256 at 64 heads).  Measured on a
+v5e (PERF.md, PR 37): a tile of 32 MiB (32 heads) stays on the chip in every
+layer; one of 64 MiB (64 heads) the TPU compiler keeps in HBM for some
+layers of a program and not for others, 64 ms a layer against 21.  The limit
+lies between the two, nearer neither; nothing between them was measured.
 """
 
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -17,16 +26,27 @@ import jax.numpy as jnp
 __all__ = ["doc_causal_attention"]
 
 _NEG = -1e30
+_SCORE_TILE_BYTES = 48 << 20
+
+
+def default_block(heads: int) -> int:
+    """512, halved while ``[heads, block, block]`` float32 passes the
+    tile."""
+    block = 512
+    while block > 128 and heads * block * block * 4 > _SCORE_TILE_BYTES:
+        block //= 2
+    return block
 
 
 def doc_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          segments: jax.Array, doc_start: jax.Array,
-                         block: int = 512) -> jax.Array:
+                         block: Optional[int] = None) -> jax.Array:
     """``q, k [T, H, d_qk]`` (``q`` already scaled), ``v [T, H, d_v]``,
     ``segments [T]`` document ids (non-decreasing), ``doc_start [T]`` the
-    stream position of each token's document start.  Returns
-    ``[T, H, d_v]`` float32."""
+    stream position of each token's document start; ``block`` tokens a
+    block (None: ``default_block``).  Returns ``[T, H, d_v]`` float32."""
     t, h, _ = q.shape
+    block = block or default_block(h)
     dv = v.shape[-1]
     f32 = jnp.float32
     pad = -t % block

@@ -364,8 +364,8 @@ def test_padding_tokens_reach_no_expert(model, params, scorer, template):
 
 
 @pytest.mark.parametrize("key,value", [
-    ("moe_router_activation_func", "softmax"), ("q_lora_rank", 1536),
-    ("mla_use_nope", False), ("num_expert_group", 8),
+    ("moe_router_activation_func", "softmax"), ("topk_method", "greedy"),
+    ("moe_renormalize", False), ("num_expert_group", 3), ("topk_group", 2),
     ("held_experts", [4, 12])])
 def test_an_architecture_it_does_not_compute_is_refused(key, value):
     with pytest.raises(ValueError):

@@ -264,6 +264,49 @@ def case_document_scorer(topo):
         "moe/shared", "moe/combine", "dense_mlp", "lm_head"])
 
 
+def case_document_scorer_dsv3(topo):
+    """The same class on the ``deepseek_v3``-type configuration at its timed
+    sizes (one dense and four mixture layers at published widths, 16 of 256
+    experts, an eighth of the vocabulary, 8 documents in 16 384 tokens,
+    bfloat16): it fits the chip beside its 8.58 GB of parameters, the
+    experts' rows are carried in blocks (no ``[T * k, H]`` buffer), and
+    neither the ``[T, T]`` scores nor the ``[T, V]`` logits exist whole."""
+    import re
+
+    from dmlc_core_tpu.models.hybrid_lm import HybridMoELM, load_arch
+    here = os.path.dirname(os.path.abspath(__file__))
+    model = HybridMoELM(load_arch(os.path.join(
+        here, "..", "benchmarks", "chip", "configs",
+        "gigachat31_702b_ep16_l5.json")))
+    one = SingleDeviceSharding(topo.devices[0])
+    S, i32 = jax.ShapeDtypeStruct, jnp.int32
+    t, rows = 16384, 8
+    params = jax.tree.map(lambda shape: S(shape, model.dtype, sharding=one),
+                          model.shapes(),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    batch = _on(one, dict(_batch(rows, t), row_ptr=S((rows + 1,), i32)))
+    compiled = jax.jit(model.forward_counted).lower(params, batch).compile()
+    assert sum(np.prod(leaf.shape) for leaf in jax.tree.leaves(params)) \
+        == 4_291_256_320
+    # 8.583 GB of arguments + 3.177 GB of temporaries (PR 37)
+    assert _hbm(compiled) < 12_000_000_000 < 15_000_000_000 < HBM_BYTES
+    text = compiled.as_text()
+    assert "ragged-dot" in text                  # experts: grouped products
+    assert " %kda_chunk" not in text             # no KDA layer, no kernel
+    top = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]", text,
+                     re.M)
+    assignments = t * model.top_k
+    for dims in top:
+        d = [int(x) for x in dims.split(",")]
+        assert not (t in d and model.vocab in d), dims     # [T, V]
+        assert d.count(t) < 2, dims                        # [T, T]
+        assert not (assignments in d and model.hidden in d), dims
+    _names_scopes(compiled, [
+        "lm_embed", "mla/q_lora", "mla/project", "mla/rope",
+        "mla/attention", "mla/out", "moe/router", "moe/dispatch",
+        "moe/experts", "moe/shared", "moe/combine", "dense_mlp", "lm_head"])
+
+
 CASES = {
     "gather_embed_128": case_kernel(lambda one, w: _gather(one, w, False)),
     "gather_fm_128": case_kernel(lambda one, w: _gather(one, w, True)),
@@ -279,6 +322,7 @@ CASES = {
     "serving_bucket_ragged": case_serving_bucket(True),
     "fm_mesh_dp2_mp2_step": case_mesh_step,
     "document_scorer_forward": case_document_scorer,
+    "document_scorer_dsv3_forward": case_document_scorer_dsv3,
 }
 
 
