@@ -52,7 +52,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.doc_attention import doc_causal_attention
+from ..ops.doc_attention import doc_causal_attention_counted
 from ..ops.kda import kda_chunked_counted
 from ..ops.moe import held_experts_sum, route, swiglu
 
@@ -366,9 +366,9 @@ class HybridMoELM:
                  jnp.broadcast_to(k_shared[:, None, :], (t, nh, dr))], -1)
             v = kv[..., dn:]
         with jax.named_scope("mla/attention"):
-            o = doc_causal_attention(q, k, v, seg, doc_start)
+            o, fused = doc_causal_attention_counted(q, k, v, seg, doc_start)
         with jax.named_scope("mla/out"):
-            return _mm(o.astype(x.dtype).reshape(t, nh * dv), p["wo"])
+            return _mm(o.astype(x.dtype).reshape(t, nh * dv), p["wo"]), fused
 
     def _moe(self, p, x, live):
         with jax.named_scope("moe/router"):
@@ -396,7 +396,9 @@ class HybridMoELM:
         turn = self._turn(pos)
         with jax.named_scope("lm_embed"):
             x = params["embed"][ids]
-        counters, choices = {"kda.fused_layers": jnp.int32(0)}, {}
+        counters = {"kda.fused_layers": jnp.int32(0),
+                    "mla.fused_layers": jnp.int32(0)}
+        choices = {}
         for layer in range(1, self.layers + 1):
             name = f"layer_{layer:02d}"
             p = params[name]
@@ -406,7 +408,9 @@ class HybridMoELM:
                 x = x + out
                 counters["kda.fused_layers"] += fused
             else:
-                x = x + self._mla(p, y, seg, doc_start, turn)
+                out, fused = self._mla(p, y, seg, doc_start, turn)
+                x = x + out
+                counters["mla.fused_layers"] += fused
             y = _rms(x, p["norm2"], self.eps)
             if layer <= self.dense_layers:
                 with jax.named_scope("dense_mlp"):
@@ -421,8 +425,9 @@ class HybridMoELM:
         the assignments that reached held experts, the largest and the mean
         load of a held expert, the live tokens none of whose experts is
         held and the rows the dispatch gathered for them; the batch's
-        tokens and documents; ``kda.fused_layers``, how many KDA layers of
-        this program took the chunk kernel."""
+        tokens and documents; ``kda.fused_layers`` and ``mla.fused_layers``,
+        how many KDA layers of this program took the chunk kernel and how
+        many MLA layers the attention kernel."""
         seg, row_ptr = batch["segments"], batch["row_ptr"]
         rows = row_ptr.shape[0] - 1
         t = seg.shape[0]

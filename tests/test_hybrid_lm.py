@@ -16,7 +16,7 @@ import lm_reference
 from dmlc_core_tpu.data.row_block import RowBlock
 from dmlc_core_tpu.models.hybrid_lm import HybridMoELM
 from dmlc_core_tpu.ops.doc_attention import doc_causal_attention
-from dmlc_core_tpu.ops import kda
+from dmlc_core_tpu.ops import doc_attention, kda
 from dmlc_core_tpu.ops.kda import kda_chunked
 from dmlc_core_tpu.pipeline.packing import pack_flat
 
@@ -263,32 +263,157 @@ def test_counters_say_how_many_layers_took_the_kernel(model, params, scorer):
     _, counters = scorer[0](params, batch)
     rec = HybridMoELM.counter_record(counters)
     assert rec["kda.fused_layers"] == 0.0                  # the CPU: none
+    assert rec["mla.fused_layers"] == 0.0
     assert rec["tokens"] == sum(TEMPLATES["mixed"])
 
 
-@pytest.mark.parametrize("block", [16, 64, 512])
-@pytest.mark.parametrize("template", ["mixed", "many_short", "full"])
-def test_block_diagonal_attention_is_one_document_at_a_time(block, template):
-    lengths = TEMPLATES[template]
-    t, h, dqk, dv = sum(lengths), 2, 24, 16
-    rng = np.random.default_rng(block)
+def attention_inputs(lengths, h, dqk, dv, seed):
+    """``q`` (scaled), ``k``, ``v`` float64, the document ids and every
+    token's document start."""
+    t = sum(lengths)
+    rng = np.random.default_rng(seed)
     q = rng.normal(size=(t, h, dqk)) * dqk ** -0.5
     k = rng.normal(size=(t, h, dqk))
     v = rng.normal(size=(t, h, dv))
     bounds = np.concatenate([[0], np.cumsum(lengths)])
     seg = np.repeat(np.arange(len(lengths)), lengths)
-    want = np.zeros((t, h, dv))
+    return q, k, v, jnp.asarray(seg, jnp.int32), \
+        jnp.asarray(bounds[seg], jnp.int32)
+
+
+def one_document_at_a_time(q, k, v, lengths):
+    """Causal softmax attention document by document (float64 on the
+    host)."""
+    bounds = np.concatenate([[0], np.cumsum(lengths)])
+    want = np.zeros(v.shape)
     for s, e in zip(bounds[:-1], bounds[1:]):
         sc = np.einsum("qhd,khd->hqk", q[s:e], k[s:e])
         sc = np.where(np.tril(np.ones((e - s, e - s), bool)), sc, -np.inf)
         p = np.exp(sc - sc.max(-1, keepdims=True))
         want[s:e] = np.einsum("hqk,khd->qhd", p / p.sum(-1, keepdims=True),
                               v[s:e])
+    return want
+
+
+@pytest.mark.parametrize("block", [16, 64, 512])
+@pytest.mark.parametrize("template", ["mixed", "many_short", "full"])
+def test_block_diagonal_attention_is_one_document_at_a_time(block, template):
+    lengths = TEMPLATES[template]
+    q, k, v, seg, start = attention_inputs(lengths, 2, 24, 16, seed=block)
     f32 = lambda x: jnp.asarray(x, jnp.float32)            # noqa: E731
-    got = doc_causal_attention(
-        f32(q), f32(k), f32(v), jnp.asarray(seg, jnp.int32),
-        jnp.asarray(bounds[seg], jnp.int32), block)
-    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+    got = doc_causal_attention(f32(q), f32(k), f32(v), seg, start, block)
+    np.testing.assert_allclose(
+        np.asarray(got), one_document_at_a_time(q, k, v, lengths), atol=2e-5)
+
+
+# the templates at kernel-eligible shapes: as they are (T no multiple of the
+# kernel's 256-token block, boundaries inside blocks), and cut so that T is a
+# multiple of 256 and documents start on a block's first (256, 512) and last
+# (255) token
+ATTENTION_TEMPLATES = {
+    "mixed": (TEMPLATES["mixed"], [255, 1, 256, 127, 129]),
+    "many_short": (TEMPLATES["many_short"],
+                   [5, 1, 2, 247, 1, 63, 65, 3, 125]),
+    "full": (TEMPLATES["full"], [100, 28, 127, 1, 256]),
+}
+
+
+@pytest.mark.parametrize("head_blocks", [1, 2])
+@pytest.mark.parametrize("dv", [128, 192])
+@pytest.mark.parametrize("whole", [False, True],
+                         ids=["ragged_t", "whole_blocks"])
+@pytest.mark.parametrize("template", sorted(ATTENTION_TEMPLATES))
+def test_attention_kernel_is_one_document_at_a_time(template, whole, dv,
+                                                    head_blocks, monkeypatch):
+    """The Pallas kernel, interpreted on the CPU, at the head widths the
+    selection rule sends to it on a TPU (192-wide keys, 128- and 192-wide
+    values), in float32 so that it is held to the ``jnp`` text's
+    tolerance."""
+    lengths = ATTENTION_TEMPLATES[template][whole]
+    block = doc_attention._BLOCK
+    t, h, dqk = sum(lengths), 2 * head_blocks, 192
+    assert (t % block == 0) == whole and t > block
+    if head_blocks == 2:        # room for two heads' tiles and state only
+        monkeypatch.setattr(doc_attention, "_VMEM_BUDGET", 4_000_000)
+    assert doc_attention._head_block(h, dqk, dv, block, 4) == 2
+    q, k, v, seg, start = attention_inputs(lengths, h, dqk, dv,
+                                           seed=t + dv + head_blocks)
+    f32 = lambda x: jnp.asarray(x, jnp.float32)            # noqa: E731
+    # the jitted wrapper read _VMEM_BUDGET when it was traced
+    got = jax.jit(doc_attention._attention_kernel.__wrapped__,
+                  static_argnames="interpret")(
+        f32(q), f32(k), f32(v), seg, start, interpret=True)
+    assert got.shape == v.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(got), one_document_at_a_time(q, k, v, lengths), atol=2e-5)
+
+
+@pytest.mark.parametrize("dv", [128, 192])
+def test_attention_kernel_and_jnp_path_agree_in_bfloat16(dv):
+    """``q, k, v`` in bfloat16 as the benchmark's cells have them: both
+    paths round the same operands at the same points (``p`` to bfloat16
+    before the second product, everything else float32), so they differ by
+    the order of their sums only, and the kernel is no farther than
+    ``jnp`` from the float64 softmax over the same bfloat16 inputs."""
+    lengths = ATTENTION_TEMPLATES["mixed"][1]
+    q, k, v, seg, start = attention_inputs(lengths, 2, 192, dv, seed=3)
+    bf = lambda x: jnp.asarray(x, jnp.bfloat16)               # noqa: E731
+    args = (bf(q), bf(k), bf(v), seg, start)
+    assert doc_attention._kernel_fits(*args[:3:2])
+    got = doc_attention._attention_kernel(*args, interpret=True)
+    want = doc_attention._attention_jnp(*args, doc_attention._BLOCK)
+    assert got.dtype == want.dtype == jnp.float32
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got, want, atol=5e-6)
+    f64 = lambda x: np.asarray(                               # noqa: E731
+        bf(x).astype(jnp.float32), np.float64)
+    exact = one_document_at_a_time(f64(q), f64(k), f64(v), lengths)
+    assert np.abs(got - exact).max() <= 1.05 * np.abs(want - exact).max()
+
+
+@pytest.mark.parametrize("t,dqk,dv,dtype,fits", [
+    (16384, 192, 192, "bfloat16", True),      # gigachat31_702b_ep16_l5
+    (32768, 192, 128, "bfloat16", True),      # kimi_linear_48b_ep2_l5
+    (256, 128, 128, "bfloat16", True), (1000, 256, 64, "bfloat16", True),
+    (255, 192, 192, "bfloat16", False),       # under a block of tokens
+    (640, 192, 192, "float32", False), (640, 192, 192, "float16", False),
+    (640, 24, 16, "bfloat16", False), (640, 64, 128, "bfloat16", False),
+    (640, 320, 128, "bfloat16", True), (640, 192, 24, "bfloat16", False)])
+def test_attention_kernel_rule_is_a_function_of_the_shapes(t, dqk, dv, dtype,
+                                                           fits):
+    q = jax.ShapeDtypeStruct((t, 4, dqk), jnp.dtype(dtype))
+    v = jax.ShapeDtypeStruct((t, 4, dv), jnp.dtype(dtype))
+    assert doc_attention._kernel_fits(q, v) == fits
+    # three heads of 192: no head block's keys are whole tiles of lanes
+    odd = jax.ShapeDtypeStruct((t, 3, dqk), jnp.dtype(dtype))
+    assert doc_attention._kernel_fits(odd, odd) == (fits and dqk % 128 == 0)
+
+
+def test_attention_head_block_is_sized_from_vmem():
+    block = doc_attention._BLOCK
+    # the cells: 64 heads of 192 / 192 and 32 of 192 / 128, bfloat16
+    assert doc_attention._head_block(64, 192, 192, block, 2) == 8
+    assert doc_attention._head_block(32, 192, 128, block, 2) == 16
+    assert doc_attention._head_block(3, 256, 192, block, 2) == 3
+    assert doc_attention._head_block(3, 192, 192, block, 2) is None
+    assert doc_attention._head_block(7, 4096, 4096, block, 2) is None
+
+
+def test_eligible_attention_takes_the_jnp_path_on_the_cpu():
+    """The selection is made at lowering: the same call that holds the
+    kernel in a program for a TPU runs ``jnp`` here, and says so."""
+    lengths = ATTENTION_TEMPLATES["many_short"][0]
+    q, k, v, seg, start = attention_inputs(lengths, 2, 192, 128, seed=7)
+    bf = lambda x: jnp.asarray(x, jnp.bfloat16)               # noqa: E731
+    assert doc_attention._kernel_fits(bf(q), bf(v))
+    got, fused = jax.jit(doc_attention.doc_causal_attention_counted)(
+        bf(q), bf(k), bf(v), seg, start)
+    assert int(fused) == 0
+    f64 = lambda x: np.asarray(                               # noqa: E731
+        bf(x).astype(jnp.float32), np.float64)
+    np.testing.assert_allclose(
+        np.asarray(got),
+        one_document_at_a_time(f64(q), f64(k), f64(v), lengths), atol=2e-2)
 
 
 @pytest.mark.parametrize("layer", ["layer_02", "layer_04"])
@@ -435,6 +560,7 @@ def test_predict_scores_documents_through_the_cli(run_dir):
     assert len(recs) >= 2
     assert sum(r["attrs"]["documents"] for r in recs[-2:]) == len(docs)
     assert all(r["attrs"]["kda.fused_layers"] == 0 for r in recs[-2:])
+    assert all(r["attrs"]["mla.fused_layers"] == 0 for r in recs[-2:])
 
 
 @pytest.mark.parametrize("over,why", [

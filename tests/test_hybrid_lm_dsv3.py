@@ -93,6 +93,7 @@ def test_forward_agrees_with_the_reference(model, params, scorer, template):
     rec = HybridMoELM.counter_record(counters)
     assert rec["tokens"] == total and rec["documents"] == len(lengths)
     assert rec["kda.fused_layers"] == 0.0              # no KDA layer at all
+    assert rec["mla.fused_layers"] == 0.0              # the CPU: jnp
     for name in ref["chosen"]:       # 3 of 16 held: the blocked form
         assert rec[f"{name}.assignments"] <= rec[f"{name}.dispatch_rows"] \
             < rec[f"{name}.assignments"] + moe.DISPATCH_BLOCK
@@ -442,7 +443,7 @@ def test_predict_scores_a_deepseek_v3_arch_file_through_the_cli(tmp_path):
             if r["name"] == "lm.batch"][-2:]
     assert sum(r["documents"] for r in recs) == len(docs)
     for r in recs:
-        assert r["kda.fused_layers"] == 0
+        assert r["kda.fused_layers"] == 0 and r["mla.fused_layers"] == 0
         for layer in ("layer_02", "layer_03", "layer_04"):
             assert {f"{layer}.{c}" for c in (
                 "assignments", "load_max", "load_mean", "unserved_tokens",

@@ -211,6 +211,25 @@ def case_mesh_step(topo):
     assert _hbm(compiled) < HBM_BYTES      # bytes on each device
 
 
+def _attention_is_the_kernel(text, model):
+    """Every MLA layer's attention is one ``doc_attention`` Pallas kernel,
+    selected at lowering, under the scope a trace reads, and no loop of
+    the ``jnp`` walk is left under that scope."""
+    import re
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line and " %doc_attention" in line]
+    assert len(kernels) == sum(model.mixer(layer) == "mla"
+                               for layer in range(1, model.layers + 1))
+    for line in kernels:
+        assert 'custom_call_target="tpu_custom_call"' in line
+        assert re.search(r'op_name="[^"]*mla/attention/', line), line[:300]
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert loops                         # the head's and the dispatch's
+    for line in loops:
+        assert not re.search(r'op_name="[^"]*mla/attention', line), \
+            line[-300:]
+
+
 def case_document_scorer(topo):
     """The document scorer at the benchmark's published widths and timed
     sizes (layers 1-5, 128 of 256 experts, half the vocabulary, 16
@@ -235,8 +254,8 @@ def case_document_scorer(topo):
     assert sum(np.prod(leaf.shape) for leaf in jax.tree.leaves(params)) \
         == 4_282_936_192
     # 15.41 GB with the temporaries of the jnp chunk (PR 30); the kernel
-    # keeps them in VMEM
-    assert _hbm(compiled) < 15_410_000_000 < HBM_BYTES
+    # keeps them in VMEM: 12.18 GB (PR 36, and with attention's kernel)
+    assert _hbm(compiled) < 12_200_000_000 < 15_410_000_000 < HBM_BYTES
     text = compiled.as_text()
     assert "ragged-dot" in text                  # experts: grouped products
     # every KDA layer's chunk is the Pallas kernel, selected at lowering
@@ -248,6 +267,7 @@ def case_document_scorer(topo):
     for line in kernels:
         assert 'custom_call_target="tpu_custom_call"' in line
         assert re.search(r'op_name="[^"]*kda/scan/', line), line[:300]
+    _attention_is_the_kernel(text, model)
     top = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]", text,
                      re.M)
     chunks = t // 64
@@ -288,11 +308,13 @@ def case_document_scorer_dsv3(topo):
     compiled = jax.jit(model.forward_counted).lower(params, batch).compile()
     assert sum(np.prod(leaf.shape) for leaf in jax.tree.leaves(params)) \
         == 4_291_256_320
-    # 8.583 GB of arguments + 3.177 GB of temporaries (PR 37)
-    assert _hbm(compiled) < 12_000_000_000 < 15_000_000_000 < HBM_BYTES
+    # 8.583 GB of arguments + 3.177 GB of temporaries (PR 37); 3.008 with
+    # attention's kernel (PR 38)
+    assert _hbm(compiled) < 11_760_000_000 < 12_000_000_000 < HBM_BYTES
     text = compiled.as_text()
     assert "ragged-dot" in text                  # experts: grouped products
     assert " %kda_chunk" not in text             # no KDA layer, no kernel
+    _attention_is_the_kernel(text, model)
     top = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]", text,
                      re.M)
     assignments = t * model.top_k
