@@ -1,5 +1,6 @@
-"""A hybrid linear-attention / latent-attention mixture-of-experts language
-model that scores packed documents, forward only.
+"""A hybrid linear-attention / latent-attention / grouped-query-attention
+mixture-of-experts language model that scores packed documents, forward
+only.
 
 The batch is the loader's own flat-CSR dict read another way: a row is a
 **document**, ``ids[nnz_cap]`` are its **tokens in order** (repeats and
@@ -10,11 +11,11 @@ score a row: the mean log-probability of each next token given the tokens
 before it in the document, ``1/(n-1) sum_t log p(x_{t+1} | x_{<=t})``.
 
 The architecture arrives as the model's published ``config.json`` keys, in
-the ``kimi_linear`` or the ``deepseek_v3`` spelling (``canonical`` maps the
-second onto the first, the one the class reads), plus two that say what
-this holder keeps of a layer shared between chips: ``held_experts =
-[lo, hi]`` and ``vocab_rows``.  Pre-norm residual blocks, RMSNorm, untied
-embedding and head:
+the ``kimi_linear``, the ``deepseek_v3`` or the ``afmoe`` spelling
+(``canonical`` maps the others onto the first, the one the class reads),
+plus two that say what this holder keeps of a layer shared between chips:
+``held_experts = [lo, hi]`` and ``vocab_rows``.  Pre-norm residual blocks,
+RMSNorm, untied embedding and head:
 
 * layers in ``linear_attn_config.kda_layers`` (numbered from 1; none where
   the group is absent or the list empty) mix tokens with a gated delta
@@ -22,7 +23,8 @@ embedding and head:
   convolution and SiLU, L2-normalised ``q``/``k``, a low-rank decay gate
   and a low-rank output gate (rank = the head size, as the published
   implementation has it);
-* every other layer uses latent attention: keys and values expand from a
+* every other layer of an architecture with ``kv_lora_rank`` uses latent
+  attention: keys and values expand from a
   normalised latent of ``kv_lora_rank``; the query is one matrix, or with
   ``q_lora_rank`` a normalised low-rank pair.  The ``qk_rope_head_dim``
   columns, the key's shared by all heads, are rotated by the token's
@@ -31,15 +33,29 @@ embedding and head:
   towards ``1/factor`` of themselves below ``beta_fast`` rotations of the
   original context, the softmax scale times ``mscale^2`` — or, with
   ``mla_use_nope``, ride unrotated;
-* the first ``first_k_dense_replace`` layers have a dense SwiGLU, the rest a
+* an architecture with ``layer_types`` and no ``kv_lora_rank`` (``afmoe``)
+  uses grouped-query attention at every layer: ``num_attention_heads``
+  query heads on ``num_key_value_heads`` key heads of ``head_dim``, an
+  RMSNorm over each head's columns of ``q`` and of ``k`` (one weight
+  vector each), then on a ``sliding_attention`` layer the rotation of all
+  ``head_dim`` columns by the token's position in its document (the halves
+  paired, ``(x_i, x_{i + d/2})``, frequencies ``rope_theta^(-2i/d)``) and a
+  view of the last ``sliding_window`` keys of the document, the query's
+  own among them; on a ``full_attention`` layer no rotation and the whole
+  document.  The output is gated a channel, ``o * sigmoid(W_g x)``, before
+  ``W_o``.  Such an architecture also norms every sublayer's *output*
+  before it joins the residual (``x + post_norm(f(norm(x)))``) and, with
+  ``mup_enabled``, scales the embedding row by ``sqrt(hidden_size)``;
+* the first ``first_k_dense_replace`` (``num_dense_layers``) layers have a
+  dense SwiGLU, the rest a
   sigmoid-routed mixture plus shared experts (``ops.moe``), the choice
   limited to the ``topk_group`` best of ``num_expert_group`` groups where
   the architecture has groups.
 
 ``dtype`` (bfloat16 as published) is the type of parameters and
 activations; router scores, group sums and the choice, the rotation,
-softmax and log-softmax, RMSNorm statistics, the decay (in log space), the
-recurrent state and every accumulation are float32.  The
+softmax and log-softmax, RMSNorm statistics, the gates' sigmoid, the decay
+(in log space), the recurrent state and every accumulation are float32.  The
 ``[T, vocab_rows]`` logits exist one block of tokens at a time.
 """
 
@@ -52,7 +68,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.doc_attention import doc_causal_attention_counted
+from ..ops.doc_attention import doc_causal_attention_counted, walk_blocks
 from ..ops.kda import kda_chunked_counted
 from ..ops.moe import held_experts_sum, route, swiglu
 
@@ -73,6 +89,15 @@ SPELLINGS = {
     "moe_renormalize": "norm_topk_prob",
     "num_expert_group": "n_group",
 }
+# ... and as ``afmoe`` spells what the two above do not
+SPELLINGS_AFMOE = {
+    "first_k_dense_replace": "num_dense_layers",
+    "routed_scaling_factor": "route_scale",
+    "moe_router_activation_func": "score_func",
+    "moe_renormalize": "route_norm",
+    "num_expert_group": "num_expert_groups",
+    "topk_group": "num_limited_groups",
+}
 
 
 def load_arch(path: str) -> dict:
@@ -81,10 +106,11 @@ def load_arch(path: str) -> dict:
 
 
 def canonical(arch: dict) -> dict:
-    """``arch`` with every key of ``SPELLINGS`` under the name the class
-    reads; a file that gives both spellings has to give them alike."""
+    """``arch`` with every key of ``SPELLINGS`` and ``SPELLINGS_AFMOE``
+    under the name the class reads; a file that gives two spellings has to
+    give them alike."""
     a = dict(arch)
-    for ours, theirs in SPELLINGS.items():
+    for ours, theirs in (*SPELLINGS.items(), *SPELLINGS_AFMOE.items()):
         if theirs not in a:
             continue
         value = a.pop(theirs)
@@ -138,6 +164,13 @@ def _rotate(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
 
 
+def _rotate_halves(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """The pairs ``(x_i, x_{i + d/2})`` of the last axis turned by their
+    angle, float32, each where it was (``x cos + rotate_half(x) sin``)."""
+    a, b = jnp.split(x.astype(F32), 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
+
+
 def _rms(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     xf = x.astype(F32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
@@ -186,17 +219,27 @@ class HybridMoELM:
             self.conv_taps = int(lin["short_conv_kernel_size"])
             self.gate_rank = self.kda_dim
         self.heads = int(a["num_attention_heads"])
-        self.q_rank = a.get("q_lora_rank") and int(a["q_lora_rank"])
-        self.kv_rank = int(a["kv_lora_rank"])
-        self.d_nope = int(a["qk_nope_head_dim"])
-        self.d_rope = int(a["qk_rope_head_dim"])
-        self.d_v = int(a["v_head_dim"])
-        self.rope_freqs, mscale = None, 1.0
-        if not a.get("mla_use_nope", False):
-            self.rope_freqs, mscale = rope_frequencies(
-                self.d_rope, float(a.get("rope_theta", 10000.0)),
-                a.get("rope_scaling"))
-        self.attn_scale = (self.d_nope + self.d_rope) ** -0.5 * mscale ** 2
+        theta = float(a.get("rope_theta", 10000.0))
+        # what the layers outside ``kda_layers`` mix tokens with
+        self.attention = "gqa" if "layer_types" in a \
+            and "kv_lora_rank" not in a else "mla"
+        if self.attention == "gqa":
+            self._init_gqa(a, theta)
+        else:
+            self.q_rank = a.get("q_lora_rank") and int(a["q_lora_rank"])
+            self.kv_rank = int(a["kv_lora_rank"])
+            self.d_nope = int(a["qk_nope_head_dim"])
+            self.d_rope = int(a["qk_rope_head_dim"])
+            self.d_v = int(a["v_head_dim"])
+            self.rope_freqs, mscale = None, 1.0
+            if not a.get("mla_use_nope", False):
+                self.rope_freqs, mscale = rope_frequencies(
+                    self.d_rope, theta, a.get("rope_scaling"))
+            self.attn_scale = (self.d_nope + self.d_rope) ** -0.5 \
+                * mscale ** 2
+        # the norm on a sublayer's output and the embedding's multiplier
+        self.sandwich = self.attention == "gqa"
+        self.embed_scale = self.hidden ** 0.5 if a.get("mup_enabled") else 1.0
         self.dense_layers = int(a["first_k_dense_replace"])
         self.dense_width = int(a["intermediate_size"])
         self.expert_width = int(a["moe_intermediate_size"])
@@ -222,8 +265,32 @@ class HybridMoELM:
                              f"{self.experts} experts")
         self.vocab = int(a.get("vocab_rows", a["vocab_size"]))
 
+    def _init_gqa(self, a: dict, theta: float) -> None:
+        self.kv_heads = int(a["num_key_value_heads"])
+        self.head_dim = int(a["head_dim"])
+        if self.heads % self.kv_heads:
+            raise ValueError(f"{self.heads} query heads on {self.kv_heads} "
+                             f"key heads are no whole groups")
+        types = list(a["layer_types"])[:self.layers]
+        known = ("sliding_attention", "full_attention")
+        if len(types) < self.layers or set(types) - set(known):
+            raise ValueError(f"layer_types has to say one of {known} for "
+                             f"each of the {self.layers} layers, it says "
+                             f"{types}")
+        self.sliding = {n for n, kind in enumerate(types, 1)
+                        if kind == known[0]}
+        self.window = int(a["sliding_window"])
+        if self.window < 1:
+            raise ValueError(f"sliding_window {self.window}")
+        if a.get("rope_scaling"):
+            raise ValueError("hybrid_moe_lm computes rope_scaling=None only "
+                             "under grouped-query attention, the "
+                             f"architecture says {a['rope_scaling']!r}")
+        self.rope_freqs, _ = rope_frequencies(self.head_dim, theta, None)
+        self.attn_scale = self.head_dim ** -0.5
+
     def mixer(self, layer: int) -> str:
-        return "kda" if layer in self.kda_layers else "mla"
+        return "kda" if layer in self.kda_layers else self.attention
 
     # -- parameters -------------------------------------------------------
     def _layer_shapes(self, layer: int) -> Dict[str, tuple]:
@@ -239,6 +306,11 @@ class HybridMoELM:
                      w_beta=(h, self.kda_heads),
                      gate_down=(h, r), gate_up=(r, n),
                      out_norm=(self.kda_dim,), wo=(n, h))
+        elif self.attention == "gqa":
+            d = self.head_dim
+            n, nkv = self.heads * d, self.kv_heads * d
+            s.update(wq=(h, n), wk=(h, nkv), wv=(h, nkv), w_gate=(h, n),
+                     q_head_norm=(d,), k_head_norm=(d,), wo=(n, h))
         else:
             dq = self.heads * (self.d_nope + self.d_rope)
             if self.q_rank:
@@ -251,6 +323,8 @@ class HybridMoELM:
                      wkv_b=(self.kv_rank,
                             self.heads * (self.d_nope + self.d_v)),
                      wo=(self.heads * self.d_v, h))
+        if self.sandwich:
+            s.update(post_norm1=(h,), post_norm2=(h,))
         if layer <= self.dense_layers:
             s.update(w_gu=(h, 2 * self.dense_width),
                      w_down=(self.dense_width, h))
@@ -327,10 +401,11 @@ class HybridMoELM:
 
     def _turn(self, pos):
         """(cos, sin) ``[T, d_rope / 2]`` of every token's rotation, or None
-        where the architecture rotates nothing: the same for every layer."""
+        where the architecture rotates nothing: the same for every layer
+        that rotates."""
         if self.rope_freqs is None:
             return None
-        with jax.named_scope("mla/rope"):
+        with jax.named_scope(f"{self.attention}/rope"):
             angle = pos.astype(F32)[:, None] * jnp.asarray(
                 self.rope_freqs, F32)[None, :]
             return jnp.cos(angle), jnp.sin(angle)
@@ -370,6 +445,43 @@ class HybridMoELM:
         with jax.named_scope("mla/out"):
             return _mm(o.astype(x.dtype).reshape(t, nh * dv), p["wo"]), fused
 
+    def _gqa(self, p, x, seg, first_key, turn):
+        """``first_key [T]`` the first key each token sees in this layer;
+        ``turn`` None on a layer that rotates nothing."""
+        t = x.shape[0]
+        nh, nkv, d = self.heads, self.kv_heads, self.head_dim
+        with jax.named_scope("gqa/project"):
+            q = _mm(x, p["wq"]).reshape(t, nh, d)
+            k = _mm(x, p["wk"]).reshape(t, nkv, d)
+            v = _mm(x, p["wv"]).reshape(t, nkv, d)
+            gate = _mm(x, p["w_gate"])
+        with jax.named_scope("gqa/qk_norm"):
+            q = _rms(q, p["q_head_norm"], self.eps)
+            k = _rms(k, p["k_head_norm"], self.eps)
+            if turn is None:
+                q = (q.astype(F32) * self.attn_scale).astype(x.dtype)
+        if turn is not None:
+            with jax.named_scope("gqa/rope"):
+                cos, sin = turn[0][:, None], turn[1][:, None]
+                q = (_rotate_halves(q, cos, sin)
+                     * self.attn_scale).astype(x.dtype)
+                k = _rotate_halves(k, cos, sin).astype(x.dtype)
+        with jax.named_scope("gqa/attention"):
+            o, fused = doc_causal_attention_counted(q, k, v, seg, first_key)
+        with jax.named_scope("gqa/gate"):
+            o = (o.reshape(t, nh * d)
+                 * jax.nn.sigmoid(gate.astype(F32))).astype(x.dtype)
+        with jax.named_scope("gqa/out"):
+            return _mm(o, p["wo"]), fused
+
+    def _post(self, p, name, out):
+        """A sublayer's output as it joins the residual: through its own
+        RMSNorm where the architecture has one."""
+        if not self.sandwich:
+            return out
+        with jax.named_scope("post_norm"):
+            return _rms(out, p[name], self.eps)
+
     def _moe(self, p, x, live):
         with jax.named_scope("moe/router"):
             chosen, weights = route(x, p["router"], p["router_bias"],
@@ -396,28 +508,41 @@ class HybridMoELM:
         turn = self._turn(pos)
         with jax.named_scope("lm_embed"):
             x = params["embed"][ids]
+            if self.embed_scale != 1.0:
+                x = (x.astype(F32) * self.embed_scale).astype(x.dtype)
         counters = {"kda.fused_layers": jnp.int32(0),
-                    "mla.fused_layers": jnp.int32(0)}
+                    "mla.fused_layers": jnp.int32(0),
+                    "gqa.fused_layers": jnp.int32(0)}
+        if self.attention == "gqa":
+            # the first key a token sees: on a full layer its document's
+            # start, on a sliding one no more than a window back
+            in_window = jnp.maximum(
+                doc_start, jnp.arange(t, dtype=jnp.int32) - (self.window - 1))
+            counters["attn.key_blocks_full"] = walk_blocks(doc_start)
+            counters["attn.key_blocks_window"] = walk_blocks(in_window)
         choices = {}
         for layer in range(1, self.layers + 1):
             name = f"layer_{layer:02d}"
             p = params[name]
             y = _rms(x, p["norm1"], self.eps)
-            if self.mixer(layer) == "kda":
+            mixer = self.mixer(layer)
+            if mixer == "kda":
                 out, fused = self._kda(p, y, seg, pos)
-                x = x + out
-                counters["kda.fused_layers"] += fused
-            else:
+            elif mixer == "mla":
                 out, fused = self._mla(p, y, seg, doc_start, turn)
-                x = x + out
-                counters["mla.fused_layers"] += fused
+            elif layer in self.sliding:
+                out, fused = self._gqa(p, y, seg, in_window, turn)
+            else:
+                out, fused = self._gqa(p, y, seg, doc_start, None)
+            counters[f"{mixer}.fused_layers"] += fused
+            x = x + self._post(p, "post_norm1", out)
             y = _rms(x, p["norm2"], self.eps)
             if layer <= self.dense_layers:
                 with jax.named_scope("dense_mlp"):
-                    x = x + swiglu(y, p["w_gu"], p["w_down"])
+                    out = swiglu(y, p["w_gu"], p["w_down"])
             else:
                 out, counters[name], choices[name] = self._moe(p, y, live)
-                x = x + out
+            x = x + self._post(p, "post_norm2", out)
         return _rms(x, params["final_norm"], self.eps), counters, choices
 
     def forward_counted(self, params: Params, batch: Dict[str, jax.Array]):
@@ -425,9 +550,13 @@ class HybridMoELM:
         the assignments that reached held experts, the largest and the mean
         load of a held expert, the live tokens none of whose experts is
         held and the rows the dispatch gathered for them; the batch's
-        tokens and documents; ``kda.fused_layers`` and ``mla.fused_layers``,
-        how many KDA layers of this program took the chunk kernel and how
-        many MLA layers the attention kernel."""
+        tokens and documents; ``kda.fused_layers``, ``mla.fused_layers`` and
+        ``gqa.fused_layers``, how many KDA layers of this program took the
+        chunk kernel and how many layers of either attention the attention
+        kernel; under grouped-query attention ``attn.key_blocks_full`` and
+        ``attn.key_blocks_window``, the key blocks one full and one sliding
+        layer's walk visits for this batch (``ops.doc_attention.
+        walk_blocks``)."""
         seg, row_ptr = batch["segments"], batch["row_ptr"]
         rows = row_ptr.shape[0] - 1
         t = seg.shape[0]

@@ -335,7 +335,7 @@ def test_attention_kernel_is_one_document_at_a_time(template, whole, dv,
     assert (t % block == 0) == whole and t > block
     if head_blocks == 2:        # room for two heads' tiles and state only
         monkeypatch.setattr(doc_attention, "_VMEM_BUDGET", 4_000_000)
-    assert doc_attention._head_block(h, dqk, dv, block, 4) == 2
+    assert doc_attention._head_block(h, h, dqk, dv, block, 4) == 2
     q, k, v, seg, start = attention_inputs(lengths, h, dqk, dv,
                                            seed=t + dv + head_blocks)
     f32 = lambda x: jnp.asarray(x, jnp.float32)            # noqa: E731
@@ -359,7 +359,7 @@ def test_attention_kernel_and_jnp_path_agree_in_bfloat16(dv):
     q, k, v, seg, start = attention_inputs(lengths, 2, 192, dv, seed=3)
     bf = lambda x: jnp.asarray(x, jnp.bfloat16)               # noqa: E731
     args = (bf(q), bf(k), bf(v), seg, start)
-    assert doc_attention._kernel_fits(*args[:3:2])
+    assert doc_attention._kernel_fits(*args[:3])
     got = doc_attention._attention_kernel(*args, interpret=True)
     want = doc_attention._attention_jnp(*args, doc_attention._BLOCK)
     assert got.dtype == want.dtype == jnp.float32
@@ -383,20 +383,21 @@ def test_attention_kernel_rule_is_a_function_of_the_shapes(t, dqk, dv, dtype,
                                                            fits):
     q = jax.ShapeDtypeStruct((t, 4, dqk), jnp.dtype(dtype))
     v = jax.ShapeDtypeStruct((t, 4, dv), jnp.dtype(dtype))
-    assert doc_attention._kernel_fits(q, v) == fits
+    assert doc_attention._kernel_fits(q, q, v) == fits
     # three heads of 192: no head block's keys are whole tiles of lanes
     odd = jax.ShapeDtypeStruct((t, 3, dqk), jnp.dtype(dtype))
-    assert doc_attention._kernel_fits(odd, odd) == (fits and dqk % 128 == 0)
+    assert doc_attention._kernel_fits(odd, odd, odd) == (
+        fits and dqk % 128 == 0)
 
 
 def test_attention_head_block_is_sized_from_vmem():
     block = doc_attention._BLOCK
     # the cells: 64 heads of 192 / 192 and 32 of 192 / 128, bfloat16
-    assert doc_attention._head_block(64, 192, 192, block, 2) == 8
-    assert doc_attention._head_block(32, 192, 128, block, 2) == 16
-    assert doc_attention._head_block(3, 256, 192, block, 2) == 3
-    assert doc_attention._head_block(3, 192, 192, block, 2) is None
-    assert doc_attention._head_block(7, 4096, 4096, block, 2) is None
+    assert doc_attention._head_block(64, 64, 192, 192, block, 2) == 8
+    assert doc_attention._head_block(32, 32, 192, 128, block, 2) == 16
+    assert doc_attention._head_block(3, 3, 256, 192, block, 2) == 3
+    assert doc_attention._head_block(3, 3, 192, 192, block, 2) is None
+    assert doc_attention._head_block(7, 7, 4096, 4096, block, 2) is None
 
 
 def test_eligible_attention_takes_the_jnp_path_on_the_cpu():
@@ -405,7 +406,7 @@ def test_eligible_attention_takes_the_jnp_path_on_the_cpu():
     lengths = ATTENTION_TEMPLATES["many_short"][0]
     q, k, v, seg, start = attention_inputs(lengths, 2, 192, 128, seed=7)
     bf = lambda x: jnp.asarray(x, jnp.bfloat16)               # noqa: E731
-    assert doc_attention._kernel_fits(bf(q), bf(v))
+    assert doc_attention._kernel_fits(bf(q), bf(k), bf(v))
     got, fused = jax.jit(doc_attention.doc_causal_attention_counted)(
         bf(q), bf(k), bf(v), seg, start)
     assert int(fused) == 0

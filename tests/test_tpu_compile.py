@@ -211,22 +211,23 @@ def case_mesh_step(topo):
     assert _hbm(compiled) < HBM_BYTES      # bytes on each device
 
 
-def _attention_is_the_kernel(text, model):
-    """Every MLA layer's attention is one ``doc_attention`` Pallas kernel,
-    selected at lowering, under the scope a trace reads, and no loop of
-    the ``jnp`` walk is left under that scope."""
+def _attention_is_the_kernel(text, model, mixer="mla"):
+    """Every ``mixer`` layer's attention is one ``doc_attention`` Pallas
+    kernel, selected at lowering, under the scope a trace reads, and no
+    loop of the ``jnp`` walk is left under that scope."""
     import re
     kernels = [line for line in text.splitlines()
                if "custom-call(" in line and " %doc_attention" in line]
-    assert len(kernels) == sum(model.mixer(layer) == "mla"
+    assert len(kernels) == sum(model.mixer(layer) == mixer
                                for layer in range(1, model.layers + 1))
     for line in kernels:
         assert 'custom_call_target="tpu_custom_call"' in line
-        assert re.search(r'op_name="[^"]*mla/attention/', line), line[:300]
+        assert re.search(rf'op_name="[^"]*{mixer}/attention/', line), \
+            line[:300]
     loops = [line for line in text.splitlines() if " while(" in line]
     assert loops                         # the head's and the dispatch's
     for line in loops:
-        assert not re.search(r'op_name="[^"]*mla/attention', line), \
+        assert not re.search(rf'op_name="[^"]*{mixer}/attention', line), \
             line[-300:]
 
 
@@ -329,6 +330,61 @@ def case_document_scorer_dsv3(topo):
         "moe/experts", "moe/shared", "moe/combine", "dense_mlp", "lm_head"])
 
 
+def case_document_scorer_afmoe(topo):
+    """The same class on the ``afmoe``-type configuration at its timed
+    sizes (one dense and four mixture layers at published widths, 32 of 256
+    experts, an eighth of the vocabulary, 8 documents in 32 768 tokens,
+    bfloat16): it fits the chip beside its 8.64 GB of parameters, every
+    layer's attention is the kernel on grouped keys — no key or value array
+    repeated to the 48 query heads exists — and neither the ``[T, T]``
+    scores nor the ``[T, V]`` logits exist whole."""
+    import re
+
+    from dmlc_core_tpu.models.hybrid_lm import HybridMoELM, load_arch
+    here = os.path.dirname(os.path.abspath(__file__))
+    model = HybridMoELM(load_arch(os.path.join(
+        here, "..", "benchmarks", "chip", "configs",
+        "trinity_large_400b_ep8_l5.json")))
+    one = SingleDeviceSharding(topo.devices[0])
+    S, i32 = jax.ShapeDtypeStruct, jnp.int32
+    t, rows = 32768, 8
+    params = jax.tree.map(lambda shape: S(shape, model.dtype, sharding=one),
+                          model.shapes(),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    batch = _on(one, dict(_batch(rows, t), row_ptr=S((rows + 1,), i32)))
+    compiled = jax.jit(model.forward_counted).lower(params, batch).compile()
+    assert sum(np.prod(leaf.shape) for leaf in jax.tree.leaves(params)) \
+        == 4_321_903_872
+    # 8.644 GB of arguments + the temporaries (PR 39; ISSUE 39 expected
+    # 12-13.5 GB in all)
+    assert _hbm(compiled) < 13_500_000_000 < HBM_BYTES
+    text = compiled.as_text()
+    assert "ragged-dot" in text                  # experts: grouped products
+    assert " %kda_chunk" not in text             # no KDA layer, no kernel
+    _attention_is_the_kernel(text, model, "gqa")
+    top = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]", text,
+                     re.M)
+    assignments = t * model.top_k
+    for dims in top:
+        d = [int(x) for x in dims.split(",")]
+        assert not (t in d and model.vocab in d), dims     # [T, V]
+        assert d.count(t) < 2, dims                        # [T, T]
+        assert not (assignments in d and model.hidden in d), dims
+    # keys and values leave the projections 8 heads wide and reach the
+    # kernel so, [T, 8 * 128] and [8, 128, T] beside q's [48, 128, T]
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line and " %doc_attention" in line]
+    for line in kernels:
+        assert f"bf16[{t},{model.kv_heads * model.head_dim}]" in line
+        assert f"bf16[{model.kv_heads},{model.head_dim},{t}]" in line
+        assert f"bf16[{model.heads},{model.head_dim},{t}]" in line
+    _names_scopes(compiled, [
+        "lm_embed", "gqa/project", "gqa/qk_norm", "gqa/rope",
+        "gqa/attention", "gqa/gate", "gqa/out", "post_norm", "moe/router",
+        "moe/dispatch", "moe/experts", "moe/shared", "moe/combine",
+        "dense_mlp", "lm_head"])
+
+
 CASES = {
     "gather_embed_128": case_kernel(lambda one, w: _gather(one, w, False)),
     "gather_fm_128": case_kernel(lambda one, w: _gather(one, w, True)),
@@ -345,6 +401,7 @@ CASES = {
     "fm_mesh_dp2_mp2_step": case_mesh_step,
     "document_scorer_forward": case_document_scorer,
     "document_scorer_dsv3_forward": case_document_scorer_dsv3,
+    "document_scorer_afmoe_forward": case_document_scorer_afmoe,
 }
 
 
