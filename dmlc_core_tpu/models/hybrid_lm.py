@@ -488,8 +488,7 @@ class HybridMoELM:
                                     self.top_k, self.route_scale,
                                     self.groups, self.groups_kept)
         routed, counters = held_experts_sum(
-            x, chosen, weights, live, p["e_gu"], p["e_down"], self.held,
-            self.experts)
+            x, chosen, weights, live, p["e_gu"], p["e_down"], self.held)
         with jax.named_scope("moe/shared"):
             shared = swiglu(x, p["s_gu"], p["s_down"])
         return routed + shared, counters, chosen
@@ -512,7 +511,8 @@ class HybridMoELM:
                 x = (x.astype(F32) * self.embed_scale).astype(x.dtype)
         counters = {"kda.fused_layers": jnp.int32(0),
                     "mla.fused_layers": jnp.int32(0),
-                    "gqa.fused_layers": jnp.int32(0)}
+                    "gqa.fused_layers": jnp.int32(0),
+                    "moe.fused_combines": jnp.int32(0)}
         if self.attention == "gqa":
             # the first key a token sees: on a full layer its document's
             # start, on a sliding one no more than a window back
@@ -542,6 +542,8 @@ class HybridMoELM:
                     out = swiglu(y, p["w_gu"], p["w_down"])
             else:
                 out, counters[name], choices[name] = self._moe(p, y, live)
+                counters["moe.fused_combines"] += counters[name].pop(
+                    "fused_combine")
             x = x + self._post(p, "post_norm2", out)
         return _rms(x, params["final_norm"], self.eps), counters, choices
 
@@ -553,10 +555,11 @@ class HybridMoELM:
         tokens and documents; ``kda.fused_layers``, ``mla.fused_layers`` and
         ``gqa.fused_layers``, how many KDA layers of this program took the
         chunk kernel and how many layers of either attention the attention
-        kernel; under grouped-query attention ``attn.key_blocks_full`` and
-        ``attn.key_blocks_window``, the key blocks one full and one sliding
-        layer's walk visits for this batch (``ops.doc_attention.
-        walk_blocks``)."""
+        kernel, and ``moe.fused_combines``, how many mixture layers added
+        their experts' rows by the combine kernel; under grouped-query
+        attention ``attn.key_blocks_full`` and ``attn.key_blocks_window``,
+        the key blocks one full and one sliding layer's walk visits for this
+        batch (``ops.doc_attention.walk_blocks``)."""
         seg, row_ptr = batch["segments"], batch["row_ptr"]
         rows = row_ptr.shape[0] - 1
         t = seg.shape[0]

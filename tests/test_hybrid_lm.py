@@ -264,6 +264,7 @@ def test_counters_say_how_many_layers_took_the_kernel(model, params, scorer):
     rec = HybridMoELM.counter_record(counters)
     assert rec["kda.fused_layers"] == 0.0                  # the CPU: none
     assert rec["mla.fused_layers"] == 0.0
+    assert rec["moe.fused_combines"] == 0.0                # nor the combine
     assert rec["tokens"] == sum(TEMPLATES["mixed"])
 
 
@@ -562,6 +563,7 @@ def test_predict_scores_documents_through_the_cli(run_dir):
     assert sum(r["attrs"]["documents"] for r in recs[-2:]) == len(docs)
     assert all(r["attrs"]["kda.fused_layers"] == 0 for r in recs[-2:])
     assert all(r["attrs"]["mla.fused_layers"] == 0 for r in recs[-2:])
+    assert all(r["attrs"]["moe.fused_combines"] == 0 for r in recs[-2:])
 
 
 @pytest.mark.parametrize("over,why", [

@@ -99,7 +99,6 @@ def test_the_benchmark_s_configuration_is_the_issue_s_arithmetic():
     assert m.sliding == {1, 2, 3, 4} and m.dense_layers == 1
     assert (m.top_k, m.experts, m.held, m.groups) == (4, 256, (0, 32), 1)
     assert m.route_scale == 2.448 and m.embed_scale == 3072 ** 0.5
-    assert moe._takes_blocks(32, 256)          # an eighth held: the blocks
 
 
 @pytest.mark.parametrize("template", sorted(TEMPLATES))
@@ -132,6 +131,7 @@ def test_forward_agrees_with_the_reference(model, params, scorer, template):
     assert rec["tokens"] == total and rec["documents"] == len(lengths)
     assert rec["kda.fused_layers"] == rec["mla.fused_layers"] == 0.0
     assert rec["gqa.fused_layers"] == 0.0              # the CPU: jnp
+    assert rec["moe.fused_combines"] == 0.0            # and the scatter
     # 640 tokens are three blocks of 256: a window of 16 walks a block and
     # the one before it, the one document longer than two blocks one more
     assert rec["attn.key_blocks_window"] == 5.0
@@ -237,7 +237,6 @@ def test_the_shares_add_up_to_the_uncut_layer(model, params, layer):
     total, loads = 0.0, 0
     for lo in range(0, 16, 2):
         share = HybridMoELM(dict(ARCH, held_experts=[lo, lo + 2]))
-        assert moe._takes_blocks(2, 16)
         mine = dict(whole, e_gu=whole["e_gu"][lo:lo + 2],
                     e_down=whole["e_down"][lo:lo + 2])
         out, counters, _ = share._moe(mine, x, live)
@@ -340,7 +339,7 @@ def test_predict_scores_an_afmoe_arch_file_through_the_cli(tmp_path):
     assert sum(r["documents"] for r in recs) == len(docs)
     for r in recs:
         assert r["kda.fused_layers"] == r["mla.fused_layers"] == 0
-        assert r["gqa.fused_layers"] == 0
+        assert r["gqa.fused_layers"] == 0 and r["moe.fused_combines"] == 0
         assert 0 < r["attn.key_blocks_window"] <= r["attn.key_blocks_full"]
         for layer in MIXTURES:
             assert {f"{layer}.{c}" for c in (

@@ -94,6 +94,7 @@ def test_forward_agrees_with_the_reference(model, params, scorer, template):
     assert rec["tokens"] == total and rec["documents"] == len(lengths)
     assert rec["kda.fused_layers"] == 0.0              # no KDA layer at all
     assert rec["mla.fused_layers"] == 0.0              # the CPU: jnp
+    assert rec["moe.fused_combines"] == 0.0            # and the scatter
     for name in ref["chosen"]:       # 3 of 16 held: the blocked form
         assert rec[f"{name}.assignments"] <= rec[f"{name}.dispatch_rows"] \
             < rec[f"{name}.assignments"] + moe.DISPATCH_BLOCK
@@ -300,7 +301,7 @@ def test_blocked_dispatch_is_the_whole_buffer(block, held):
     def blocked(x, chosen, weights, live, e_gu, e_down):
         _, order, loads, w = moe._sorted_assignments(chosen, weights, live,
                                                      held)
-        return moe._blocked_sum(x, order, loads, w, e_gu, e_down, block)
+        return moe._blocked_sum(x, order, loads, w, e_gu, e_down, block)[:2]
 
     got, dispatched = jax.jit(blocked)(x, chosen, weights, live, e_gu,
                                        e_down)
@@ -315,16 +316,15 @@ def test_blocked_dispatch_is_the_whole_buffer(block, held):
         assert not np.asarray(got).any()
 
 
-@pytest.mark.parametrize("held, blocks", [
-    ((0, 4), False), ((0, 3), True), ((16, 20), False), ((17, 20), True)],
-    ids=["whole", "blocked", "whole_none", "blocked_none"])
-def test_the_layer_takes_one_form_by_the_share_held(held, blocks):
-    """No caller chooses: a holder of a fifth or more of the experts sorts
-    all ``T * k`` rows into one buffer, a smaller one walks whole blocks of
-    its held rows; either way the sum and the counters are the whole
-    buffer's."""
+@pytest.mark.parametrize("held", [(0, 4), (0, 3), (16, 20), (17, 20)],
+                         ids=["a_fifth", "under_a_fifth", "a_fifth_none",
+                              "under_a_fifth_none"])
+def test_the_layer_takes_one_form_at_every_share_held(held):
+    """No caller chooses and no rule does: whatever share of the experts a
+    holder has, it walks whole blocks of its held rows, and the sum and the
+    counters are the whole buffer's."""
     rng = np.random.default_rng(5)
-    t, h, i, k, experts = 150, 32, 16, 4, 20
+    t, h, i, k = 150, 32, 16, 4
     g = held[1] - held[0]
     x = jnp.asarray(rng.normal(size=(t, h)), jnp.float32)
     chosen = jnp.asarray(np.stack([rng.permutation(16)[:k]
@@ -335,31 +335,57 @@ def test_the_layer_takes_one_form_by_the_share_held(held, blocks):
     e_down = jnp.asarray(rng.normal(size=(g, i, h)) / 4, jnp.float32)
     want, loads = whole_buffer_dispatch(x, chosen, weights, live, e_gu,
                                         e_down, held)
-    got, counters = jax.jit(moe.held_experts_sum, static_argnums=(6, 7))(
-        x, chosen, weights, live, e_gu, e_down, held, experts)
+    got, counters = jax.jit(moe.held_experts_sum, static_argnums=6)(
+        x, chosen, weights, live, e_gu, e_down, held)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     rows = int(loads.sum())
     assert int(counters["assignments"]) == rows
     assert int(counters["load_max"]) == int(loads.max())
-    if moe._takes_blocks(g, experts):
-        size = min(moe.DISPATCH_BLOCK, t * k)
-        assert int(counters["dispatch_rows"]) == -(-rows // size) * size
-    else:
-        assert int(counters["dispatch_rows"]) == t * k
-    assert moe._takes_blocks(g, experts) is blocks
+    size = min(moe.DISPATCH_BLOCK, t * k)
+    assert int(counters["dispatch_rows"]) == -(-rows // size) * size
+    assert int(counters["fused_combine"]) == 0             # the CPU
 
 
-@pytest.mark.parametrize("config, blocks", [
-    ("kimi_linear_48b_ep2_l5", False), ("gigachat31_702b_ep16_l5", True)])
-def test_which_form_each_benchmark_configuration_takes(config, blocks):
-    """Kimi's holder of half the experts keeps the whole buffer, the
-    holder of a sixteenth walks blocks (PERF.md, PR 37: measured at these
-    two shares; ROADMAP S5 names what makes it one form)."""
-    arch = hybrid_lm.load_arch(os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmarks", "chip", "configs", config + ".json"))
-    m = HybridMoELM(arch)
-    assert moe._takes_blocks(m.held[1] - m.held[0], m.experts) is blocks
+# toy widths under the names the class reads (``hybrid_lm.canonical``)
+TINY = {
+    "hidden_size": 64, "intermediate_size": 128, "q_lora_rank": 32,
+    "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+    "v_head_dim": 16, "head_dim": 16, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "sliding_window": 16,
+    "moe_intermediate_size": 32, "num_experts": 16,
+    "num_experts_per_token": 2, "num_expert_group": 1, "topk_group": 1,
+    "held_experts": [0, 8], "vocab_size": 1024, "vocab_rows": 512,
+    "dtype": "float32",
+}
+
+
+@pytest.mark.parametrize("config", [
+    "kimi_linear_48b_ep2_l5", "gigachat31_702b_ep16_l5",
+    "trinity_large_400b_ep8_l5"])
+def test_each_benchmark_configuration_counts_its_fused_combines(config):
+    """Each benchmark configuration, cut to toy widths: every mixture layer
+    goes through the one form, and what ``forward_counted`` reports — the
+    ``lm.batch`` event's keys — holds ``moe.fused_combines``, 0 on the CPU
+    (4 on the chip: the combine kernel, chosen at lowering)."""
+    with open(os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmarks", "chip", "configs", config + ".json")) as f:
+        cfg = hybrid_lm.canonical(json.load(f))
+    cfg.update({k: v for k, v in TINY.items() if k in cfg})
+    cfg["linear_attn_config"].update(head_dim=16, num_heads=4)
+    m = HybridMoELM(cfg)
+    assert m.layers - m.dense_layers == 4
+    batch, _ = make_batch([35, 130, 64, 91])
+    _, counters = jax.jit(m.forward_counted)(
+        m.init(jax.random.PRNGKey(0)), batch)
+    rec = HybridMoELM.counter_record(counters)
+    assert rec["moe.fused_combines"] == 0.0
+    mixtures = sorted(k[:-len(".dispatch_rows")] for k in rec
+                      if k.endswith(".dispatch_rows"))
+    assert len(mixtures) == 4
+    for name in mixtures:
+        assert f"{name}.fused_combine" not in rec
+        assert rec[f"{name}.assignments"] <= rec[f"{name}.dispatch_rows"]
 
 
 def test_both_spellings_load_to_one_model(params):
@@ -444,6 +470,7 @@ def test_predict_scores_a_deepseek_v3_arch_file_through_the_cli(tmp_path):
     assert sum(r["documents"] for r in recs) == len(docs)
     for r in recs:
         assert r["kda.fused_layers"] == 0 and r["mla.fused_layers"] == 0
+        assert r["moe.fused_combines"] == 0
         for layer in ("layer_02", "layer_03", "layer_04"):
             assert {f"{layer}.{c}" for c in (
                 "assignments", "load_max", "load_mean", "unserved_tokens",

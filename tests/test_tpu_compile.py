@@ -231,6 +231,21 @@ def _attention_is_the_kernel(text, model, mixer="mla"):
             line[-300:]
 
 
+def _combine_is_the_kernel(text, model, t):
+    """Every mixture layer adds its blocks' rows to their tokens by the
+    ``moe_combine`` Pallas kernel, selected at lowering, under the scope a
+    trace reads; no scatter into the ``[T, H]`` float32 sums is left."""
+    import re
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line and " %moe_combine" in line]
+    assert len(kernels) == model.layers - model.dense_layers
+    for line in kernels:
+        assert 'custom_call_target="tpu_custom_call"' in line
+        assert re.search(r'op_name="[^"]*moe/combine/', line), line[:300]
+    sums = rf"f32\[{t},(?:{model.hidden}|{model.hidden // 128},128)\]"
+    assert not re.search(rf"= {sums}\S* scatter\(", text)
+
+
 def case_document_scorer(topo):
     """The document scorer at the benchmark's published widths and timed
     sizes (layers 1-5, 128 of 256 experts, half the vocabulary, 16
@@ -269,6 +284,10 @@ def case_document_scorer(topo):
         assert 'custom_call_target="tpu_custom_call"' in line
         assert re.search(r'op_name="[^"]*kda/scan/', line), line[:300]
     _attention_is_the_kernel(text, model)
+    # half the experts held, and no ``[T * k, H]`` buffer: the temporaries
+    # are under the whole buffer's 3.61 GB (PR 36-39)
+    _combine_is_the_kernel(text, model, t)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3_610_738_176
     top = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]", text,
                      re.M)
     chunks = t // 64
@@ -316,6 +335,7 @@ def case_document_scorer_dsv3(topo):
     assert "ragged-dot" in text                  # experts: grouped products
     assert " %kda_chunk" not in text             # no KDA layer, no kernel
     _attention_is_the_kernel(text, model)
+    _combine_is_the_kernel(text, model, t)
     top = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]", text,
                      re.M)
     assignments = t * model.top_k
@@ -362,6 +382,7 @@ def case_document_scorer_afmoe(topo):
     assert "ragged-dot" in text                  # experts: grouped products
     assert " %kda_chunk" not in text             # no KDA layer, no kernel
     _attention_is_the_kernel(text, model, "gqa")
+    _combine_is_the_kernel(text, model, t)
     top = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]", text,
                      re.M)
     assignments = t * model.top_k
