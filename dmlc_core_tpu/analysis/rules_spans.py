@@ -10,7 +10,9 @@ documented trace-topology diagram still referenced.  Mirrors
 ``metric-vocabulary``, both directions:
 
 * every **literal** name passed to ``span()`` / ``start_span()`` /
-  ``record_completed()`` must
+  ``record_completed()``, and every literal of a ``wait_spans=(...)``
+  keyword (the names a queue's owner gives its waits,
+  ``utils.threaded_iter``), must
   match the span grammar (lowercase dotted segments; single-segment
   names like ``reshard`` are legal for whole-subsystem spans);
 * every such name must be covered by a row in the span catalog of
@@ -57,17 +59,23 @@ class SpanVocabularyRule(LintRule):
             fn = node.func
             callee = (fn.attr if isinstance(fn, ast.Attribute)
                       else fn.id if isinstance(fn, ast.Name) else None)
-            if callee not in _SPAN_FUNCS:
-                continue
-            name = str_const(node.args[0]) if node.args else None
-            if name is None:        # dynamic name — wildcard family
-                continue
-            ctx.note_span(name, mod.rel)
-            if not _GRAMMAR.match(name):
-                out.append(Finding(
-                    self.name, mod.rel, node.lineno, node.col_offset,
-                    f"span name {name!r} violates the span grammar "
-                    f"(lowercase dotted segments)"))
+            names = []
+            if callee in _SPAN_FUNCS and node.args:
+                names.append(str_const(node.args[0]))
+            # a queue's owner names its waits at the call that builds the
+            # queue: ``ThreadedIter(..., wait_spans=("q.wait_slot", None))``
+            for kw in node.keywords:
+                if kw.arg == "wait_spans" and isinstance(kw.value, ast.Tuple):
+                    names.extend(str_const(e) for e in kw.value.elts)
+            for name in names:
+                if name is None:    # dynamic name — wildcard family
+                    continue
+                ctx.note_span(name, mod.rel)
+                if not _GRAMMAR.match(name):
+                    out.append(Finding(
+                        self.name, mod.rel, node.lineno, node.col_offset,
+                        f"span name {name!r} violates the span grammar "
+                        f"(lowercase dotted segments)"))
         return out
 
     def finalize(self, ctx: LintContext) -> List[Finding]:

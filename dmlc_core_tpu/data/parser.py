@@ -24,6 +24,7 @@ import numpy as np
 
 from .. import native
 from ..io import create_input_split, URISpec
+from ..telemetry import trace as teltrace
 from ..utils import (DMLCError, Parameter, Registry, ThreadedIter, check,
                      field)
 from . import py_parsers
@@ -82,6 +83,9 @@ class TextParser(ParserBase):
         self.source = source
         self.parse_fn = parse_fn
         self.nthreads = nthreads
+        #: the team size the kernel was built with (what ``_make_kernel``
+        #: resolved a 0 to); 1 for a kernel that says nothing
+        self.team = int(getattr(parse_fn, "nthreads", 1))
         self._bind_metrics()
 
     def _bind_metrics(self) -> None:
@@ -98,13 +102,17 @@ class TextParser(ParserBase):
         from ..utils.metrics import metrics
         if self._m_gen != metrics.generation:
             self._bind_metrics()
-        with self._m_chunk.time():
+        # spans that are also the stage totals: one clock pair feeds the
+        # ring's record and ``parser.chunk`` / ``parser.parse``
+        with teltrace.span("parser.chunk", stage=self._m_chunk) as s:
             chunk = self.source.next_chunk()
+            s.attrs["bytes"] = len(chunk) if chunk is not None else 0
         if chunk is None:
             return None
         self.bytes_read += len(chunk)
         self._m_bytes.add(len(chunk))
-        with self._m_parse.time():
+        with teltrace.span("parser.parse", stage=self._m_parse,
+                           bytes=len(chunk), nthreads=self.team):
             d = self.parse_fn(chunk)
         return RowBlockContainer.from_arrays(
             d["offsets"], d["labels"], d["indices"], d.get("values"),
@@ -124,7 +132,9 @@ class ThreadedParser(ParserBase):
     def __init__(self, base: ParserBase, max_capacity: int = 8):
         super().__init__()
         self.base = base
-        self._iter: ThreadedIter[RowBlockContainer] = ThreadedIter(max_capacity)
+        self._iter: ThreadedIter[RowBlockContainer] = ThreadedIter(
+            max_capacity, wait_spans=("parser.prefetch.wait_slot",
+                                      "parser.prefetch.wait_item"))
         self._iter.init(lambda _cell: base.parse_next(), base.before_first)
 
     def parse_next(self) -> Optional[RowBlockContainer]:
@@ -170,16 +180,21 @@ def _make_kernel(fmt: str, nthreads: int, csv_param=None) -> Callable[[bytes], D
     if nthreads <= 0:
         nthreads = _default_nthreads()
     if fmt == "libsvm":
-        return (lambda b: native.parse_libsvm(b, nthreads)) if use_native \
+        kernel = (lambda b: native.parse_libsvm(b, nthreads)) if use_native \
             else (lambda b: py_parsers.parse_libsvm(b))
-    if fmt == "libfm":
-        return (lambda b: native.parse_libfm(b, nthreads)) if use_native \
+    elif fmt == "libfm":
+        kernel = (lambda b: native.parse_libfm(b, nthreads)) if use_native \
             else (lambda b: py_parsers.parse_libfm(b))
-    if fmt == "csv":
+    elif fmt == "csv":
         lc, dl = csv_param.label_column, csv_param.delimiter
-        return (lambda b: native.parse_csv(b, lc, dl, nthreads)) if use_native \
+        kernel = (lambda b: native.parse_csv(b, lc, dl, nthreads)) if use_native \
             else (lambda b: py_parsers.parse_csv(b, lc, dl))
-    raise DMLCError(f"no parse kernel for format {fmt!r}")
+    else:
+        raise DMLCError(f"no parse kernel for format {fmt!r}")
+    # the team size it runs with, for ``parser.parse``'s record (the
+    # python fallbacks parse on the calling thread)
+    kernel.nthreads = nthreads if use_native else 1
+    return kernel
 
 
 def _register_text_format(fmt: str, description: str) -> None:

@@ -26,7 +26,14 @@ class ThreadedInputSplit(InputSplit):
 
     def __init__(self, base: InputSplit, max_capacity: int = 2):
         self.base = base
-        self._iter: ThreadedIter[bytes] = ThreadedIter(max_capacity=max_capacity)
+        self._start(max_capacity)
+
+    def _start(self, max_capacity: int) -> None:
+        base = self.base
+        self._iter: ThreadedIter[bytes] = ThreadedIter(
+            max_capacity=max_capacity,
+            wait_spans=("input_split.prefetch.wait_slot",
+                        "input_split.prefetch.wait_item"))
         self._iter.init(lambda _cell: base.next_chunk(), base.before_first)
         self._reset_record_iter()
 
@@ -47,9 +54,7 @@ class ThreadedInputSplit(InputSplit):
         # quiesce the producer, repartition the base, restart
         self._iter.destroy()
         self.base.reset_partition(part_index, num_parts)
-        self._iter = ThreadedIter(max_capacity=self._iter.max_capacity)
-        self._iter.init(lambda _cell: self.base.next_chunk(), self.base.before_first)
-        self._reset_record_iter()
+        self._start(self._iter.max_capacity)
 
     def hint_chunk_size(self, chunk_size: int) -> None:
         self.base.hint_chunk_size(chunk_size)

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import math
 import threading
+import time
 from collections import deque
 from typing import Dict, Iterator, Optional
 
@@ -204,35 +205,47 @@ def _get_unpack(rows: int, meta: int):
 def _put_fused_buf(buf: np.ndarray, rows: int, meta: int) -> Dict[str, jax.Array]:
     """Transfer a fused int32 buffer in ONE device_put, then decode inside
     a cached jitted fn (layout chosen by the emit meta).  On the CPU
-    backend segments are precomputed host-side (see _host_segments)."""
-    words = _fused_words_meta(rows, meta)
-    view = buf if len(buf) == words else buf[:words]
-    if jax.default_backend() == "cpu":
-        nnz, w, _ = _decode_meta(meta)
-        segs = _host_segments(view, rows, nnz, words)
-        dp = jax.device_put
-        if w == 0:
-            # v2 on CPU: slice copies + per-array puts, no jit dispatch
-            # (measured ~2x cheaper per batch than fused-put + jitted
-            # slices).  The .copy() is load-bearing: device_put of a numpy
-            # VIEW on the CPU backend may alias rather than copy, and an
-            # aliased output would be corrupted when the pooled buffer is
-            # recycled — a fresh owned temp is safe either way and costs
-            # the same single memcpy.
-            f32 = np.float32
-            return {
-                "ids": dp(view[:nnz].copy()),
-                "vals": dp(view[nnz:2 * nnz].copy().view(f32)),
-                "segments": dp(segs),
-                "row_ptr": dp(view[2 * nnz:2 * nnz + rows + 1].copy()),
-                "labels": dp(view[2 * nnz + rows + 1:
-                                  2 * nnz + 2 * rows + 1].copy().view(f32)),
-                "weights": dp(
-                    view[2 * nnz + 2 * rows + 1:words].copy().view(f32)),
-            }
-        # compact v3 on CPU (explicit opt-in): jitted decode, host segments
-        return _get_unpack(rows, meta)(dp(view), dp(segs))
-    return _get_unpack(rows, meta)(jax.device_put(view))
+    backend segments are precomputed host-side (see _host_segments).
+
+    The two calls are of different nature, so each is a span of its own
+    inside the caller's ``device_loader.put``: ``put.transfer`` hands the
+    runtime a host buffer to copy, ``put.decode`` dispatches a compiled
+    program (the CPU's per-array path has no decode to dispatch).  Between
+    them they cover the whole function, so that what ``put`` holds beside
+    them is the spans' own entering and leaving."""
+    with teltrace.span("device_loader.put.transfer"):
+        words = _fused_words_meta(rows, meta)
+        view = buf if len(buf) == words else buf[:words]
+        if jax.default_backend() == "cpu":
+            nnz, w, _ = _decode_meta(meta)
+            segs = _host_segments(view, rows, nnz, words)
+            dp = jax.device_put
+            if w == 0:
+                # v2 on CPU: slice copies + per-array puts, no jit dispatch
+                # (measured ~2x cheaper per batch than fused-put + jitted
+                # slices).  The .copy() is load-bearing: device_put of a
+                # numpy VIEW on the CPU backend may alias rather than copy,
+                # and an aliased output would be corrupted when the pooled
+                # buffer is recycled — a fresh owned temp is safe either
+                # way and costs the same single memcpy.
+                f32 = np.float32
+                return {
+                    "ids": dp(view[:nnz].copy()),
+                    "vals": dp(view[nnz:2 * nnz].copy().view(f32)),
+                    "segments": dp(segs),
+                    "row_ptr": dp(view[2 * nnz:2 * nnz + rows + 1].copy()),
+                    "labels": dp(view[2 * nnz + rows + 1:
+                                      2 * nnz + 2 * rows + 1].copy().view(f32)),
+                    "weights": dp(
+                        view[2 * nnz + 2 * rows + 1:words].copy().view(f32)),
+                }
+            # compact v3 on CPU (explicit opt-in): jitted decode, host
+            # segments
+            on_device = dp(view), dp(segs)
+        else:
+            on_device = (jax.device_put(view),)
+    with teltrace.span("device_loader.put.decode"):
+        return _get_unpack(rows, meta)(*on_device)
 
 
 def _host_fused(host: Dict[str, np.ndarray], rows: int, nnz: int,
@@ -533,7 +546,10 @@ class DeviceLoader:
         # the transfer thread all read these handles
         self._bind_metrics()
         # stage 1: parse+pack in its own thread → bounded host-buffer queue
-        self._pack_iter: ThreadedIter = ThreadedIter(max_capacity=depth)
+        self._pack_iter: ThreadedIter = ThreadedIter(
+            max_capacity=depth,
+            wait_spans=("device_loader.pack_queue.wait_slot",
+                        "device_loader.pack_queue.wait_item"))
         self._pack_iter.init(self._pack_factory(), self._reset_source)
         # stage 2: device transfer → bounded device queue
         if emit == "host":
@@ -545,7 +561,10 @@ class DeviceLoader:
                 n_threads=put_threads,
                 window=max(int(prefetch), put_threads))
         else:
-            self._iter = ThreadedIter(max_capacity=max(1, int(prefetch)))
+            # its consumer's wait is ``device_loader.next_batch`` already
+            self._iter = ThreadedIter(
+                max_capacity=max(1, int(prefetch)),
+                wait_spans=("device_loader.batch_queue.wait_slot", None))
             self._iter.init(self._transfer_next, self._reset_transfer)
 
     # ---------------- stage 1: pack ----------------
@@ -866,7 +885,7 @@ class DeviceLoader:
         rows_seen = 0
         try:
             while True:
-                with m_chunk.time():
+                with teltrace.span("parser.chunk", stage=m_chunk):
                     chunk = split.next_chunk()
                 if chunk is None:
                     break
@@ -998,7 +1017,8 @@ class DeviceLoader:
                 host.pop("row_ptr", None)
                 # sharded arrays lead with the batch/nnz axis: one sharding
                 # fits each; fusing would mix axes, so transfer per-array
-                with teltrace.span("device_loader.put", stage=self._m_put):
+                with teltrace.span("device_loader.put", stage=self._m_put), \
+                        teltrace.span("device_loader.put.transfer"):
                     out = {k: jax.device_put(v, self.sharding)
                            for k, v in host.items()}
                 if sync:
@@ -1089,12 +1109,15 @@ class DeviceLoader:
     def next_batch(self) -> Optional[Dict[str, jax.Array]]:
         """The next batch, or None at the end of an epoch.  Its span is how
         long the caller waited for the feed; ``got`` is false for the
-        None."""
+        None.  ``proc_cpu_us`` is the process's CPU clock at the hand-over
+        — every thread's, a native call's own workers included — so two
+        records say how many cores the process kept busy between them."""
         self._maybe_bind()
         with teltrace.span("device_loader.next_batch",
                            stage=self._m_next_batch) as s:
             batch = self._iter.next()
             s.attrs["got"] = batch is not None
+            s.attrs["proc_cpu_us"] = time.process_time_ns() // 1000
         return batch
 
     def before_first(self) -> None:

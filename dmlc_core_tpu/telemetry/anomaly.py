@@ -51,16 +51,19 @@ Three cooperating pieces, all stdlib, all cheap enough to leave on:
 
 from __future__ import annotations
 
+import json
 import sys
 import threading
+import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..utils.logging import DMLCError, log_warning
 from ..utils.metrics import MetricsRegistry, metrics
 from ..utils.parameter import get_env
+from . import trace as _trace
 
 __all__ = [
-    "StreamingStat", "StallDetector", "StragglerBoard",
+    "StreamingStat", "StallDetector", "capture_stall", "StragglerBoard",
     "SloRule", "SloSpecError", "SloMonitor", "parse_slo_spec",
     "maybe_monitor_from_env", "active_slo_spec",
 ]
@@ -150,8 +153,11 @@ class StallDetector:
         self._m_z = metrics.gauge(f"anomaly.stall_z.{self.name}")
         self._m_stalls = metrics.counter(f"anomaly.stalls.{self.name}")
 
-    def observe(self, dur_s: float) -> float:
-        """Feed one duration; returns the z-score it was judged at."""
+    def observe(self, dur_s: float, span: Any = None) -> float:
+        """Feed one duration; returns the z-score it was judged at.  With
+        the ``span`` that just ended (``telemetry.trace.span(stall=)``
+        hands it over), a flagged stall also says what the span ring saw
+        (:func:`capture_stall`)."""
         with self._lock:
             z = self._stat.zscore(dur_s, rel_floor=self.rel_floor)
             self._stat.update(dur_s)
@@ -169,7 +175,84 @@ class StallDetector:
             if fl is not None:
                 fl.note("stage_stall", stage=self.name,
                         dur_s=float(dur_s), z=float(z))
+            if span is not None:
+                capture_stall(span, z)
         return z
+
+
+def capture_stall(span: Any, z: float = 0.0) -> Dict[str, Any]:
+    """What the span ring holds about a span that just ended, as one
+    ``stall.capture`` event in the ring, a flight note and a line on
+    standard error.  After the fact, from records already made:
+
+    * the stalled span's name, thread, ``dur_us`` and ``cpu_us`` (its own
+      thread's CPU time: a stall with little of it was a wait);
+    * ``children``: every span of the same thread inside its extent, by
+      name, with count, ``dur_us`` and ``cpu_us`` summed;
+    * ``threads``: for every other thread, the seconds of each span name
+      that overlap the stalled extent (``spans``), the seconds no finished
+      span covers (``uncovered_s``) and, where the thread is inside an
+      unfinished scoped span at this moment, that span's name and how long
+      it has been open (``open``) — a consumer blocked for the whole stall
+      has finished nothing yet.
+    """
+    lo, hi = span._t0_mono, span._t0_mono + span.dur_s
+    own = _trace.format_id(span.span_id)
+    children: Dict[str, Dict[str, int]] = {}
+    threads: Dict[str, Dict[str, Any]] = {}
+    covered: Dict[str, List[Tuple[float, float]]] = {}
+    for r in _trace.recorder.snapshot(since_mono_s=lo):
+        if r.get("kind") != "span":
+            continue
+        a = r["mono_us"] * 1e-6
+        b = a + r["dur_us"] * 1e-6
+        if r["tid"] == span._tid:
+            if lo <= a and b <= hi + 1e-6 and r["span_id"] != own:
+                c = children.setdefault(
+                    r["name"], {"n": 0, "dur_us": 0, "cpu_us": 0})
+                c["n"] += 1
+                c["dur_us"] += r["dur_us"]
+                c["cpu_us"] += r.get("cpu_us", 0)
+            continue
+        a, b = max(a, lo), min(b, hi)
+        if b <= a:
+            continue
+        t = threads.setdefault(r["thread"], {"spans": {}})
+        t["spans"][r["name"]] = round(
+            t["spans"].get(r["name"], 0.0) + (b - a), 6)
+        covered.setdefault(r["thread"], []).append((a, b))
+    for name, t in threads.items():
+        t["uncovered_s"] = round(
+            (hi - lo) - _union_seconds(covered[name]), 6)
+    now = time.monotonic()
+    for s in _trace.open_spans():
+        if s._tid != span._tid:
+            t = threads.setdefault(
+                s._thread, {"spans": {}, "uncovered_s": round(hi - lo, 6)})
+            t["open"] = {"name": s.name,
+                         "for_s": round(now - s._t0_mono, 6)}
+    capture = {
+        "span": span.name, "thread": span._thread, "z": round(float(z), 2),
+        "dur_us": int(span.dur_s * 1e6),
+        "cpu_us": (int(span.cpu_s * 1e6) if span.cpu_s is not None
+                   else None),
+        "children": children, "threads": threads,
+    }
+    _trace.record_event("stall.capture", **capture)
+    fl = _flight_mod()
+    if fl is not None:
+        fl.note("stall_capture", **capture)
+    log_warning("stall.capture %s", json.dumps(capture, sort_keys=True))
+    return capture
+
+
+def _union_seconds(intervals: List[Tuple[float, float]]) -> float:
+    total, end = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total
 
 
 class StragglerBoard:

@@ -16,6 +16,20 @@ per-request recording.  Consumers are ``telemetry.chrome_trace``
 (Perfetto export) and the ``/spans`` endpoint of
 ``telemetry.exposition``.
 
+A span's record carries two durations: ``dur_us``, wall time on
+``time.monotonic``, and ``cpu_us``, the CPU time of **the thread that ran
+the span** over the same extent (``time.thread_time_ns``), so ``dur_us -
+cpu_us`` is the time that thread was not running: blocked on a lock, the
+GIL or a device, or runnable with no core.  ``cpu_us`` is the calling
+thread only: what a native call's own workers (an OpenMP team, the
+runtime's transfer threads) burned is not in it — that shows in the
+process's CPU clock, which ``device_loader.next_batch`` records carry as
+``proc_cpu_us``.  A span ended on another thread than the one that started
+it, and one placed by :func:`record_completed`, has no ``cpu_us``.  Its
+resolution is the kernel's thread clock's: nanoseconds on most hosts, 10 ms
+where CPU time is accounted by the tick (the benchmark's chip machine reads
+0 or 10 000) — there only sums over many spans mean anything.
+
 Usage::
 
     with span("serving.client.predict", rows=4):        # scoped span
@@ -23,6 +37,9 @@ Usage::
 
     with span("device_loader.pack", stage=timer, stall=detector):
         ...     # one clock pair: the record, the stage total, the detector
+
+    with span("queue.wait_item", floor_s=50e-6):
+        ...     # a wait: no record when it returned at once
 
     s = start_span("serving.server.request", parent=ctx)  # manual span
     ...                                                   # (async paths)
@@ -50,8 +67,8 @@ __all__ = [
     "TraceContext", "Span", "SpanRecorder", "recorder", "current",
     "current_trace_id", "new_trace_id", "start_span", "span",
     "record_completed", "activate",
-    "add_event", "format_id", "wire_ids", "from_wire", "set_sampler",
-    "get_sampler",
+    "add_event", "record_event", "open_spans", "format_id", "wire_ids",
+    "from_wire", "set_sampler", "get_sampler",
 ]
 
 
@@ -71,46 +88,78 @@ def format_id(v: int) -> str:
 
 # one RNG for id generation; os.urandom-seeded so forked workers diverge
 _id_rng = random.Random(int.from_bytes(os.urandom(8), "little"))
-_id_lock = threading.Lock()
 
 
 def new_trace_id() -> int:
     """Random non-zero 63-bit id (zero is the wire's 'untraced' marker;
     bit 63 is reserved as the tail-sampling ``debug=1`` force-keep flag
-    — see ``telemetry.sampling`` — so it is never minted by accident)."""
-    with _id_lock:
-        return _id_rng.randrange(1, 1 << 63)
+    — see ``telemetry.sampling`` — so it is never minted by accident).
+    One C call under the GIL: no lock of its own."""
+    return _id_rng.getrandbits(63) or 1
 
 
 class SpanRecorder:
     """Lock-protected ring buffer of finished span/event records.
 
-    Records are plain JSON-ready dicts (see :meth:`Span.end` for the
-    schema) so exports never touch live objects.  Bounded by
-    ``capacity`` (env ``DMLC_SPAN_BUFFER``): under sustained load old
-    spans fall off the back — observability must never become the
-    memory leak it exists to find.
+    :meth:`snapshot` hands out plain JSON-ready dicts (see
+    :func:`_render` for a span's schema), so exports never touch live
+    objects.  A finished span is kept as the flat tuple :meth:`Span.end`
+    made and rendered by whoever reads: the hex ids, the attribute
+    coercion and the dict are not paid on the hot path.  Bounded by
+    ``capacity`` (env ``DMLC_SPAN_BUFFER``; the default holds a 10 s
+    window of the busiest benchmark cell, ~17 600 records): under
+    sustained load old spans fall off the back — observability must never
+    become the memory leak it exists to find.
     """
 
-    def __init__(self, capacity: int = 4096) -> None:
+    def __init__(self, capacity: int = 32768) -> None:
         self._lock = threading.Lock()
         self._buf: deque = deque(maxlen=max(1, int(capacity)))
         self._dropped = 0
+        # the last rendering, good until the next record: a run's readers
+        # take one snapshot each, back to back
+        self._rendered: Optional[List[Dict[str, Any]]] = None
 
-    def record(self, rec: Dict[str, Any]) -> None:
+    def record(self, rec: Union[Dict[str, Any], tuple]) -> None:
+        """Append a rendered record (a dict) or a span's flat tuple."""
         with self._lock:
             evicted = len(self._buf) == self._buf.maxlen
             if evicted:
                 self._dropped += 1
             self._buf.append(rec)
+            self._rendered = None
         if evicted:
             # eviction at maxlen used to be invisible — consumers of a
             # lossy /spans window must be able to see that it is lossy
             metrics.counter("telemetry.spans_dropped").add(1)
 
-    def snapshot(self) -> List[Dict[str, Any]]:
+    def snapshot(self, since_mono_s: Optional[float] = None
+                 ) -> List[Dict[str, Any]]:
+        """The ring's records, oldest first, rendered.  ``since_mono_s``
+        keeps only spans that ended at or after that ``time.monotonic``
+        instant (records without a monotonic start are left out then)."""
         with self._lock:
-            return list(self._buf)
+            raw = list(self._buf)
+            rendered = self._rendered
+        pid = os.getpid()
+        if since_mono_s is None:
+            if rendered is None:
+                rendered = [_render(r, pid) if type(r) is tuple else r
+                            for r in raw]
+                with self._lock:
+                    if len(self._buf) == len(raw) and \
+                            (not raw or self._buf[-1] is raw[-1]):
+                        self._rendered = rendered
+            return list(rendered)
+        out = []
+        for r in raw:
+            if type(r) is tuple:
+                if r[_MONO] + r[_DUR] >= since_mono_s:
+                    out.append(_render(r, pid))
+            elif "mono_us" in r and \
+                    (r["mono_us"] + r.get("dur_us", 0)) * 1e-6 >= since_mono_s:
+                out.append(r)
+        return out
 
     @property
     def dropped(self) -> int:
@@ -122,6 +171,7 @@ class SpanRecorder:
         with self._lock:
             self._buf.clear()
             self._dropped = 0
+            self._rendered = None
 
     def __len__(self) -> int:
         with self._lock:
@@ -129,7 +179,7 @@ class SpanRecorder:
 
 
 #: process-global recorder (the /spans endpoint and Chrome export read it)
-recorder = SpanRecorder(capacity=get_env("DMLC_SPAN_BUFFER", 4096))
+recorder = SpanRecorder(capacity=get_env("DMLC_SPAN_BUFFER", 32768))
 
 # Optional tail sampler (telemetry.sampling.TailSampler) interposed
 # between span completion and the recorder.  None (the default) keeps
@@ -202,8 +252,8 @@ class Span:
     paths may race a cleanup path)."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "events", "dur_s", "_t0_wall", "_t0_mono", "_tid",
-                 "_thread", "_ended")
+                 "events", "dur_s", "cpu_s", "_t0_wall", "_t0_mono",
+                 "_t0_cpu", "_floor_s", "_tid", "_thread", "_ended")
 
     def __init__(self, name: str, trace_id: int, span_id: int,
                  parent_id: Optional[int], attrs: Dict[str, Any]) -> None:
@@ -215,12 +265,18 @@ class Span:
         self.events: List[Dict[str, Any]] = []
         #: seconds from start to :meth:`end`, on ``time.monotonic``
         self.dur_s = 0.0
-        self._t0_wall = time.time()
-        self._t0_mono = time.monotonic()
+        #: CPU seconds of the starting thread over the same extent; None
+        #: until the end, and for a span ended on another thread
+        self.cpu_s: Optional[float] = None
+        #: a span shorter than this leaves no record (:func:`span`)
+        self._floor_s = 0.0
         t = threading.current_thread()
         self._tid = t.ident or 0
         self._thread = t.name
         self._ended = False
+        self._t0_wall = time.time()
+        self._t0_mono = time.monotonic()
+        self._t0_cpu: Optional[int] = time.thread_time_ns()
 
     @property
     def context(self) -> TraceContext:
@@ -236,43 +292,71 @@ class Span:
         })
 
     def end(self, **attrs: Any) -> None:
-        """Finish the span and push its record into the ring buffer."""
+        """Finish the span and push its record into the ring buffer: a
+        flat tuple, rendered by whoever reads the ring."""
         if self._ended:
             return
         self._ended = True
+        cpu_ns = None
+        if self._t0_cpu is not None and threading.get_ident() == self._tid:
+            cpu_ns = time.thread_time_ns() - self._t0_cpu
+            self.cpu_s = cpu_ns * 1e-9
+        self.dur_s = max(0.0, time.monotonic() - self._t0_mono)
         if attrs:
             self.attrs.update(attrs)
-        self.dur_s = max(0.0, time.monotonic() - self._t0_mono)
-        rec = {
-            "kind": "span",
-            "name": self.name,
-            "trace_id": format_id(self.trace_id),
-            "span_id": format_id(self.span_id),
-            "parent_id": (format_id(self.parent_id)
-                          if self.parent_id else None),
-            "ts_us": int(self._t0_wall * 1e6),
-            # the start on the clock ``dur_us`` was taken from: in-process
-            # arithmetic between records uses this, never the wall clock
-            "mono_us": int(self._t0_mono * 1e6),
-            "dur_us": int(self.dur_s * 1e6),
-            "pid": os.getpid(),
-            "tid": self._tid,
-            "thread": self._thread,
-            "attrs": _jsonable(self.attrs),
-            "events": self.events,
-        }
+        if self.dur_s < self._floor_s:
+            return
+        rec = (self.name, self.trace_id, self.span_id, self.parent_id,
+               self._t0_wall, self._t0_mono, self.dur_s, cpu_ns, self._tid,
+               self._thread, self.attrs, self.events)
         s = _sampler
         if s is not None:
-            s.on_end(self.trace_id, rec)
+            s.on_end(self.trace_id, _render(rec, os.getpid()))
         else:
             recorder.record(rec)
+
+
+# places in a finished span's flat tuple that readers of the raw ring use
+_MONO, _DUR = 5, 6
+
+
+def _render(rec: tuple, pid: int) -> Dict[str, Any]:
+    """The JSON-ready record of one finished span."""
+    (name, trace_id, span_id, parent_id, t0_wall, t0_mono, dur_s, cpu_ns,
+     tid, thread, attrs, events) = rec
+    out = {
+        "kind": "span",
+        "name": name,
+        "trace_id": format_id(trace_id),
+        "span_id": format_id(span_id),
+        "parent_id": format_id(parent_id) if parent_id else None,
+        "ts_us": int(t0_wall * 1e6),
+        # the start on the clock ``dur_us`` was taken from: in-process
+        # arithmetic between records uses this, never the wall clock
+        "mono_us": int(t0_mono * 1e6),
+        "dur_us": int(dur_s * 1e6),
+        "pid": pid,
+        "tid": tid,
+        "thread": thread,
+        "attrs": _jsonable(attrs),
+        "events": events,
+    }
+    if cpu_ns is not None:
+        # CPU time of the span's own thread: ``dur_us - cpu_us`` is the
+        # time it was not running
+        out["cpu_us"] = cpu_ns // 1000
+    return out
+
+
+_SCALARS = (str, int, float, bool, type(None))
 
 
 def _jsonable(attrs: Dict[str, Any]) -> Dict[str, Any]:
     """Attrs must survive json.dumps — coerce exotic values to str."""
     out: Dict[str, Any] = {}
     for k, v in attrs.items():
-        if isinstance(v, (str, int, float, bool)) or v is None:
+        if type(v) in _SCALARS or (
+                type(v) is tuple and all(type(x) in _SCALARS for x in v)):
             out[k] = v
         else:
             try:
@@ -298,53 +382,95 @@ def start_span(name: str, parent: Optional[TraceContext] = None,
     s = _sampler
     if s is not None:
         s.on_start(trace_id)
-    return Span(name, trace_id, new_trace_id(), parent_id, _jsonable(attrs))
+    # (the record keeps no reference to a caller's live object)
+    return Span(name, trace_id, new_trace_id(), parent_id,
+                _jsonable(attrs) if attrs else attrs)
 
 
-@contextlib.contextmanager
-def span(name: str, stage: Any = None, stall: Any = None,
-         **attrs: Any) -> Iterator[Span]:
+# The innermost unfinished scoped span of each thread, by thread ident: what
+# a ``stall.capture`` says the other threads were in.  Plain dict writes
+# under the GIL; an entry goes when its thread's outermost span ends.
+_open: Dict[int, Span] = {}
+
+
+def open_spans() -> List[Span]:
+    """The innermost unfinished :func:`span` of every thread that has one."""
+    return list(_open.values())
+
+
+class span:
     """Scoped span: child of the ambient context (or of ``parent=``),
     active for the block, ended on exit (exceptions recorded as ``error``
-    before re-raising).
+    before re-raising).  ``with span(name, ...) as s`` binds the
+    :class:`Span`.
 
     For its whole extent it is also a ``jax.profiler.TraceAnnotation`` of
     the same name, so it lies on the host plane of any running profile, on
     the device trace's clock.  The one duration the record carries is also
     added to ``stage`` (a ``StageTimer``) and handed to ``stall`` (a
-    ``StallDetector``) when the call site passes them: one pair of clock
-    reads, three sinks."""
-    with profiler_annotation(name):
-        s = start_span(name, **attrs)
-        token = _current.set(s)
-        try:
-            yield s
-        except BaseException as e:
-            s.end(error=f"{type(e).__name__}: {e}")
-            raise
-        finally:
-            try:
-                _current.reset(token)
-            except ValueError:
-                # a span opened inside a generator dies wherever the
-                # generator is finalized: GC can close an abandoned
-                # iterator from another thread's context, where this token
-                # is foreign.  The span still ends; only the
-                # ambient-context pop is moot.
-                pass
+    ``StallDetector``, with the span, so that a flagged stall can say what
+    the ring saw) when the call site passes them: one pair of clock reads,
+    three sinks.  With ``floor_s``, a span that took less leaves no
+    record: for waits, which mostly return at once.
+
+    A class and not a generator: entering and leaving is most of what a
+    span costs."""
+
+    __slots__ = ("_name", "_stage", "_stall", "_floor_s", "_attrs",
+                 "_annotation", "_span", "_token", "_outer")
+
+    def __init__(self, name: str, stage: Any = None, stall: Any = None,
+                 floor_s: float = 0.0, **attrs: Any) -> None:
+        self._name = name
+        self._stage = stage
+        self._stall = stall
+        self._floor_s = floor_s
+        self._attrs = attrs
+
+    def __enter__(self) -> Span:
+        self._annotation = profiler_annotation(self._name)
+        self._annotation.__enter__()
+        s = self._span = start_span(self._name, **self._attrs)
+        s._floor_s = self._floor_s
+        self._token = _current.set(s)
+        self._outer = _open.get(s._tid)
+        _open[s._tid] = s
+        return s
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        s = self._span
+        if exc is not None:
+            s.end(error=f"{exc_type.__name__}: {exc}")
+        else:
             s.end()
-            if stage is not None:
-                stage.add(s.dur_s)
-            if stall is not None:
-                stall.observe(s.dur_s)
+        try:
+            _current.reset(self._token)
+        except ValueError:
+            # a span opened inside a generator dies wherever the generator
+            # is finalized: GC can close an abandoned iterator from another
+            # thread's context, where this token is foreign.  The span
+            # still ends; only the ambient-context pop is moot.
+            pass
+        if self._outer is None:
+            _open.pop(s._tid, None)
+        else:
+            _open[s._tid] = self._outer
+        if self._stage is not None:
+            self._stage.add(s.dur_s)
+        if self._stall is not None:
+            self._stall.observe(s.dur_s, span=s)
+        self._annotation.__exit__(exc_type, exc, tb)
+        return False
 
 
 def record_completed(name: str, dur_s: float, **attrs: Any) -> None:
     """Record a span that ended just now and took ``dur_s``: for work timed
-    by someone else (JAX reports a compile's duration once it is over)."""
+    by someone else (JAX reports a compile's duration once it is over).
+    Nobody took its thread's CPU clock, so the record has no ``cpu_us``."""
     s = start_span(name, **attrs)
     s._t0_wall -= dur_s
     s._t0_mono -= dur_s
+    s._t0_cpu = None
     s.end()
 
 
@@ -371,7 +497,14 @@ def add_event(name: str, **attrs: Any) -> None:
     if isinstance(node, Span):
         node.event(name, **attrs)
         return
-    ctx = _ids_of(node)
+    record_event(name, _ids_of(node), **attrs)
+
+
+def record_event(name: str, ctx: Optional[TraceContext] = None,
+                 **attrs: Any) -> None:
+    """A standalone instant event in the ring, whatever span is active
+    (``stall.capture``: the stalled span has ended, and its findings are
+    not an annotation of whichever span encloses it)."""
     t = threading.current_thread()
     rec = {
         "kind": "event",

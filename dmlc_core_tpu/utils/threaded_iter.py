@@ -12,21 +12,33 @@ Exceptions thrown by the producer are captured and re-thrown to the consumer
 This implementation keeps the exact contract (bounded queue, recycling,
 mid-stream destruction, BeforeFirst reset, producer-exception propagation) on
 Python threads.  It is the backbone of the ingest pipeline: chunk prefetch
-(io.threaded_split), parse prefetch (data.parser) and the device feed
+(io.wrappers), parse prefetch (data.parser) and the device feed
 (pipeline.device_loader) all wrap their producers in it, mirroring how the
 reference composes `threaded_input_split.h:23` and `parser.h:71`.
+
+A hand-over can account for itself: an owner that passes ``wait_spans``
+gets a span for every real wait at its queue — the producer thread with an
+item and no room (``<queue>.wait_slot``), the consuming thread with nothing
+to take (``<queue>.wait_item``) — through ``telemetry.trace.span``, imported
+when the first such iterator is built (this module imports nothing of
+``telemetry`` at load: the dependency runs the other way).
 """
 
 from __future__ import annotations
 
 import threading
-from typing import Any, Callable, Generic, Iterator, List, Optional, TypeVar
+from typing import (Any, Callable, Generic, Iterator, List, Optional, Tuple,
+                    TypeVar)
 
 from .logging import DMLCError
 
 __all__ = ["ThreadedIter"]
 
 T = TypeVar("T")
+
+#: a wait shorter than this leaves no record: one that returned at once
+#: says nothing, and a queue that is never empty or full must cost nothing
+WAIT_FLOOR_S = 50e-6
 
 
 class ThreadedIter(Generic[T]):
@@ -37,10 +49,25 @@ class ThreadedIter(Generic[T]):
     max_capacity:
         Bound on queued items (reference ``set_max_capacity``; chunk wrapper
         uses 2 `threaded_input_split.h:33`, parser uses 8 `parser.h:75`).
+    wait_spans:
+        ``(wait_slot, wait_item)``: the span names under which the producer
+        thread's waits for room and the consuming thread's waits for an
+        item are recorded, each a full literal name
+        (``"parser.prefetch.wait_slot"``); ``None`` in either place records
+        nothing there (the device loader's last queue: its consumer's wait
+        is ``device_loader.next_batch`` already).  A queue with an item, or
+        with room, is not timed at all; a wait under ``WAIT_FLOOR_S``
+        leaves no record.
     """
 
-    def __init__(self, max_capacity: int = 8):
+    def __init__(self, max_capacity: int = 8,
+                 wait_spans: Tuple[Optional[str], Optional[str]] = (None, None)):
         self.max_capacity = max(1, int(max_capacity))
+        self._wait_slot, self._wait_item = wait_spans
+        self._span = None
+        if self._wait_slot or self._wait_item:
+            from ..telemetry.trace import span
+            self._span = span
         self._lock = threading.Condition()
         self._queue: List[T] = []
         self._free: List[T] = []
@@ -67,7 +94,12 @@ class ThreadedIter(Generic[T]):
             raise DMLCError("ThreadedIter.init called twice")
         self._next_fn = next_fn
         self._beforefirst_fn = beforefirst_fn
-        self._thread = threading.Thread(target=self._producer_loop, daemon=True)
+        # a named queue names its producer thread (``parser.prefetch``), so
+        # a record's ``thread`` says which stage of the feed it is
+        named = self._wait_slot or self._wait_item
+        self._thread = threading.Thread(
+            target=self._producer_loop, daemon=True,
+            name=named.rpartition(".")[0] if named else None)
         self._thread.start()
 
     @classmethod
@@ -90,13 +122,35 @@ class ThreadedIter(Generic[T]):
         return it
 
     # -- producer side --
+    def _must_wait_slot(self) -> bool:
+        return (not self._destroy and not self._reset_pending
+                and (self._produced_end
+                     or len(self._queue) >= self.max_capacity))
+
+    def _must_wait_item(self) -> bool:
+        return (not self._queue and not self._produced_end
+                and not self._destroy)
+
+    def _wait_while(self, must_wait: Callable[[], bool],
+                    name: Optional[str]) -> None:
+        """Wait on the (held) lock while ``must_wait()``; under a name, a
+        wait that has to happen is a span, kept from ``WAIT_FLOOR_S`` up."""
+        if name and must_wait():
+            with self._span(name, floor_s=WAIT_FLOOR_S):
+                while must_wait():
+                    self._lock.wait()
+        while must_wait():
+            self._lock.wait()
+
     def _producer_loop(self) -> None:
         while True:
             with self._lock:
                 # wait for: destroy | reset request | space to produce
-                while (not self._destroy and not self._reset_pending
-                       and (self._produced_end or len(self._queue) >= self.max_capacity)):
-                    self._lock.wait()
+                # (a producer parked at the end of its stream waits for a
+                # reset, not for room: no span for that)
+                self._wait_while(
+                    self._must_wait_slot,
+                    None if self._produced_end else self._wait_slot)
                 if self._destroy:
                     return
                 if self._reset_pending:
@@ -152,9 +206,7 @@ class ThreadedIter(Generic[T]):
         with self._lock:
             if self._consumed_end:
                 return None
-            while (not self._queue and not self._produced_end
-                   and not self._destroy):
-                self._lock.wait()
+            self._wait_while(self._must_wait_item, self._wait_item)
             if self._destroy and not self._queue:
                 self._consumed_end = True
                 return None

@@ -46,9 +46,11 @@ def clean_ring():
 def test_span_feeds_three_sinks_from_one_duration(clean_ring):
     class Seen:
         got = []
+        spans = []
 
-        def observe(self, dur_s):
+        def observe(self, dur_s, span=None):
             self.got.append(dur_s)
+            self.spans.append(span)
 
     stage, stall = StageTimer(), Seen()
     for _ in range(3):
@@ -56,6 +58,8 @@ def test_span_feeds_three_sinks_from_one_duration(clean_ring):
             time.sleep(0.002)
     recs = records("unit.sinks")
     assert len(recs) == 3 and stage.count == 3
+    # the detector is handed the span that just ended, with its duration
+    assert [s.dur_s for s in stall.spans] == stall.got
     assert all(r["attrs"] == {"rows": 7} for r in recs)
     # the stage total IS the recorded durations (dur_us drops the fraction
     # of a microsecond), and the detector saw the same three numbers
@@ -89,6 +93,170 @@ def test_record_carries_the_monotonic_start(clean_ring):
     assert child["parent_id"] == parent["span_id"]
 
 
+def _spin(seconds):
+    t0 = time.monotonic()
+    while time.monotonic() - t0 < seconds:
+        pass
+
+
+def test_a_sleeping_span_has_no_cpu_time_and_a_spinning_one_has_all(
+        clean_ring):
+    with trace.span("unit.cpu.sleep") as asleep:
+        time.sleep(0.05)
+    # the best of a few: a spin on a shared machine can lose its core
+    for _ in range(5):
+        with trace.span("unit.cpu.spin"):
+            _spin(0.05)
+    (sleep,) = records("unit.cpu.sleep")
+    assert sleep["dur_us"] >= 50_000 and sleep["cpu_us"] < 0.1 * sleep["dur_us"]
+    assert asleep.cpu_s == pytest.approx(sleep["cpu_us"] * 1e-6, abs=1e-6)
+    spin = max(records("unit.cpu.spin"),
+               key=lambda r: r["cpu_us"] / r["dur_us"])
+    assert abs(spin["dur_us"] - spin["cpu_us"]) <= 0.2 * spin["dur_us"]
+
+
+def test_cpu_time_is_the_spans_own_thread_only(clean_ring):
+    # a worker burns a core while the span's thread sleeps: not in cpu_us
+    t = threading.Thread(target=_spin, args=(0.05,))
+    with trace.span("unit.cpu.own"):
+        t.start()
+        t.join(timeout=30)
+    (rec,) = records("unit.cpu.own")
+    assert rec["dur_us"] >= 50_000 and rec["cpu_us"] < 0.2 * rec["dur_us"]
+
+
+def test_a_span_ended_on_another_thread_has_no_cpu_time(clean_ring):
+    s = trace.start_span("unit.cpu.handed_over")
+    t = threading.Thread(target=s.end)
+    t.start()
+    t.join(timeout=30)
+    (rec,) = records("unit.cpu.handed_over")
+    assert "cpu_us" not in rec and s.cpu_s is None
+
+
+SPAN_KEYS_BEFORE = {"kind", "name", "trace_id", "span_id", "parent_id",
+                    "ts_us", "mono_us", "dur_us", "pid", "tid", "thread",
+                    "attrs", "events"}
+
+
+def test_snapshot_renders_the_same_keys_plus_cpu_us(clean_ring):
+    import json
+    import os
+    with trace.span("unit.keys", rows=3, wire=(0, 0)) as s:
+        s.event("retry", attempt=2)
+    (rec,) = trace.recorder.snapshot()
+    assert set(rec) == SPAN_KEYS_BEFORE | {"cpu_us"}
+    assert rec["pid"] == os.getpid()
+    assert rec["trace_id"] == trace.format_id(s.trace_id)
+    assert rec["span_id"] == trace.format_id(s.span_id)
+    assert rec["attrs"] == {"rows": 3, "wire": [0, 0]} or \
+        rec["attrs"] == {"rows": 3, "wire": (0, 0)}
+    assert rec["events"][0]["name"] == "retry"
+    json.dumps(rec)
+    # a rendered record handed to the ring comes back as it went in
+    trace.recorder.record({"kind": "span", "name": "unit.keys.dict"})
+    assert trace.recorder.snapshot()[-1] == {"kind": "span",
+                                             "name": "unit.keys.dict"}
+    # only what ended at or after an instant
+    later = time.monotonic() + 1.0
+    assert trace.recorder.snapshot(since_mono_s=later) == []
+    assert [r["name"] for r in trace.recorder.snapshot(
+        since_mono_s=s._t0_mono)] == ["unit.keys"]
+
+
+def test_the_default_ring_holds_32768_records_and_counts_the_rest(
+        clean_ring, monkeypatch):
+    monkeypatch.setattr(trace, "recorder", trace.SpanRecorder())
+    for _ in range(40_000):
+        with trace.span("unit.ring"):
+            pass
+    assert len(trace.recorder) == 32_768
+    assert trace.recorder.dropped == 7_232
+
+
+def test_a_span_under_its_floor_leaves_no_record(clean_ring):
+    stage = StageTimer()
+    with trace.span("unit.floor", stage=stage, floor_s=0.01):
+        pass
+    with trace.span("unit.floor", stage=stage, floor_s=0.01):
+        time.sleep(0.02)
+    (rec,) = records("unit.floor")
+    assert rec["dur_us"] >= 20_000 and stage.count == 2
+
+
+# ------------------------------------------------------------ stall capture
+
+def test_a_forced_stall_leaves_one_capture_of_what_the_ring_saw(
+        clean_ring, caplog):
+    """A detector warmed on 20 short spans, then one long one whose time is
+    in one child while another thread works and a third sits in a wait."""
+    from dmlc_core_tpu.telemetry import flight
+    stall = StallDetector("unit.stalled", min_samples=16)
+
+    def turn(child_s):
+        with trace.span("unit.stalled", stall=stall):
+            with trace.span("unit.stalled.quick"):
+                pass
+            with trace.span("unit.stalled.long"):
+                time.sleep(child_s)
+
+    for _ in range(20):
+        turn(0.001)
+    assert not [r for r in trace.recorder.snapshot()
+                if r["name"] == "stall.capture"]
+
+    go, done = threading.Event(), threading.Event()
+
+    def other():
+        go.wait(10)
+        for _ in range(3):
+            with trace.span("unit.other.work"):
+                _spin(0.01)
+
+    def waiter():
+        with trace.span("unit.waiter.blocked"):
+            done.wait(10)
+
+    threads = [threading.Thread(target=other, name="unit-other"),
+               threading.Thread(target=waiter, name="unit-waiter")]
+    for t in threads:
+        t.start()
+    time.sleep(0.01)            # the waiter is inside its span by now
+    go.set()
+    turn(0.15)
+    done.set()
+    for t in threads:
+        t.join(timeout=30)
+
+    (cap,) = [r for r in trace.recorder.snapshot()
+              if r["name"] == "stall.capture"]
+    assert cap["kind"] == "event"
+    a = cap["attrs"]
+    assert a["span"] == "unit.stalled" and a["thread"] == "MainThread"
+    assert a["dur_us"] >= 150_000 and a["cpu_us"] < 0.2 * a["dur_us"]
+    assert set(a["children"]) == {"unit.stalled.quick", "unit.stalled.long"}
+    long = a["children"]["unit.stalled.long"]
+    assert long["n"] == 1 and long["dur_us"] >= 150_000
+    assert long["cpu_us"] < 0.2 * long["dur_us"]
+    assert a["children"]["unit.stalled.quick"]["dur_us"] < 1_000
+    # the thread that worked: its spans' seconds inside the stalled extent
+    worked = a["threads"]["unit-other"]
+    assert 0.02 <= worked["spans"]["unit.other.work"] <= 0.06
+    assert worked["uncovered_s"] == pytest.approx(
+        a["dur_us"] * 1e-6 - worked["spans"]["unit.other.work"], abs=2e-3)
+    # the thread that waited all along has finished nothing: it is named by
+    # the span it is still in
+    blocked = a["threads"]["unit-waiter"]
+    assert blocked["open"]["name"] == "unit.waiter.blocked"
+    assert blocked["open"]["for_s"] >= 0.15 and not blocked["spans"]
+    # the same capture as a flight note and as one line on standard error
+    notes = [n for n in flight.flight_recorder.notes()
+             if n["kind"] == "stall_capture" and n["span"] == "unit.stalled"]
+    assert notes and notes[-1]["children"] == a["children"]
+    assert caplog.text.count("stall.capture {") == 1
+    assert not trace.open_spans()
+
+
 def test_an_error_still_reaches_every_sink(clean_ring):
     stage = StageTimer()
     with pytest.raises(KeyError):
@@ -105,6 +273,7 @@ def test_record_completed_is_placed_in_the_past(clean_ring):
     assert r["dur_us"] == pytest.approx(1.5e6, abs=5e3)
     assert r["mono_us"] == pytest.approx((now - 1.5) * 1e6, abs=5e4)
     assert r["attrs"] == {"why": "timed elsewhere"}
+    assert "cpu_us" not in r       # nobody took its thread's CPU clock
 
 
 def test_registry_stage_timer_knows_its_name_and_takes_add():
@@ -273,8 +442,43 @@ def test_loader_spans_on_every_pack_path(tmp_path, clean_ring, path):
         assert all(r["parent_id"] is None for r in waits
                    if r["parent_id"] not in by_id)
 
+    # put at its two calls: the transfer, then (where a compiled decoder
+    # is dispatched: on the CPU only the compact wire's) the decode
+    puts = {r["span_id"]: r for r in put}
+    for name, n in (("device_loader.put.transfer", batches),
+                    ("device_loader.put.decode",
+                     batches if path == "compact" else 0)):
+        kids = records(name)
+        assert len(kids) == n, name
+        assert all(_inside(r, puts[r["parent_id"]]) for r in kids), name
+        assert all(r["cpu_us"] <= r["dur_us"] + 1000 for r in kids), name
+
     pack = records("device_loader.pack")
     assert len(pack) >= batches
+
+    # the consumer's records carry the process's CPU clock, which only rises
+    clock = [r["attrs"]["proc_cpu_us"] for r in nb]
+    assert clock == sorted(clock) and clock[-1] > clock[0] > 0
+
+    # the parser's stages are spans too (one pass of streampack has no
+    # parse of its own), on the thread that ran them, with the team size
+    # the kernel was built with
+    chunks, parses = records("parser.chunk"), records("parser.parse")
+    assert chunks and chunks[-1]["attrs"].get("bytes", 0) == 0
+    if path == "streampack":
+        assert not parses
+    else:
+        assert len(parses) == len(chunks) - 1
+        assert all(r["attrs"]["nthreads"] >= 1 and r["attrs"]["bytes"] > 0
+                   for r in parses)
+        # on the parser's own thread, named after its queue; the put's
+        # thread is the last queue's (the pool's workers are unnamed)
+        assert {r["thread"] for r in parses} == {"parser.prefetch"}
+        if not pool:
+            assert {r["thread"] for r in put} == \
+                {"device_loader.batch_queue"}
+        for name in ("parser.chunk", "parser.parse"):
+            assert metrics.stage(name).count == len(records(name)), name
 
     # each span is the stage timer of its name: same count, same seconds
     for name in ("device_loader.next_batch", "device_loader.put",
