@@ -22,6 +22,11 @@ item and no room (``<queue>.wait_slot``), the consuming thread with nothing
 to take (``<queue>.wait_item``) — through ``telemetry.trace.span``, imported
 when the first such iterator is built (this module imports nothing of
 ``telemetry`` at load: the dependency runs the other way).
+
+The queue's occupancy is readable by its producer, for a producer that
+paces itself by its consumer (``data.parser.ThreadedParser`` sizes its
+parse team so): ``starved`` counts the consumer's waits on an empty queue,
+``full_streak`` the items in a row before which the producer found it full.
 """
 
 from __future__ import annotations
@@ -58,6 +63,19 @@ class ThreadedIter(Generic[T]):
         is ``device_loader.next_batch`` already).  A queue with an item, or
         with room, is not timed at all; a wait under ``WAIT_FLOOR_S``
         leaves no record.
+
+    Attributes
+    ----------
+    starved:
+        How many times the consumer found the queue empty and had to wait,
+        not counting while it takes the first ``max_capacity`` items after
+        :meth:`init` or :meth:`before_first`: the stages after a rewound
+        queue are filling then, and an empty queue says nothing about the
+        producer's rate.
+    full_streak:
+        How many items in a row the producer found the queue full before
+        making; 0 once it finds room.  Written by the producer thread just
+        before it calls ``next_fn``.
     """
 
     def __init__(self, max_capacity: int = 8,
@@ -76,6 +94,9 @@ class ThreadedIter(Generic[T]):
         self._destroy = False
         self._reset_pending = False
         self._error: Optional[BaseException] = None
+        self._taken = 0         # items the consumer took since the last reset
+        self.starved = 0
+        self.full_streak = 0
         self._thread: Optional[threading.Thread] = None
         self._next_fn: Optional[Callable[[Optional[T]], Optional[T]]] = None
         self._beforefirst_fn: Optional[Callable[[], None]] = None
@@ -148,6 +169,8 @@ class ThreadedIter(Generic[T]):
                 # wait for: destroy | reset request | space to produce
                 # (a producer parked at the end of its stream waits for a
                 # reset, not for room: no span for that)
+                full = (not self._produced_end
+                        and len(self._queue) >= self.max_capacity)
                 self._wait_while(
                     self._must_wait_slot,
                     None if self._produced_end else self._wait_slot)
@@ -170,6 +193,7 @@ class ThreadedIter(Generic[T]):
                     self._reset_pending = False
                     self._lock.notify_all()
                     continue
+                self.full_streak = self.full_streak + 1 if full else 0
                 cell = self._free.pop() if self._free else None
             # produce outside the lock (reference calls producer_->Next
             # without holding the mutex, `threadediter.h:330-340`)
@@ -206,6 +230,8 @@ class ThreadedIter(Generic[T]):
         with self._lock:
             if self._consumed_end:
                 return None
+            if self._taken >= self.max_capacity and self._must_wait_item():
+                self.starved += 1
             self._wait_while(self._must_wait_item, self._wait_item)
             if self._destroy and not self._queue:
                 self._consumed_end = True
@@ -216,6 +242,7 @@ class ThreadedIter(Generic[T]):
                 raise DMLCError(f"ThreadedIter producer failed: {err!r}") from err
             if self._queue:
                 item = self._queue.pop(0)
+                self._taken += 1
                 self._lock.notify_all()
                 return item
             self._consumed_end = True
@@ -238,6 +265,7 @@ class ThreadedIter(Generic[T]):
             while self._reset_pending and not self._destroy:
                 self._lock.wait()
             self._consumed_end = False
+            self._taken = 0
 
     def __iter__(self) -> Iterator[T]:
         while True:
