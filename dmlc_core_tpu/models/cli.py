@@ -61,22 +61,38 @@ def _dcn(p: "TrainParams"):
                  layers=p.layers, l2=p.l2, task=p.task)
 
 
-@MODEL_REGISTRY.register("hybrid_moe_lm",
-                         "linear + latent attention, routed experts: "
-                         "scores packed token documents (forward only)")
-def _hybrid_moe_lm(p: "TrainParams"):
-    from .hybrid_lm import HybridMoELM, load_arch
+def _document_scorer(p: "TrainParams", name: str, cls):
+    """A language-model scorer ``cls`` built from ``arch=``, held to
+    ``task=score`` over its own vocabulary rows."""
+    from .hybrid_lm import load_arch
     if not p.arch:
-        raise ParamError("model=hybrid_moe_lm needs arch=<json file of the "
-                         "architecture's published keys>")
-    model = HybridMoELM(load_arch(p.arch))
+        raise ParamError(f"model={name} needs arch=<json file of the "
+                         f"architecture's published keys>")
+    model = cls(load_arch(p.arch))
     if p.task != "score" or p.features != model.vocab:
         raise ParamError(
-            f"model=hybrid_moe_lm scores documents by mean log-probability "
+            f"model={name} scores documents by mean log-probability "
             f"over {model.vocab} vocabulary rows: set task=score and "
             f"features={model.vocab} (got task={p.task}, "
             f"features={p.features})")
     return model
+
+
+@MODEL_REGISTRY.register("hybrid_moe_lm",
+                         "linear + latent attention, routed experts: "
+                         "scores packed token documents (forward only)")
+def _hybrid_moe_lm(p: "TrainParams"):
+    from .hybrid_lm import HybridMoELM
+    return _document_scorer(p, "hybrid_moe_lm", HybridMoELM)
+
+
+@MODEL_REGISTRY.register("sambay_lm",
+                         "Mamba + differential attention + a YOCO cross-"
+                         "decoder: scores packed token documents (forward "
+                         "only)")
+def _sambay_lm(p: "TrainParams"):
+    from .sambay_lm import SambaYLM
+    return _document_scorer(p, "sambay_lm", SambaYLM)
 
 
 class TrainParams(Parameter):
@@ -118,7 +134,7 @@ class TrainParams(Parameter):
     arch = field(str, default="",
                  help="JSON file of a published architecture's config keys "
                       "(+ held_experts, vocab_rows), for models described "
-                      "by one (hybrid_moe_lm)")
+                      "by one (hybrid_moe_lm, sambay_lm)")
     task = field(str, default="binary",
                  enum=["binary", "regression", "score"],
                  help="score: the model's output is the row's score as it "

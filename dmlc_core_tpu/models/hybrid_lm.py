@@ -72,7 +72,7 @@ from ..ops.doc_attention import doc_causal_attention_counted, walk_blocks
 from ..ops.kda import kda_chunked_counted
 from ..ops.moe import held_experts_sum, route, swiglu
 
-__all__ = ["HybridMoELM", "canonical", "load_arch"]
+__all__ = ["HybridMoELM", "canonical", "doc_scores", "load_arch"]
 
 Params = Dict[str, object]
 F32 = jnp.float32
@@ -192,6 +192,41 @@ def _doc_conv(x: jax.Array, taps: jax.Array, pos: jax.Array) -> jax.Array:
         y = y + jnp.where((pos >= back)[:, None], shifted.astype(F32), 0.0) \
             * taps[kk - 1 - back].astype(F32)
     return y.astype(x.dtype)
+
+
+def doc_scores(hid: jax.Array, batch: Dict[str, jax.Array], logits_of):
+    """(scores ``[batch_rows]`` float32, ``{"tokens", "documents"}``): each
+    document's mean log-probability of its next tokens from the final
+    hidden states ``hid [T, H]``; ``logits_of(h [HEAD_BLOCK, H])`` gives a
+    block's float32 logits over the vocabulary rows, so the ``[T, V]``
+    logits exist one block of tokens at a time.  The head of every scorer
+    in ``models``."""
+    seg, row_ptr = batch["segments"], batch["row_ptr"]
+    rows = row_ptr.shape[0] - 1
+    t = seg.shape[0]
+    with jax.named_scope("lm_head"):
+        target = jnp.roll(batch["ids"], -1)
+        pad = -t % HEAD_BLOCK
+        blocks = lambda a: jnp.pad(                               # noqa: E731
+            a, [(0, pad)] + [(0, 0)] * (a.ndim - 1)).reshape(
+                (t + pad) // HEAD_BLOCK, HEAD_BLOCK, *a.shape[1:])
+
+        def block_logp(args):
+            h_b, target_b = args
+            logits = logits_of(h_b)
+            picked = jnp.take_along_axis(logits, target_b[:, None], 1)
+            return picked[:, 0] - jax.nn.logsumexp(logits, axis=-1)
+
+        logp = jax.lax.map(block_logp, (blocks(hid), blocks(target)))
+        logp = logp.reshape(-1)[:t]
+        # token t predicts t+1 where both lie in one document
+        counted = (seg == jnp.roll(seg, -1)) & (seg < rows)
+        counted = counted.at[-1].set(False)
+        total = jax.ops.segment_sum(jnp.where(counted, logp, 0.0), seg,
+                                    num_segments=rows + 1)[:rows]
+        n = jnp.diff(row_ptr)
+        scores = total / jnp.maximum(n - 1, 1).astype(F32)
+    return scores, {"tokens": row_ptr[rows], "documents": jnp.sum(n > 0)}
 
 
 class HybridMoELM:
@@ -560,35 +595,11 @@ class HybridMoELM:
         attention ``attn.key_blocks_full`` and ``attn.key_blocks_window``,
         the key blocks one full and one sliding layer's walk visits for this
         batch (``ops.doc_attention.walk_blocks``)."""
-        seg, row_ptr = batch["segments"], batch["row_ptr"]
-        rows = row_ptr.shape[0] - 1
-        t = seg.shape[0]
         hid, counters, _ = self._hidden(params, batch)
-        with jax.named_scope("lm_head"):
-            target = jnp.roll(batch["ids"], -1)
-            pad = -t % HEAD_BLOCK
-            blocks = lambda a: jnp.pad(                           # noqa: E731
-                a, [(0, pad)] + [(0, 0)] * (a.ndim - 1)).reshape(
-                    (t + pad) // HEAD_BLOCK, HEAD_BLOCK, *a.shape[1:])
-
-            def block_logp(args):
-                h_b, target_b = args
-                logits = jnp.dot(h_b, params["head"],
-                                 preferred_element_type=F32)
-                picked = jnp.take_along_axis(logits, target_b[:, None], 1)
-                return picked[:, 0] - jax.nn.logsumexp(logits, axis=-1)
-
-            logp = jax.lax.map(block_logp, (blocks(hid), blocks(target)))
-            logp = logp.reshape(-1)[:t]
-            # token t predicts t+1 where both lie in one document
-            counted = (seg == jnp.roll(seg, -1)) & (seg < rows)
-            counted = counted.at[-1].set(False)
-            total = jax.ops.segment_sum(jnp.where(counted, logp, 0.0), seg,
-                                        num_segments=rows + 1)[:rows]
-            n = jnp.diff(row_ptr)
-            scores = total / jnp.maximum(n - 1, 1).astype(F32)
-        counters["tokens"] = row_ptr[rows]
-        counters["documents"] = jnp.sum(n > 0)
+        scores, counts = doc_scores(
+            hid, batch, lambda h: jnp.dot(h, params["head"],
+                                          preferred_element_type=F32))
+        counters.update(counts)
         return scores, counters
 
     def forward(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:

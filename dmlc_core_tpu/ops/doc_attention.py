@@ -66,6 +66,17 @@ block at least and a head block exists; everywhere else (the CPU; float32;
 small heads; a caller that names ``block``) the ``jnp`` formulation below
 runs.  ``doc_causal_attention_counted`` says which.
 
+**Differential pairs** (``doc_diff_attention_counted``, PR 43): two
+softmax maps a pair of query heads over the same value pair, ``(A1 -
+lambda A2) V``.  On a TPU one Pallas kernel a call (``diff_attention``)
+walks the same bounds with the same copies, keeps both maps' maximum, sum
+and accumulator in VMEM and writes ``acc1 / l1 - lambda acc2 / l2`` as one
+tile.  The heads are 64 wide, which the rule above leaves to ``jnp`` (half
+of a 128-deep pass would be zeros): a group's two key heads are one run of
+128 lanes of ``k``'s row, and each map's query comes zero-padded to the
+pair's 128 rows, so one 128-deep product gives one map's scores — the
+matrix unit's cost of a 64-deep product, which it pads to 128 anyway.
+
 The ``jnp`` formulation is a ``lax.map`` over query blocks of a
 ``fori_loop`` over key blocks, all heads at once.  Its block is 512 tokens,
 halved while the float32 scores of one pair of blocks, ``[H, block,
@@ -90,7 +101,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["doc_causal_attention", "doc_causal_attention_counted",
-           "walk_blocks"]
+           "doc_diff_attention_counted", "walk_blocks"]
 
 _NEG = -1e30
 _SCORE_TILE_BYTES = 48 << 20
@@ -331,6 +342,201 @@ def _attention_kernel(q, k, v, segments, first_key, interpret=False):
       # a head a run of d_qk lanes of its token's row
       q.transpose(1, 2, 0), k.reshape(tp, hkv * dqk), v.transpose(1, 2, 0))
     return o.transpose(2, 0, 1)[:t]
+
+
+# -- differential heads: two maps over one value pair -------------------------
+
+def _diff_kernel_body(lo_ref, lam_ref, start_ref, q1_ref, q2_ref, k_hbm,
+                      vt_hbm, o_ref, k_buf, vt_buf, sem, m_ref, l_ref,
+                      acc_ref):
+    """``_attention_kernel_body``'s walk for differential pairs.  A pair
+    ``h`` of the block reads group ``h // (pairs / groups)``, whose keys are
+    ``w = 2 d`` lanes of ``k [T, G * w]`` (its two key heads side by side)
+    and whose values are the ``[w, T]`` rows of ``vt`` (its two value heads
+    stacked).  ``q1_ref``, ``q2_ref [pairs, w, block]`` hold the pair's two
+    query heads, each beside ``d`` zero rows where the other key head lies,
+    so one product over ``w`` gives a map's scores.  Each map keeps its own
+    running maximum, sum and accumulator in VMEM; the two share every key
+    and value tile, and ``o = acc1 / l1 - lam acc2 / l2`` leaves as one
+    tile.  ``lam_ref [1, 1]`` (SMEM)."""
+    f32 = jnp.float32
+    pairs, width, block = q1_ref.shape
+    groups = vt_buf.shape[1]
+    grp = pairs // groups
+    g, i = pl.program_id(0), pl.program_id(1)
+    lo = lo_ref[i]
+
+    def copies(j, slot):
+        at = pl.ds(pl.multiple_of(j * block, block), block)
+        ours = pl.ds(pl.multiple_of(g * groups * width, 128), groups * width)
+        return (pltpu.make_async_copy(k_hbm.at[at, ours], k_buf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(
+                    vt_hbm.at[pl.ds(g * groups, groups), :, at],
+                    vt_buf.at[slot], sem.at[1, slot]))
+
+    def fetch(j):
+        for copy in copies(j, j % 2):
+            copy.start()
+
+    pl.when(i == 0)(lambda: fetch(lo))
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    start = start_ref[...]                                    # [1, block]
+    key = lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    behind = key - lax.broadcasted_iota(jnp.int32, (block, block), 1)
+
+    def one_key_block(j, _):
+        pl.when(j < i)(lambda: fetch(j + 1))
+        ok = (key >= start - j * block) & (behind <= (i - j) * block)
+        slot = j % 2
+        for copy in copies(j, slot):
+            copy.wait()
+        for h in range(pairs):
+            kv = h // grp
+            keys = k_buf[slot, :, kv * width:(kv + 1) * width]
+            for half, q_ref in enumerate((q1_ref, q2_ref)):
+                s = jnp.dot(keys, q_ref[h], preferred_element_type=f32)
+                s = jnp.where(ok, s, -jnp.inf)
+                m = m_ref[half, h]
+                m_new = jnp.maximum(m, jnp.max(s, 0, keepdims=True))
+                p = jnp.exp(s - m_new)
+                scale = jnp.exp(m - m_new)
+                m_ref[half, h] = m_new
+                part = p[:8]
+                for r in range(8, block, 8):
+                    part = part + p[r:r + 8]
+                l_ref[half, h] = l_ref[half, h] * scale + part
+                acc_ref[half, h] = acc_ref[half, h] * scale + jnp.dot(
+                    vt_buf[slot, kv], p.astype(vt_buf.dtype),
+                    preferred_element_type=f32)
+
+    lax.fori_loop(lo, i + 1, one_key_block, None)
+    pl.when(i + 1 < pl.num_programs(1))(lambda: fetch(lo_ref[i + 1]))
+    lam = lam_ref[0, 0]
+    o_ref[...] = (acc_ref[0] / jnp.sum(l_ref[0], 1, keepdims=True)
+                  - lam * acc_ref[1] / jnp.sum(l_ref[1], 1, keepdims=True))
+
+
+def _diff_pair_block(pairs: int, groups: int, d: int, block: int,
+                     itemsize: int) -> Optional[int]:
+    """Pairs a kernel step takes: the largest divisor of ``pairs`` that is
+    whole groups and whose tiles (two query tiles in and ``o`` out,
+    double-buffered; a group's two key blocks of ``k`` and ``v``) and the
+    two maps' state fit ``_VMEM_BUDGET``; None where no divisor is."""
+    grp, w = pairs // groups, 2 * d
+    per_group = block * (
+        grp * (2 * 2 * w * itemsize + 2 * w * 4)              # q1, q2, o
+        + 2 * 2 * w * itemsize                                # k, v
+        + grp * 2 * (w + 8 + 8) * 4)                          # acc, m, l
+    fit = [n * grp for n in range(1, groups + 1)
+           if groups % n == 0 and n * per_group <= _VMEM_BUDGET]
+    return max(fit) if fit else None
+
+
+def _diff_kernel_fits(q: jax.Array, k: jax.Array) -> bool:
+    """bfloat16; a value pair ``2 d`` whole tiles of 128 lanes (the two
+    ``d``-wide key heads of a group are one such run of ``k``'s row); a
+    block of tokens; a pair block."""
+    t, h, d = q.shape
+    return (q.dtype == k.dtype == jnp.bfloat16 and (2 * d) % 128 == 0
+            and t >= _BLOCK
+            and _diff_pair_block(h // 2, k.shape[1] // 2, d, _BLOCK, 2)
+            is not None)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _diff_attention_kernel(q, k, v, lam, segments, first_key,
+                           interpret=False):
+    """``_diff_attention_jnp``'s contract through the ``diff_attention``
+    kernel, blocks of ``_BLOCK`` tokens."""
+    t, h, d = q.shape
+    hkv = k.shape[1]
+    pairs, groups, w = h // 2, hkv // 2, 2 * d
+    block = _BLOCK
+    q, k, v, _, first_key = _padded(q, k, v, segments, first_key, block)
+    tp = q.shape[0]
+    nb = tp // block
+    per = _diff_pair_block(pairs, groups, d, block, q.dtype.itemsize)
+    kv_groups = per * groups // pairs
+    start = first_key.astype(jnp.int32).reshape(nb, 1, block)
+    # a pair's query heads with the tokens on the lanes, each beside the
+    # zero rows where the other map's key head lies
+    qt = q.reshape(tp, pairs, 2, d).transpose(2, 1, 3, 0)     # [2,P,d,T]
+    zero = jnp.zeros_like(qt[0])
+    q1 = jnp.concatenate([qt[0], zero], 1)
+    q2 = jnp.concatenate([zero, qt[1]], 1)
+    q_spec = pl.BlockSpec((per, w, block), lambda g, i, lo: (g, 0, i))
+    o = pl.pallas_call(
+        _diff_kernel_body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(pairs // per, nb),
+            in_specs=[
+                pl.BlockSpec((1, 1), lambda g, i, lo: (0, 0),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((None, 1, block), lambda g, i, lo: (i, 0, 0)),
+                q_spec, q_spec,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((per, w, block),
+                                   lambda g, i, lo: (g, 0, i)),
+            scratch_shapes=[
+                pltpu.VMEM((2, block, kv_groups * w), k.dtype),
+                pltpu.VMEM((2, kv_groups, w, block), v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((2, per, 1, block), jnp.float32),
+                pltpu.VMEM((2, per, 8, block), jnp.float32),
+                pltpu.VMEM((2, per, w, block), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((pairs, w, tp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name="diff_attention",
+    )(start[:, 0, 0] // block, jnp.reshape(lam, (1, 1)).astype(jnp.float32),
+      start, q1, q2, k.reshape(tp, hkv * d),
+      v.reshape(tp, groups, w).transpose(1, 2, 0))
+    return o.transpose(2, 0, 1)[:t]
+
+
+def _diff_attention_jnp(q, k, v, lam, segments, first_key, block):
+    """The two maps as two walks of ``_attention_jnp`` over the same value
+    pairs, then ``o1 - lam o2``."""
+    t, h, d = q.shape
+    pairs = v.reshape(t, -1, 2 * d)
+    o1 = _attention_jnp(q[:, 0::2], k[:, 0::2], pairs, segments, first_key,
+                        block)
+    o2 = _attention_jnp(q[:, 1::2], k[:, 1::2], pairs, segments, first_key,
+                        block)
+    return o1 - lam * o2
+
+
+def doc_diff_attention_counted(q: jax.Array, k: jax.Array, v: jax.Array,
+                               lam: jax.Array, segments: jax.Array,
+                               first_key: jax.Array):
+    """Differential attention (arXiv:2410.05258) inside each document.
+    ``q [T, 2P, d]`` (already scaled), ``k, v [T, 2G, d]``, the heads
+    interleaved: pair ``j``'s two query heads are ``2j`` and ``2j + 1``,
+    group ``g``'s two key heads ``2g`` and ``2g + 1``, and its value pair
+    ``V_g = [v_2g | v_2g+1]`` is ``2 d`` wide; pair ``j`` reads group ``j //
+    (P / G)``.  ``lam`` a float32 scalar; ``segments`` and ``first_key`` as
+    ``doc_causal_attention_counted`` has them.  Returns ``(o [T, P, 2 d]
+    float32, fused)`` with ``o_j = (softmax(q_2j k_2g^T) - lam
+    softmax(q_2j+1 k_2g+1^T)) V_g`` over the visible keys; ``fused`` 1
+    where the program this was lowered into holds the ``diff_attention``
+    kernel and 0 where the ``jnp`` formulation runs."""
+    if q.shape[1] % 2 or k.shape[1] % 2 or q.shape[1] % k.shape[1] \
+            or k.shape != v.shape:
+        raise ValueError(f"{q.shape[1]} query heads on keys {k.shape} and "
+                         f"values {v.shape} are no whole pairs and groups")
+    args = (q, k, v, jnp.asarray(lam, jnp.float32), segments, first_key)
+    plain = lambda *a: (  # noqa: E731
+        _diff_attention_jnp(*a, default_block(q.shape[1] // 2)), jnp.int32(0))
+    if _diff_kernel_fits(q, k):
+        return lax.platform_dependent(
+            *args, default=plain,
+            tpu=lambda *a: (_diff_attention_kernel(*a), jnp.int32(1)))
+    return plain(*args)
 
 
 def walk_blocks(first_key: jax.Array) -> jax.Array:

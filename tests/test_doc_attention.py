@@ -11,6 +11,7 @@ import pytest
 from dmlc_core_tpu.ops import doc_attention
 from dmlc_core_tpu.ops.doc_attention import (doc_causal_attention,
                                              doc_causal_attention_counted,
+                                             doc_diff_attention_counted,
                                              walk_blocks)
 
 # documents of one stream: boundaries inside the kernel's 256-token blocks,
@@ -188,3 +189,127 @@ def test_walk_blocks_counts_what_the_kernel_walks(lengths, window, blocks):
     start = bounds[np.repeat(np.arange(len(lengths)), lengths)]
     row = jnp.asarray(first_keys(start, window), jnp.int32)
     assert int(walk_blocks(row)) == blocks
+
+
+# -- differential heads (``doc_diff_attention_counted``): new cases only ------
+
+def dense_differential(q, k, v, seg, window, lam):
+    """``(A1 - lam A2) V`` a pair, float64 on the host: query heads ``2j``,
+    ``2j + 1`` on key heads ``2g``, ``2g + 1``, ``V_g = [v_2g | v_2g+1]``."""
+    t, h, d = q.shape
+    pairs, groups = h // 2, k.shape[1] // 2
+    behind = np.arange(t)[:, None] - np.arange(t)[None, :]
+    seen = (seg[:, None] == seg[None, :]) & (behind >= 0) & (behind < window)
+    out = np.zeros((t, pairs, 2 * d))
+    for j in range(pairs):
+        g = j // (pairs // groups)
+        maps = []
+        for half in (0, 1):
+            s = np.where(seen, q[:, 2 * j + half] @ k[:, 2 * g + half].T,
+                         -np.inf)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            maps.append(p / p.sum(-1, keepdims=True))
+        out[:, j] = (maps[0] - lam * maps[1]) @ np.concatenate(
+            [v[:, 2 * g], v[:, 2 * g + 1]], -1)
+    return out
+
+
+def diff_args(q, k, v, seg, first, lam, dtype=jnp.float32):
+    q, k, v, seg, first = as_args(q, k, v, seg, first, dtype)
+    return q, k, v, jnp.float32(lam), seg, first
+
+
+@pytest.mark.parametrize("window", WINDOWS)
+@pytest.mark.parametrize("h,hkv", [(4, 2), (8, 4), (8, 2)])
+def test_differential_jnp_walk_is_the_dense_maps(h, hkv, window):
+    q, k, v, seg, start = inputs(h, hkv, 16, 16, seed=h + hkv + window)
+    v = np.random.default_rng(window).normal(size=k.shape)
+    got = doc_attention._diff_attention_jnp(
+        *diff_args(q, k, v, seg, first_keys(start, window), 0.37), 64)
+    assert got.shape == (len(seg), h // 2, 32)
+    np.testing.assert_allclose(
+        np.asarray(got), dense_differential(q, k, v, seg, window, 0.37),
+        atol=2e-5)
+
+
+@pytest.mark.parametrize("window", WINDOWS)
+@pytest.mark.parametrize("h,hkv,pair_blocks", [(4, 2, 1), (8, 4, 2),
+                                               (8, 2, 1)])
+def test_differential_kernel_is_the_dense_maps(h, hkv, pair_blocks, window,
+                                               monkeypatch):
+    """The kernel, interpreted, at the model's head width (64: a value pair
+    of 128 lanes), float32; one pair block and two (whole groups)."""
+    block = doc_attention._BLOCK
+    pairs, groups = h // 2, hkv // 2
+    grp = pairs // groups
+    per_group = block * (grp * (2 * 2 * 128 * 4 + 2 * 128 * 4)
+                         + 2 * 2 * 128 * 4 + grp * 2 * (128 + 16) * 4)
+    monkeypatch.setattr(doc_attention, "_VMEM_BUDGET",
+                        per_group * groups // pair_blocks)
+    assert doc_attention._diff_pair_block(pairs, groups, 64, block, 4) \
+        == pairs // pair_blocks
+    q, k, v, seg, start = inputs(h, hkv, 64, 64, seed=h * hkv + window)
+    v = np.random.default_rng(window).normal(size=k.shape)
+    got = jax.jit(doc_attention._diff_attention_kernel.__wrapped__,
+                  static_argnames="interpret")(
+        *diff_args(q, k, v, seg, first_keys(start, window), 0.61),
+        interpret=True)
+    assert got.shape == (len(seg), h // 2, 128) and got.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(got), dense_differential(q, k, v, seg, window, 0.61),
+        atol=2e-5)
+
+
+@pytest.mark.parametrize("lengths", [LENGTHS, [70, 33, 129, 1, 90, 60, 200]],
+                         ids=["whole_blocks", "ragged_t"])
+def test_differential_kernel_and_jnp_agree_in_bfloat16(lengths):
+    """The same rounding points (operands bfloat16, ``p`` cast to ``v``'s
+    type, float32 sums, one division a map): the two differ by the order of
+    their sums, and the kernel is no farther than ``jnp`` from the float64
+    maps over the same bfloat16 inputs; windowed."""
+    q, k, v, seg, start = inputs(8, 4, 64, 64, seed=11, lengths=lengths)
+    v = np.random.default_rng(2).normal(size=k.shape)
+    args = diff_args(q, k, v, seg, first_keys(start, 100), 0.5, jnp.bfloat16)
+    assert doc_attention._diff_kernel_fits(args[0], args[1])
+    got = np.asarray(doc_attention._diff_attention_kernel(*args,
+                                                          interpret=True))
+    want = np.asarray(doc_attention._diff_attention_jnp(
+        *args, doc_attention._BLOCK))
+    np.testing.assert_allclose(got, want, atol=5e-6)
+    f64 = lambda x: np.asarray(                               # noqa: E731
+        jnp.asarray(x, jnp.bfloat16).astype(jnp.float32), np.float64)
+    exact = dense_differential(f64(q), f64(k), f64(v), seg, 100, 0.5)
+    assert np.abs(got - exact).max() <= 1.05 * np.abs(want - exact).max()
+
+
+@pytest.mark.parametrize("h,hkv,d,dtype,fits", [
+    (40, 20, 64, jnp.bfloat16, True),     # phi4_mini_flash_3.8b
+    (40, 20, 64, jnp.float32, False), (40, 20, 32, jnp.bfloat16, False),
+    (8, 4, 128, jnp.bfloat16, True), (4, 2, 64, jnp.bfloat16, True)])
+def test_differential_kernel_rule_is_a_function_of_the_shapes(h, hkv, d,
+                                                              dtype, fits):
+    q = jnp.zeros((512, h, d), dtype)
+    k = jnp.zeros((512, hkv, d), dtype)
+    assert doc_attention._diff_kernel_fits(q, k) is fits
+    # the 64-wide heads the plain kernel leaves to jnp (half zeros there)
+    if d == 64:
+        assert not doc_attention._kernel_fits(q, k, k)
+
+
+def test_differential_pair_block_is_sized_from_vmem():
+    # phi4_mini_flash_3.8b: 20 pairs on 10 groups, half of them a step
+    assert doc_attention._diff_pair_block(20, 10, 64, 256, 2) == 10
+    assert doc_attention._diff_pair_block(2, 1, 64, 256, 2) == 2
+
+
+def test_differential_heads_take_the_kernel_on_a_tpu_and_jnp_here():
+    q, k, v, seg, start = inputs(8, 4, 64, 64, seed=5)
+    args = diff_args(q, k, k, seg, first_keys(start, 64), 0.2, jnp.bfloat16)
+    got, fused = jax.jit(doc_diff_attention_counted)(*args)
+    assert int(fused) == 0 and got.shape == (len(seg), 4, 128)
+
+
+def test_heads_that_are_no_whole_pairs_are_refused():
+    q, k, v, seg, start = inputs(6, 3, 16, 16, seed=1)
+    with pytest.raises(ValueError, match="no whole pairs"):
+        doc_diff_attention_counted(*diff_args(q, k, k, seg, start, 0.1))

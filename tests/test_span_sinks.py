@@ -101,18 +101,24 @@ def _spin(seconds):
 
 def test_a_sleeping_span_has_no_cpu_time_and_a_spinning_one_has_all(
         clean_ring):
+    # 0.25 s: a thread clock that ticks by 10 ms (the chip's machine) is
+    # then 4 % of the span, where it was 20 % of a 50 ms one
     with trace.span("unit.cpu.sleep") as asleep:
-        time.sleep(0.05)
-    # the best of a few: a spin on a shared machine can lose its core
-    for _ in range(5):
-        with trace.span("unit.cpu.spin"):
-            _spin(0.05)
+        time.sleep(0.25)
     (sleep,) = records("unit.cpu.sleep")
-    assert sleep["dur_us"] >= 50_000 and sleep["cpu_us"] < 0.1 * sleep["dur_us"]
+    assert sleep["dur_us"] >= 250_000
+    assert sleep["cpu_us"] < 0.1 * sleep["dur_us"]
     assert asleep.cpu_s == pytest.approx(sleep["cpu_us"] * 1e-6, abs=1e-6)
-    spin = max(records("unit.cpu.spin"),
-               key=lambda r: r["cpu_us"] / r["dur_us"])
-    assert abs(spin["dur_us"] - spin["cpu_us"]) <= 0.2 * spin["dur_us"]
+    # a spin until its thread has burnt 0.25 s of CPU, however long a
+    # shared machine keeps it off a core (6 test workers left one 55 % of
+    # its wall time): the span holds that CPU time, and no more than its
+    # wall time, to a tick of the thread clock
+    with trace.span("unit.cpu.spin"):
+        c0 = time.thread_time()
+        while time.thread_time() - c0 < 0.25:
+            pass
+    (spin,) = records("unit.cpu.spin")
+    assert 240_000 <= spin["cpu_us"] <= spin["dur_us"] + 10_000
 
 
 def test_cpu_time_is_the_spans_own_thread_only(clean_ring):
@@ -269,8 +275,11 @@ def test_an_error_still_reaches_every_sink(clean_ring):
 def test_record_completed_is_placed_in_the_past(clean_ring):
     now = time.monotonic()
     trace.record_completed("unit.done", 1.5, why="timed elsewhere")
+    took = time.monotonic() - now
     r = records("unit.done")[0]
-    assert r["dur_us"] == pytest.approx(1.5e6, abs=5e3)
+    # 1.5 s plus no more than the call's own time, however long a loaded
+    # machine stretches it
+    assert 1.5e6 <= r["dur_us"] <= 1.5e6 + took * 1e6 + 1e3
     assert r["mono_us"] == pytest.approx((now - 1.5) * 1e6, abs=5e4)
     assert r["attrs"] == {"why": "timed elsewhere"}
     assert "cpu_us" not in r       # nobody took its thread's CPU clock

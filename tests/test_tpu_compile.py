@@ -406,6 +406,77 @@ def case_document_scorer_afmoe(topo):
         "dense_mlp", "lm_head"])
 
 
+def case_document_scorer_sambay(topo):
+    """The SambaY scorer whole at its timed sizes (32 layers at published
+    widths, the whole tied vocabulary, 8 documents in 32 768 tokens,
+    bfloat16): it fits the chip beside its 7.71 GB of parameters, every
+    Mamba layer's scan is the ``selective_scan`` kernel and every attention
+    layer's walk the ``diff_attention`` kernel, selected at lowering, under
+    the scopes a trace reads; neither the ``[T, T]`` scores nor the
+    ``[T, V]`` logits nor a ``[T, C, N]`` state exist whole."""
+    import re
+
+    from dmlc_core_tpu.models.sambay_lm import SambaYLM, load_arch
+    here = os.path.dirname(os.path.abspath(__file__))
+    model = SambaYLM(load_arch(os.path.join(
+        here, "..", "benchmarks", "chip", "configs",
+        "phi4_mini_flash_3.8b.json")))
+    one = SingleDeviceSharding(topo.devices[0])
+    S, i32 = jax.ShapeDtypeStruct, jnp.int32
+    t, rows = 32768, 8
+    params = jax.tree.map(lambda shape: S(shape, model.dtype, sharding=one),
+                          model.shapes(),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    batch = _on(one, dict(_batch(rows, t), row_ptr=S((rows + 1,), i32)))
+    compiled = jax.jit(model.forward_counted).lower(params, batch).compile()
+    assert sum(np.prod(leaf.shape) for leaf in jax.tree.leaves(params)) \
+        == 3_852_562_944
+    # 7.706 GB of arguments + 3.545 GB of temporaries (PR 43; ISSUE 43
+    # expected 4-5 GB of temporaries)
+    assert _hbm(compiled) < 11_300_000_000 < HBM_BYTES
+    text = compiled.as_text()
+    for name, scope, count in (("selective_scan", "mamba/scan", 9),
+                               ("diff_attention", "(dattn|cross)/attention",
+                                16)):
+        kernels = [line for line in text.splitlines()
+                   if "custom-call(" in line and f" %{name}" in line]
+        assert len(kernels) == count, name
+        for line in kernels:
+            assert 'custom_call_target="tpu_custom_call"' in line
+            assert re.search(rf'op_name="[^"]*{scope}/', line), line[:300]
+    assert " %doc_attention" not in text         # 64-wide heads: the pairs'
+    # the kernels' flags fold to the counters' constants in the chip's program
+    entry = text[text.index("\nENTRY"):]
+    root = re.sub(r"/\*.*?\*/", "", re.search(
+        r"ROOT %[\w.\-]+ = .*? tuple\(([^)]*)\)", entry).group(1)).split(",")
+
+    def constant(name):
+        line = re.search(rf"^\s+{re.escape(name)} = (.*)$", entry, re.M)[1]
+        m = re.search(r" (?:constant\((\d+)\)|copy\((%[\w.\-]+)\))", line)
+        return int(m[1]) if m and m[1] else constant(m[2]) if m else None
+
+    paths, _ = jax.tree_util.tree_flatten_with_path(
+        jax.eval_shape(model.forward_counted, params, batch))
+    names = [jax.tree_util.keystr(p) for p, _ in paths]
+    for counter, want in (("ssm.fused_layers", 9), ("dattn.fused_layers", 16)):
+        assert constant(root[names.index(f"[1]['{counter}']")].strip()) \
+            == want, counter
+    top = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]", text,
+                     re.M)
+    for dims in top:
+        d = [int(x) for x in dims.split(",")]
+        assert not (t in d and model.vocab in d), dims     # [T, V]
+        assert d.count(t) < 2, dims                        # [T, T]
+        assert not (t in d and model.d_state in d
+                    and model.d_inner in d), dims          # [T, C, N]
+    _names_scopes(compiled, [
+        "lm_embed", "layer_norm", "mamba/in_proj", "mamba/conv",
+        "mamba/x_proj", "mamba/dt", "mamba/scan", "mamba/gate", "mamba/out",
+        "dattn/project", "dattn/attention", "dattn/combine", "dattn/out",
+        "cross/project", "cross/attention", "cross/combine", "cross/out",
+        "gmu/gate", "gmu/out", "dense_mlp", "lm_head"])
+
+
 CASES = {
     "gather_embed_128": case_kernel(lambda one, w: _gather(one, w, False)),
     "gather_fm_128": case_kernel(lambda one, w: _gather(one, w, True)),
@@ -423,6 +494,7 @@ CASES = {
     "document_scorer_forward": case_document_scorer,
     "document_scorer_dsv3_forward": case_document_scorer_dsv3,
     "document_scorer_afmoe_forward": case_document_scorer_afmoe,
+    "document_scorer_sambay_forward": case_document_scorer_sambay,
 }
 
 
