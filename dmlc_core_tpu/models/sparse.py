@@ -109,6 +109,13 @@ class FactorizationMachine:
                 rng, (self.num_features, self.dim), jnp.float32),
         }
 
+    @property
+    def row_tables(self) -> tuple:
+        """The leaves :meth:`loss` reads only at ``batch["ids"]`` rows, so
+        that their gradient is zero on every other row (the train step's
+        fused table update, ``models/train.py``); ``l2`` reads them whole."""
+        return () if self.l2 else ("v", "w")
+
     def forward(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
         num_rows = batch["labels"].shape[0]
         linear = csr_dense_matvec(batch["ids"], batch["vals"],

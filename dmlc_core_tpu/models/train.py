@@ -30,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..pipeline.device_loader import DeviceLoader
 from ..utils import log_info
+from ..utils.metrics import metrics
 from ..utils.timer import Timer
 
 __all__ = ["make_train_step", "make_eval_step", "batch_sharding",
@@ -98,30 +99,131 @@ def shard_params(params: Dict[str, jax.Array],
     return {k: jax.device_put(v, shardings[k]) for k, v in params.items()}
 
 
-def _sgd_step(model, optimizer):
-    """The ONE SGD update recipe every step builder closes over
-    (per-step, wire-fused scan, and kbatch scan must never drift)."""
-    def step(params, opt_state, batch):
-        # the scopes name the step's three parts in every HLO ``op_name``
-        with jax.named_scope("loss_and_grad"):
-            loss, grads = jax.value_and_grad(model.loss)(params, batch)
+def _update(optimizer):
+    """What a step does to parameters and state given the gradients, under
+    the scopes that name the step's parts in every HLO ``op_name``."""
+    def update(grads, opt_state, params):
         with jax.named_scope("optimizer_update"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
         with jax.named_scope("apply_updates"):
             params = optax.apply_updates(params, updates)
+        return params, opt_state
+    return update
+
+
+def _sgd_step(model, optimizer, fused: bool = False):
+    """The ONE SGD update recipe every step builder closes over
+    (per-step, wire-fused scan, and kbatch scan must never drift).
+
+    With ``fused`` (the step's arguments live on one TPU) the tables the
+    model declares read only at ``batch["ids"]`` (``model.row_tables``)
+    take the exact dense update as one streaming kernel
+    (:mod:`dmlc_core_tpu.ops.table_update`) where the optimizer's update of
+    them is elementwise: ``model.loss`` runs on the batch's unique rows,
+    and no gradient as large as a table exists.  Otherwise, and for every
+    other leaf, the update is optax's over dense gradients."""
+    update = _update(optimizer)
+    tables = tuple(getattr(model, "row_tables", ()))
+
+    def dense(params, opt_state, batch):
+        with jax.named_scope("loss_and_grad"):
+            loss, grads = jax.value_and_grad(model.loss)(params, batch)
+        params, opt_state = update(grads, opt_state, params)
         return params, opt_state, loss
+
+    def step(params, opt_state, batch):
+        plan, why = None, "the step is not on one TPU"
+        if fused:
+            # imported here: Pallas takes about a second to import, which
+            # only a step on a TPU needs to pay
+            from ..ops import table_update
+            plan, why = table_update.plan(update, params, opt_state, tables,
+                                          batch["ids"].shape[0])
+        _built(tables, plan, why)
+        if plan is None:
+            return dense(params, opt_state, batch)
+        return _table_step(model, plan, params, opt_state, batch)
     return step
+
+
+def _built(tables, plan, why: str) -> None:
+    """The ``train.fused_tables`` gauge: table leaves that took the kernel
+    in the step last built (traced)."""
+    n = 0 if plan is None else len(plan.tables)
+    metrics.gauge("train.fused_tables").set(n)
+    if tables:
+        log_info("train step: %d of tables %s take the fused update%s", n,
+                 ",".join(tables), f" ({why})" if why else "")
+
+
+def _table_step(model, plan, params, opt_state, batch):
+    """The step with ``plan.tables`` updated by the kernel: the loss and its
+    gradient over the batch's unique rows of each table, then one pass over
+    every table and its optimizer state."""
+    from ..ops import table_update
+    tables = plan.tables
+    with jax.named_scope("loss_and_grad"):
+        with jax.named_scope("table_rows"):
+            uid, inv, count = table_update.batch_rows(batch["ids"])
+            rows = {k: table_update.gather_rows(params[k], uid, count)
+                    for k in tables}
+        rest = {k: v for k, v in params.items() if k not in tables}
+
+        def loss_fn(rows, rest):
+            return model.loss({**rest, **rows}, dict(batch, ids=inv))
+        loss, (rows_grads, grads) = jax.value_and_grad(
+            loss_fn, argnums=(0, 1))(rows, rest)
+    # the tables' dense gradients are never read: their place holds the
+    # tables themselves
+    flat = jax.tree.leaves(({**grads, **{k: params[k] for k in tables}},
+                            opt_state, params))
+    params, opt_state = plan.apply(flat, rows_grads, uid)
+    return params, opt_state, loss
+
+
+class _StepOnDevice:
+    """A jitted step built once per placement of its arguments: on one TPU
+    with the tables' kernel, elsewhere without (``build(fused)`` makes the
+    jitted function).  Called and lowered as the jitted function is."""
+
+    def __init__(self, build):
+        self._build = build
+        self._fns: Dict[Any, Any] = {}
+
+    def _fn(self, params):
+        sharding = getattr(jax.tree.leaves(params)[0], "sharding", None)
+        fn = self._fns.get(sharding)
+        if fn is None:
+            devices = (sharding.device_set if sharding is not None
+                       else {jax.devices()[0]})
+            fn = self._fns[sharding] = self._build(
+                len(devices) == 1
+                and next(iter(devices)).platform == "tpu")
+        return fn
+
+    def __call__(self, params, *args):
+        return self._fn(params)(params, *args)
+
+    def lower(self, params, *args):
+        return self._fn(params).lower(params, *args)
+
+
+def _by_device(model, build):
+    """``build(fused)`` on one TPU when the model declares tables, else the
+    one dense jitted step (the program every other model and mesh runs)."""
+    if not getattr(model, "row_tables", ()):
+        return build(False)
+    return _StepOnDevice(build)
 
 
 def make_train_step(model, optimizer: optax.GradientTransformation,
                     mesh: Optional[Mesh] = None, donate: bool = True):
     """Build the jitted SGD step; with a mesh, inputs/outputs carry
     NamedShardings and XLA inserts the dp gradient all-reduce."""
-
-    step = _sgd_step(model, optimizer)
-
+    donated = (0, 1) if donate else ()
     if mesh is None:
-        return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+        return _by_device(model, lambda fused: jax.jit(
+            _sgd_step(model, optimizer, fused), donate_argnums=donated))
 
     bs = batch_sharding(mesh)
     # params/opt_state shardings are inferred from the input arrays
@@ -129,9 +231,9 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
     # pytree PREFIX so every array of it shards its leading batch/nnz
     # axis over 'dp' without key-set coupling
     return jax.jit(
-        step,
+        _sgd_step(model, optimizer),
         in_shardings=(None, None, bs),
-        donate_argnums=(0, 1) if donate else (),
+        donate_argnums=donated,
     )
 
 
@@ -157,25 +259,28 @@ def make_train_step_fused(model, optimizer: optax.GradientTransformation,
     """
     from ..pipeline.device_loader import make_decoder
     decode = make_decoder(rows, meta)
-    step = _sgd_step(model, optimizer)
 
-    def body(carry, x):
-        p, o = carry
-        batch = decode(*x) if with_segments else decode(x)
-        p, o, loss = step(p, o, batch)
-        return (p, o), loss
+    def build(fused):
+        step = _sgd_step(model, optimizer, fused)
 
-    if with_segments:
-        def kstep(params, opt_state, bufs, segs):
-            (params, opt_state), losses = jax.lax.scan(
-                body, (params, opt_state), (bufs, segs))
-            return params, opt_state, losses
-    else:
-        def kstep(params, opt_state, bufs):
-            (params, opt_state), losses = jax.lax.scan(
-                body, (params, opt_state), bufs)
-            return params, opt_state, losses
-    return jax.jit(kstep, donate_argnums=(0, 1) if donate else ())
+        def body(carry, x):
+            p, o = carry
+            batch = decode(*x) if with_segments else decode(x)
+            p, o, loss = step(p, o, batch)
+            return (p, o), loss
+
+        if with_segments:
+            def kstep(params, opt_state, bufs, segs):
+                (params, opt_state), losses = jax.lax.scan(
+                    body, (params, opt_state), (bufs, segs))
+                return params, opt_state, losses
+        else:
+            def kstep(params, opt_state, bufs):
+                (params, opt_state), losses = jax.lax.scan(
+                    body, (params, opt_state), bufs)
+                return params, opt_state, losses
+        return jax.jit(kstep, donate_argnums=(0, 1) if donate else ())
+    return _by_device(model, build)
 
 
 class FusedTrainer:
@@ -303,21 +408,24 @@ def make_train_step_kbatch(model, optimizer: optax.GradientTransformation,
     Returns ``kstep(params, opt_state, batches) -> (params, opt_state,
     losses[k])``.  Stack host batches with :func:`stack_batches`.
     """
-    step = _sgd_step(model, optimizer)
+    def kstep_of(step):
+        def kstep(params, opt_state, batches):
+            def body(carry, batch):
+                p, o, loss = step(*carry, batch)
+                return (p, o), loss
+            (params, opt_state), losses = jax.lax.scan(
+                body, (params, opt_state), batches)
+            return params, opt_state, losses
+        return kstep
 
-    def kstep(params, opt_state, batches):
-        def body(carry, batch):
-            p, o, loss = step(*carry, batch)
-            return (p, o), loss
-        (params, opt_state), losses = jax.lax.scan(
-            body, (params, opt_state), batches)
-        return params, opt_state, losses
-
+    donated = (0, 1) if donate else ()
     if mesh is None:
-        return jax.jit(kstep, donate_argnums=(0, 1) if donate else ())
+        return _by_device(model, lambda fused: jax.jit(
+            kstep_of(_sgd_step(model, optimizer, fused)),
+            donate_argnums=donated))
     bs = NamedSharding(mesh, P(None, "dp"))    # (k, batch/nnz, ...)
-    return jax.jit(kstep, in_shardings=(None, None, bs),
-                   donate_argnums=(0, 1) if donate else ())
+    return jax.jit(kstep_of(_sgd_step(model, optimizer)),
+                   in_shardings=(None, None, bs), donate_argnums=donated)
 
 
 def stack_batches(batches, sharding: Optional[NamedSharding] = None):

@@ -144,31 +144,60 @@ def case_decoder(meta, gathers):
     return run
 
 
-def case_train_step(kstep):
+def _table_shaped(text, f):
+    """Copies, transposes or broadcasts of an ``f``-row table in ``text``."""
+    import re
+    return re.findall(rf"= f32\[(?:{f},{D}|{D},{f})\]\S* "
+                      r"(?:copy|transpose|broadcast)\(", text)
+
+
+def case_train_step(kstep, f=F, rows=ROWS, nnz=NNZ):
     def run(topo):
+        from dmlc_core_tpu.utils.metrics import metrics
         one = SingleDeviceSharding(topo.devices[0])
-        model = FactorizationMachine(num_features=F, dim=D)
+        model = FactorizationMachine(num_features=f, dim=D)
         opt = optax.adam(1e-2)
         params, opt_state = _on(one, _fm_state(model, opt))
         if kstep == 1:
             lowered = make_train_step(model, opt).lower(
-                params, opt_state, _on(one, _batch()))
+                params, opt_state, _on(one, _batch(rows, nnz)))
         else:
             bufs = jax.ShapeDtypeStruct(
-                (kstep, _fused_words_meta(ROWS, V3_META)), jnp.int32,
+                (kstep, _fused_words_meta(rows, V3_META)), jnp.int32,
                 sharding=one)
             lowered = make_train_step_fused(
-                model, opt, rows=ROWS, meta=V3_META, k=kstep).lower(
+                model, opt, rows=rows, meta=V3_META, k=kstep).lower(
                     params, opt_state, bufs)
+        # arguments on a TPU: both tables take the fused update's kernel
+        assert metrics.gauge("train.fused_tables").value == 2
         compiled = lowered.compile()
         assert _hbm(compiled) < HBM_BYTES
-        # D=32: the flat layout's ops.csr path, no Pallas kernel inside
-        assert "tpu_custom_call" not in compiled.as_text()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert " %table_update" in text
+        # the kernel reads the tables in the layout XLA holds them in, and
+        # no gradient as large as a table is built (the dense step's
+        # temporaries hold one, f x 32 float32: at 2^20 rows and 131 072
+        # ids the batch's own [nnz, 32] buffers, 64 MiB each with rows
+        # padded to 128 lanes, outweigh it — 205 against 137 MB; the cell's
+        # case below holds the temporaries to their bound)
+        assert not _table_shaped(text, f)
         _names_scopes(compiled, [
-            "loss_and_grad", "optimizer_update", "apply_updates", "csr_gather",
-            "csr_segment_sum", "loss", "transpose(jvp(csr_gather))"]
+            "loss_and_grad", "table_rows", "optimizer_update", "apply_updates",
+            "table_update", "csr_gather", "csr_segment_sum", "loss",
+            "transpose(jvp(csr_gather))"]
             + (["wire_decode/segments"] if kstep > 1 else []))
+        return compiled
     return run
+
+
+def case_train_step_cell(topo):
+    """The benchmark's train cell: 2^24 x 32, batch 4096 x 163 840 — the
+    dense step's 2.02 GiB of temporaries (a zero gradient table) are under
+    half a GiB."""
+    compiled = case_train_step(1, f=1 << 24, nnz=163840)(topo)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 ** 29 < (1 << 24) * D * 4, temp
 
 
 def case_serving_bucket(ragged):
@@ -208,6 +237,8 @@ def case_mesh_step(topo):
     compiled = make_train_step(model, opt, mesh).lower(
         params, opt_state, batch).compile()
     assert "all-reduce" in compiled.as_text()
+    # a meshed step keeps the dense update: no kernel
+    assert "tpu_custom_call" not in compiled.as_text()
     assert _hbm(compiled) < HBM_BYTES      # bytes on each device
 
 
@@ -488,6 +519,7 @@ CASES = {
     "decoder_compact_dict_donated": case_decoder(CTR_META, gathers=1),
     "fm_train_step_kstep1": case_train_step(1),
     "fm_train_step_kstep8": case_train_step(8),
+    "fm_train_step_cell_2e24": case_train_step_cell,
     "serving_bucket_padded": case_serving_bucket(False),
     "serving_bucket_ragged": case_serving_bucket(True),
     "fm_mesh_dp2_mp2_step": case_mesh_step,
