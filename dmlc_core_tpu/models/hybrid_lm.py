@@ -11,8 +11,9 @@ score a row: the mean log-probability of each next token given the tokens
 before it in the document, ``1/(n-1) sum_t log p(x_{t+1} | x_{<=t})``.
 
 The architecture arrives as the model's published ``config.json`` keys, in
-the ``kimi_linear``, the ``deepseek_v3`` or the ``afmoe`` spelling
-(``canonical`` maps the others onto the first, the one the class reads),
+the ``kimi_linear``, the ``deepseek_v3``, the ``afmoe`` or the ``mellum``
+spelling (``canonical`` maps the others onto the first, the one the class
+reads; a key the file leaves out means the plain block below),
 plus two that say what this holder keeps of a layer shared between chips:
 ``held_experts = [lo, hi]`` and ``vocab_rows``.  Pre-norm residual blocks,
 RMSNorm, untied embedding and head:
@@ -33,24 +34,32 @@ RMSNorm, untied embedding and head:
   towards ``1/factor`` of themselves below ``beta_fast`` rotations of the
   original context, the softmax scale times ``mscale^2`` — or, with
   ``mla_use_nope``, ride unrotated;
-* an architecture with ``layer_types`` and no ``kv_lora_rank`` (``afmoe``)
-  uses grouped-query attention at every layer: ``num_attention_heads``
-  query heads on ``num_key_value_heads`` key heads of ``head_dim``, an
-  RMSNorm over each head's columns of ``q`` and of ``k`` (one weight
-  vector each), then on a ``sliding_attention`` layer the rotation of all
+* an architecture with ``layer_types`` and no ``kv_lora_rank`` (``afmoe``,
+  ``mellum``) uses grouped-query attention at every layer:
+  ``num_attention_heads`` query heads on ``num_key_value_heads`` key heads
+  of ``head_dim``; a ``sliding_attention`` layer sees the last
+  ``sliding_window`` keys of the document, the query's own among them, a
+  ``full_attention`` layer the whole document.  A layer rotates all
   ``head_dim`` columns by the token's position in its document (the halves
-  paired, ``(x_i, x_{i + d/2})``, frequencies ``rope_theta^(-2i/d)``) and a
-  view of the last ``sliding_window`` keys of the document, the query's
-  own among them; on a ``full_attention`` layer no rotation and the whole
-  document.  The output is gated a channel, ``o * sigmoid(W_g x)``, before
-  ``W_o``.  Such an architecture also norms every sublayer's *output*
-  before it joins the residual (``x + post_norm(f(norm(x)))``) and, with
-  ``mup_enabled``, scales the embedding row by ``sqrt(hidden_size)``;
-* the first ``first_k_dense_replace`` (``num_dense_layers``) layers have a
-  dense SwiGLU, the rest a
-  sigmoid-routed mixture plus shared experts (``ops.moe``), the choice
-  limited to the ``topk_group`` best of ``num_expert_group`` groups where
-  the architecture has groups.
+  paired, ``(x_i, x_{i + d/2})``) as its kind's entry of ``rope_parameters``
+  says — plain RoPE, frequencies ``rope_theta^(-2i/d)``, or YaRN's blend
+  with cos and sin times ``attention_factor`` — and without that group
+  (``afmoe``) a sliding layer by plain RoPE at ``rope_theta`` and a full
+  layer not at all.  A file in ``afmoe``'s spelling (``AFMOE_BLOCK``) also
+  norms each head's columns of ``q`` and of ``k`` (one weight vector each),
+  gates the output a channel, ``o * sigmoid(W_g x)``, before ``W_o``, and
+  norms every sublayer's *output* before it joins the residual
+  (``x + post_norm(f(norm(x)))``); ``mup_enabled`` scales the embedding
+  row by ``sqrt(hidden_size)``;
+* the first ``first_k_dense_replace`` (``num_dense_layers``, the leading
+  ``dense`` entries of ``mlp_layer_types``) layers have a dense SwiGLU, the
+  rest a mixture (``ops.moe``): sigmoid scores with a bias in the choice
+  (``topk_method`` ``noaux_tc``), or, where the file names no scoring rule,
+  the plain top ``k`` of the softmax over all experts (``greedy``), no bias;
+  plus ``num_shared_experts`` shared experts (none where absent), weights
+  times ``routed_scaling_factor`` (1 where absent), the choice limited to
+  the ``topk_group`` best of ``num_expert_group`` groups where the
+  architecture has groups.
 
 ``dtype`` (bfloat16 as published) is the type of parameters and
 activations; router scores, group sums and the choice, the rotation,
@@ -98,6 +107,13 @@ SPELLINGS_AFMOE = {
     "num_expert_group": "num_expert_groups",
     "topk_group": "num_limited_groups",
 }
+# the grouped-query block's parts that ``afmoe``'s code has and its
+# config.json names no key for: a file in its spelling has all three
+AFMOE_BLOCK = {"attention_output_gate": True, "qk_norm": True,
+               "sublayer_post_norm": True}
+# a router's scores -> the choice they go with: a bias beside sigmoid
+# scores (``noaux_tc``), the plain top k of a softmax (``greedy``)
+ROUTERS = {"sigmoid": "noaux_tc", "softmax": "greedy"}
 
 
 def load_arch(path: str) -> dict:
@@ -105,12 +121,36 @@ def load_arch(path: str) -> dict:
         return json.load(f)
 
 
+def _leading_dense(arch: dict) -> int:
+    """How many layers ``mlp_layer_types`` makes dense: its leading
+    ``dense`` entries, every later one ``sparse``."""
+    layers = int(arch["num_hidden_layers"])
+    kinds = list(arch["mlp_layer_types"])[:layers]
+    dense = next((n for n, kind in enumerate(kinds) if kind != "dense"),
+                 len(kinds))
+    if len(kinds) < layers or set(kinds[dense:]) - {"sparse"}:
+        raise ValueError(f"hybrid_moe_lm computes mlp_layer_types of leading "
+                         f"'dense' layers and 'sparse' ones after them, one "
+                         f"a layer for {layers} layers; the architecture says "
+                         f"{kinds}")
+    return dense
+
+
 def canonical(arch: dict) -> dict:
     """``arch`` with every key of ``SPELLINGS`` and ``SPELLINGS_AFMOE``
-    under the name the class reads; a file that gives two spellings has to
-    give them alike."""
+    under the name the class reads, ``mlp_layer_types`` as
+    ``first_k_dense_replace`` and, in a file in ``afmoe``'s spelling, the
+    ``AFMOE_BLOCK`` keys it does not give; a file that gives two spellings
+    has to give them alike."""
     a = dict(arch)
-    for ours, theirs in (*SPELLINGS.items(), *SPELLINGS_AFMOE.items()):
+    if set(SPELLINGS_AFMOE.values()) & set(a):
+        for key, value in AFMOE_BLOCK.items():
+            a.setdefault(key, value)
+    spelt = [*SPELLINGS.items(), *SPELLINGS_AFMOE.items()]
+    if "mlp_layer_types" in a:
+        a["mlp_layer_types"] = _leading_dense(a)
+        spelt.append(("first_k_dense_replace", "mlp_layer_types"))
+    for ours, theirs in spelt:
         if theirs not in a:
             continue
         value = a.pop(theirs)
@@ -120,17 +160,14 @@ def canonical(arch: dict) -> dict:
     return a
 
 
-def rope_frequencies(d_rope: int, theta: float, scaling) -> Tuple[list, float]:
-    """(the ``d_rope / 2`` rotation frequencies, ``mscale``): plain RoPE
-    without ``scaling``, YaRN's blend and softmax factor with it."""
+def _yarn(d_rope: int, theta: float, scaling) -> Tuple[list, float]:
+    """(YaRN's ``d_rope / 2`` frequencies, its ``factor``): the plain ones
+    blended towards ``1/factor`` of themselves on a ramp from the column
+    that turns ``beta_fast`` times over the original context (floor) to
+    the one that turns ``beta_slow`` times (ceil), as transformers'
+    ``_compute_yarn_parameters`` has it."""
     half = d_rope // 2
     freqs = [theta ** (-2.0 * i / d_rope) for i in range(half)]
-    if not scaling:
-        return freqs, 1.0
-    kind = scaling.get("rope_type", scaling.get("type"))
-    if kind != "yarn":
-        raise ValueError(f"hybrid_moe_lm computes rope_scaling of type "
-                         f"'yarn' only, the architecture says {kind!r}")
     factor = float(scaling["factor"])
     original = float(scaling["original_max_position_embeddings"])
 
@@ -143,7 +180,21 @@ def rope_frequencies(d_rope: int, theta: float, scaling) -> Tuple[list, float]:
              d_rope - 1)
     width = (hi - lo) or 0.001
     ramp = [min(max((i - lo) / width, 0.0), 1.0) for i in range(half)]
-    freqs = [f * (1.0 - r) + f / factor * r for f, r in zip(freqs, ramp)]
+    return [f * (1.0 - r) + f / factor * r for f, r in zip(freqs, ramp)], \
+        factor
+
+
+def rope_frequencies(d_rope: int, theta: float, scaling) -> Tuple[list, float]:
+    """(the ``d_rope / 2`` rotation frequencies, ``mscale``): plain RoPE
+    without ``scaling``, YaRN's blend and softmax factor with it."""
+    if not scaling:
+        half = d_rope // 2
+        return [theta ** (-2.0 * i / d_rope) for i in range(half)], 1.0
+    kind = scaling.get("rope_type", scaling.get("type"))
+    if kind != "yarn":
+        raise ValueError(f"hybrid_moe_lm computes rope_scaling of type "
+                         f"'yarn' only, the architecture says {kind!r}")
+    freqs, factor = _yarn(d_rope, theta, scaling)
 
     def mscale(m):
         return 0.1 * m * math.log(factor) + 1.0 if factor > 1 and m else 1.0
@@ -154,6 +205,24 @@ def rope_frequencies(d_rope: int, theta: float, scaling) -> Tuple[list, float]:
         raise ValueError("hybrid_moe_lm computes rope_scaling with mscale = "
                          "mscale_all_dim only (cos and sin unscaled)")
     return freqs, mscale(float(scaling.get("mscale_all_dim", 0)))
+
+
+def rope_of_kind(d: int, params: dict) -> Tuple[list, float]:
+    """One kind of layer's entry of transformers' ``rope_parameters``: (the
+    ``d / 2`` frequencies, the factor cos and sin are scaled by) — plain
+    RoPE for ``rope_type`` ``default``, YaRN's blend for ``yarn`` with
+    ``attention_factor`` (``0.1 ln(factor) + 1`` where absent)."""
+    kind = params.get("rope_type", "default")
+    theta = float(params["rope_theta"])
+    if kind == "default":
+        return rope_frequencies(d, theta, None)
+    if kind != "yarn" or {"mscale", "mscale_all_dim"} & set(params):
+        raise ValueError(f"hybrid_moe_lm computes rope_parameters of type "
+                         f"'default' or 'yarn' (no mscale) only, the "
+                         f"architecture says {params!r}")
+    freqs, factor = _yarn(d, theta, params)
+    return freqs, float(params.get("attention_factor",
+                                   0.1 * math.log(factor) + 1.0))
 
 
 def _rotate(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -234,14 +303,28 @@ class HybridMoELM:
 
     def __init__(self, arch: dict):
         a = canonical(arch)
-        need = {"moe_router_activation_func": "sigmoid",
-                "moe_renormalize": True, "topk_method": "noaux_tc",
-                "tie_word_embeddings": False, "attention_bias": False,
-                "hidden_act": "silu", "moe_layer_freq": 1}
+        # no scoring rule: the plain top k of a softmax; a named one keeps
+        # the choice it always had here, a bias beside its scores
+        named = "moe_router_activation_func" in a
+        self.scoring = a.get("moe_router_activation_func", "softmax")
+        if self.scoring not in ROUTERS:
+            raise ValueError(f"hybrid_moe_lm computes router scores of "
+                             f"{sorted(ROUTERS)} only, the architecture says "
+                             f"{self.scoring!r}")
+        choice = a.get("topk_method", "noaux_tc" if named else "greedy")
+        if choice != ROUTERS[self.scoring]:
+            raise ValueError(f"hybrid_moe_lm computes {self.scoring} scores "
+                             f"with topk_method={ROUTERS[self.scoring]!r} "
+                             f"only, the architecture says {choice!r}")
+        need = {"moe_renormalize": True, "tie_word_embeddings": False,
+                "attention_bias": False, "hidden_act": "silu",
+                "moe_layer_freq": 1}
         for key, want in need.items():
             if a.get(key, want) != want:
                 raise ValueError(f"hybrid_moe_lm computes {key}={want!r} "
                                  f"only, the architecture says {a[key]!r}")
+        # the bias that joins the scores in the choice
+        self.router_bias = choice == "noaux_tc"
         self.dtype = jnp.dtype(a.get("dtype", "bfloat16"))
         self.hidden = int(a["hidden_size"])
         self.layers = int(a["num_hidden_layers"])
@@ -258,6 +341,9 @@ class HybridMoELM:
         # what the layers outside ``kda_layers`` mix tokens with
         self.attention = "gqa" if "layer_types" in a \
             and "kv_lora_rank" not in a else "mla"
+        # the grouped-query block's parts that only some files have
+        self.gate = bool(a.get("attention_output_gate"))
+        self.qk_norm = bool(a.get("qk_norm"))
         if self.attention == "gqa":
             self._init_gqa(a, theta)
         else:
@@ -273,15 +359,15 @@ class HybridMoELM:
             self.attn_scale = (self.d_nope + self.d_rope) ** -0.5 \
                 * mscale ** 2
         # the norm on a sublayer's output and the embedding's multiplier
-        self.sandwich = self.attention == "gqa"
+        self.sandwich = bool(a.get("sublayer_post_norm"))
         self.embed_scale = self.hidden ** 0.5 if a.get("mup_enabled") else 1.0
         self.dense_layers = int(a["first_k_dense_replace"])
         self.dense_width = int(a["intermediate_size"])
         self.expert_width = int(a["moe_intermediate_size"])
         self.experts = int(a["num_experts"])
         self.top_k = int(a["num_experts_per_token"])
-        self.shared = int(a["num_shared_experts"])
-        self.route_scale = float(a["routed_scaling_factor"])
+        self.shared = int(a.get("num_shared_experts", 0))
+        self.route_scale = float(a.get("routed_scaling_factor", 1.0))
         self.groups = int(a.get("num_expert_group", 1))
         self.groups_kept = int(a.get("topk_group", 1))
         per_group = self.experts // max(self.groups, 1)
@@ -321,7 +407,23 @@ class HybridMoELM:
             raise ValueError("hybrid_moe_lm computes rope_scaling=None only "
                              "under grouped-query attention, the "
                              f"architecture says {a['rope_scaling']!r}")
-        self.rope_freqs, _ = rope_frequencies(self.head_dim, theta, None)
+        # a kind of layer -> (frequencies, the factor on cos and sin, the
+        # scope a trace reads), or None where that kind rotates nothing
+        by_kind = a.get("rope_parameters")
+        if by_kind is None:
+            self.rotations = {known[0]: (*rope_frequencies(
+                self.head_dim, theta, None), "gqa/rope"), known[1]: None}
+        else:
+            if set(types) - set(by_kind):
+                raise ValueError(f"rope_parameters has to give each of "
+                                 f"{sorted(set(types))} its own entry, it "
+                                 f"gives {sorted(by_kind)}")
+            self.rotations = {kind: (*rope_of_kind(self.head_dim, rope), (
+                "gqa/rope_yarn" if rope.get("rope_type") == "yarn"
+                else "gqa/rope")) for kind, rope in by_kind.items()
+                if kind in types}
+        self.yarn_layers = sum(turn is not None and turn[2] == "gqa/rope_yarn"
+                               for turn in map(self.rotations.get, types))
         self.attn_scale = self.head_dim ** -0.5
 
     def mixer(self, layer: int) -> str:
@@ -344,8 +446,11 @@ class HybridMoELM:
         elif self.attention == "gqa":
             d = self.head_dim
             n, nkv = self.heads * d, self.kv_heads * d
-            s.update(wq=(h, n), wk=(h, nkv), wv=(h, nkv), w_gate=(h, n),
-                     q_head_norm=(d,), k_head_norm=(d,), wo=(n, h))
+            s.update(wq=(h, n), wk=(h, nkv), wv=(h, nkv), wo=(n, h))
+            if self.gate:
+                s.update(w_gate=(h, n))
+            if self.qk_norm:
+                s.update(q_head_norm=(d,), k_head_norm=(d,))
         else:
             dq = self.heads * (self.d_nope + self.d_rope)
             if self.q_rank:
@@ -365,10 +470,13 @@ class HybridMoELM:
                      w_down=(self.dense_width, h))
         else:
             g, w = self.held[1] - self.held[0], self.expert_width
-            s.update(router=(h, self.experts), router_bias=(self.experts,),
-                     e_gu=(g, h, 2 * w), e_down=(g, w, h),
-                     s_gu=(h, 2 * w * self.shared),
-                     s_down=(w * self.shared, h))
+            s.update(router=(h, self.experts), e_gu=(g, h, 2 * w),
+                     e_down=(g, w, h))
+            if self.router_bias:
+                s.update(router_bias=(self.experts,))
+            if self.shared:
+                s.update(s_gu=(h, 2 * w * self.shared),
+                         s_down=(w * self.shared, h))
         return s
 
     def shapes(self) -> Dict[str, object]:
@@ -434,16 +542,16 @@ class HybridMoELM:
             o = _rms(o, p["out_norm"], self.eps) * gate
             return _mm(o.astype(x.dtype).reshape(t, nh * d), p["wo"]), fused
 
-    def _turn(self, pos):
-        """(cos, sin) ``[T, d_rope / 2]`` of every token's rotation, or None
-        where the architecture rotates nothing: the same for every layer
-        that rotates."""
-        if self.rope_freqs is None:
-            return None
-        with jax.named_scope(f"{self.attention}/rope"):
+    def _turn(self, pos, freqs, factor=1.0, scope="mla/rope"):
+        """(cos, sin) ``[T, len(freqs)]`` of every token's rotation by
+        ``freqs``, both times ``factor``, computed under ``scope``."""
+        with jax.named_scope(scope):
             angle = pos.astype(F32)[:, None] * jnp.asarray(
-                self.rope_freqs, F32)[None, :]
-            return jnp.cos(angle), jnp.sin(angle)
+                freqs, F32)[None, :]
+            cos, sin = jnp.cos(angle), jnp.sin(angle)
+            if factor != 1.0:
+                cos, sin = cos * factor, sin * factor
+            return cos, sin
 
     def _mla(self, p, x, seg, doc_start, turn):
         t = x.shape[0]
@@ -482,32 +590,35 @@ class HybridMoELM:
 
     def _gqa(self, p, x, seg, first_key, turn):
         """``first_key [T]`` the first key each token sees in this layer;
-        ``turn`` None on a layer that rotates nothing."""
+        ``turn`` (cos, sin, scope) of the layer's kind, None on a layer that
+        rotates nothing."""
         t = x.shape[0]
         nh, nkv, d = self.heads, self.kv_heads, self.head_dim
         with jax.named_scope("gqa/project"):
             q = _mm(x, p["wq"]).reshape(t, nh, d)
             k = _mm(x, p["wk"]).reshape(t, nkv, d)
             v = _mm(x, p["wv"]).reshape(t, nkv, d)
-            gate = _mm(x, p["w_gate"])
+            gate = _mm(x, p["w_gate"]) if self.gate else None
         with jax.named_scope("gqa/qk_norm"):
-            q = _rms(q, p["q_head_norm"], self.eps)
-            k = _rms(k, p["k_head_norm"], self.eps)
+            if self.qk_norm:
+                q = _rms(q, p["q_head_norm"], self.eps)
+                k = _rms(k, p["k_head_norm"], self.eps)
             if turn is None:
                 q = (q.astype(F32) * self.attn_scale).astype(x.dtype)
         if turn is not None:
-            with jax.named_scope("gqa/rope"):
+            with jax.named_scope(turn[2]):
                 cos, sin = turn[0][:, None], turn[1][:, None]
                 q = (_rotate_halves(q, cos, sin)
                      * self.attn_scale).astype(x.dtype)
                 k = _rotate_halves(k, cos, sin).astype(x.dtype)
         with jax.named_scope("gqa/attention"):
             o, fused = doc_causal_attention_counted(q, k, v, seg, first_key)
-        with jax.named_scope("gqa/gate"):
-            o = (o.reshape(t, nh * d)
-                 * jax.nn.sigmoid(gate.astype(F32))).astype(x.dtype)
+        o = o.reshape(t, nh * d)
+        if self.gate:
+            with jax.named_scope("gqa/gate"):
+                o = o * jax.nn.sigmoid(gate.astype(F32))
         with jax.named_scope("gqa/out"):
-            return _mm(o, p["wo"]), fused
+            return _mm(o.astype(x.dtype), p["wo"]), fused
 
     def _post(self, p, name, out):
         """A sublayer's output as it joins the residual: through its own
@@ -519,11 +630,14 @@ class HybridMoELM:
 
     def _moe(self, p, x, live):
         with jax.named_scope("moe/router"):
-            chosen, weights = route(x, p["router"], p["router_bias"],
+            chosen, weights = route(x, p["router"], p.get("router_bias"),
                                     self.top_k, self.route_scale,
-                                    self.groups, self.groups_kept)
+                                    self.groups, self.groups_kept,
+                                    self.scoring)
         routed, counters = held_experts_sum(
             x, chosen, weights, live, p["e_gu"], p["e_down"], self.held)
+        if not self.shared:
+            return routed, counters, chosen
         with jax.named_scope("moe/shared"):
             shared = swiglu(x, p["s_gu"], p["s_down"])
         return routed + shared, counters, chosen
@@ -539,7 +653,11 @@ class HybridMoELM:
         # a token's document start; padding is one document behind the last
         doc_start = row_ptr[jnp.minimum(seg, rows)]
         pos = jnp.arange(t, dtype=jnp.int32) - doc_start
-        turn = self._turn(pos)
+        if self.attention == "gqa":     # each kind of layer its own
+            turns = {kind: rot and (*self._turn(pos, *rot), rot[2])
+                     for kind, rot in self.rotations.items()}
+        else:
+            turn = self.rope_freqs and self._turn(pos, self.rope_freqs)
         with jax.named_scope("lm_embed"):
             x = params["embed"][ids]
             if self.embed_scale != 1.0:
@@ -555,6 +673,8 @@ class HybridMoELM:
                 doc_start, jnp.arange(t, dtype=jnp.int32) - (self.window - 1))
             counters["attn.key_blocks_full"] = walk_blocks(doc_start)
             counters["attn.key_blocks_window"] = walk_blocks(in_window)
+            if self.yarn_layers:
+                counters["gqa.yarn_layers"] = jnp.int32(self.yarn_layers)
         choices = {}
         for layer in range(1, self.layers + 1):
             name = f"layer_{layer:02d}"
@@ -566,9 +686,11 @@ class HybridMoELM:
             elif mixer == "mla":
                 out, fused = self._mla(p, y, seg, doc_start, turn)
             elif layer in self.sliding:
-                out, fused = self._gqa(p, y, seg, in_window, turn)
+                out, fused = self._gqa(p, y, seg, in_window,
+                                       turns["sliding_attention"])
             else:
-                out, fused = self._gqa(p, y, seg, doc_start, None)
+                out, fused = self._gqa(p, y, seg, doc_start,
+                                       turns["full_attention"])
             counters[f"{mixer}.fused_layers"] += fused
             x = x + self._post(p, "post_norm1", out)
             y = _rms(x, p["norm2"], self.eps)
@@ -594,7 +716,8 @@ class HybridMoELM:
         their experts' rows by the combine kernel; under grouped-query
         attention ``attn.key_blocks_full`` and ``attn.key_blocks_window``,
         the key blocks one full and one sliding layer's walk visits for this
-        batch (``ops.doc_attention.walk_blocks``)."""
+        batch (``ops.doc_attention.walk_blocks``), and where layers rotate
+        by YaRN ``gqa.yarn_layers``, how many."""
         hid, counters, _ = self._hidden(params, batch)
         scores, counts = doc_scores(
             hid, batch, lambda h: jnp.dot(h, params["head"],

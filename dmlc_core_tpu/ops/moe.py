@@ -1,7 +1,8 @@
-"""A sigmoid-routed mixture of SwiGLU experts, as one holder of a slice of
-the experts computes it.
+"""A sigmoid- or softmax-routed mixture of SwiGLU experts, as one holder of
+a slice of the experts computes it.
 
-The router scores all ``E`` experts; a token takes the ``k`` experts with the
+The router scores all ``E`` experts (a sigmoid a logit, or the softmax over
+all of them); a token takes the ``k`` experts with the
 largest ``score + bias`` — where the experts stand in groups, only among the
 groups whose two best ``score + bias`` sum highest — and weighs them by
 their scores, renormalised over the chosen and scaled.  The layer is told
@@ -63,17 +64,22 @@ def swiglu(x: jax.Array, w_gu: jax.Array, w_down: jax.Array) -> jax.Array:
     return jnp.dot(hid, w_down, preferred_element_type=f32).astype(x.dtype)
 
 
-def route(x: jax.Array, router: jax.Array, bias: jax.Array, k: int,
-          scale: float, groups: int = 1, groups_kept: int = 1
-          ) -> Tuple[jax.Array, jax.Array]:
+def route(x: jax.Array, router: jax.Array, bias, k: int,
+          scale: float, groups: int = 1, groups_kept: int = 1,
+          scoring: str = "sigmoid") -> Tuple[jax.Array, jax.Array]:
     """(chosen experts ``[T, k]`` int32, their weights ``[T, k]`` float32).
-    With ``groups > 1`` the experts stand in that many equal groups in
-    order, a group ranks by the sum of its two largest ``score + bias``,
-    and the choice is made inside the ``groups_kept`` best.  Scores, group
-    sums and the choice are float32."""
+    A score is the sigmoid of the expert's logit, or with ``scoring``
+    ``"softmax"`` its share of the softmax over all ``E`` logits; ``bias``
+    (None for none) joins the scores in the choice only.  With
+    ``groups > 1`` the experts stand in that many equal groups in order, a
+    group ranks by the sum of its two largest ``score + bias``, and the
+    choice is made inside the ``groups_kept`` best.  Scores, group sums and
+    the choice are float32."""
     f32 = jnp.float32
-    s = jax.nn.sigmoid(jnp.dot(x, router, preferred_element_type=f32))
-    u = s + bias.astype(f32)
+    logits = jnp.dot(x, router, preferred_element_type=f32)
+    s = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
+        else jax.nn.sigmoid(logits)
+    u = s if bias is None else s + bias.astype(f32)
     if groups > 1:
         t, e = u.shape
         by_group = u.reshape(t, groups, e // groups)

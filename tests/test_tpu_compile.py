@@ -242,6 +242,31 @@ def case_mesh_step(topo):
     assert _hbm(compiled) < HBM_BYTES      # bytes on each device
 
 
+# the document scorers' programs before the class learnt the ``mellum``
+# type (per-kind rotation, softmax routing, optional block parts), by
+# ``_program_digest``: those three files have to compile to the same
+# programs, instruction for instruction
+PROGRAMS = {"kimi_linear_48b_ep2_l5": "a589a989ec647723",
+            "gigachat31_702b_ep16_l5": "e963a16bcd630264",
+            "trinity_large_400b_ep8_l5": "e5ec9287337ee361"}
+
+
+def _program_digest(compiled) -> str:
+    """The compiled program's text without what says where it came from:
+    the tables of source files and stack frames, each instruction's
+    ``metadata`` and the custom calls' ``backend_config`` (a Pallas
+    kernel's serialised body carries its source lines); what is left is
+    every instruction, shape, layout and schedule."""
+    import hashlib
+    import re
+    head, _, body = compiled.as_text().partition("\n")
+    text = head + "\n" + body[re.search(r"^(%|ENTRY)", body, re.M).start():]
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r', backend_config=(\{.*?\}|"(?:[^"\\]|\\.)*")(?=[,\n])',
+                  "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
 def _attention_is_the_kernel(text, model, mixer="mla"):
     """Every ``mixer`` layer's attention is one ``doc_attention`` Pallas
     kernel, selected at lowering, under the scope a trace reads, and no
@@ -300,6 +325,7 @@ def case_document_scorer(topo):
     compiled = jax.jit(model.forward_counted).lower(params, batch).compile()
     assert sum(np.prod(leaf.shape) for leaf in jax.tree.leaves(params)) \
         == 4_282_936_192
+    assert _program_digest(compiled) == PROGRAMS["kimi_linear_48b_ep2_l5"]
     # 15.41 GB with the temporaries of the jnp chunk (PR 30); the kernel
     # keeps them in VMEM: 12.18 GB (PR 36, and with attention's kernel)
     assert _hbm(compiled) < 12_200_000_000 < 15_410_000_000 < HBM_BYTES
@@ -359,6 +385,7 @@ def case_document_scorer_dsv3(topo):
     compiled = jax.jit(model.forward_counted).lower(params, batch).compile()
     assert sum(np.prod(leaf.shape) for leaf in jax.tree.leaves(params)) \
         == 4_291_256_320
+    assert _program_digest(compiled) == PROGRAMS["gigachat31_702b_ep16_l5"]
     # 8.583 GB of arguments + 3.177 GB of temporaries (PR 37); 3.008 with
     # attention's kernel (PR 38)
     assert _hbm(compiled) < 11_760_000_000 < 12_000_000_000 < HBM_BYTES
@@ -406,6 +433,8 @@ def case_document_scorer_afmoe(topo):
     compiled = jax.jit(model.forward_counted).lower(params, batch).compile()
     assert sum(np.prod(leaf.shape) for leaf in jax.tree.leaves(params)) \
         == 4_321_903_872
+    assert _program_digest(compiled) \
+        == PROGRAMS["trinity_large_400b_ep8_l5"]
     # 8.644 GB of arguments + the temporaries (PR 39; ISSUE 39 expected
     # 12-13.5 GB in all)
     assert _hbm(compiled) < 13_500_000_000 < HBM_BYTES
@@ -435,6 +464,54 @@ def case_document_scorer_afmoe(topo):
         "gqa/attention", "gqa/gate", "gqa/out", "post_norm", "moe/router",
         "moe/dispatch", "moe/experts", "moe/shared", "moe/combine",
         "dense_mlp", "lm_head"])
+
+
+def case_document_scorer_mellum(topo):
+    """The same class on the ``mellum``-type configuration at its timed
+    sizes (eight layers at published widths, all 64 experts of each, the
+    whole vocabulary, 8 documents in 32 768 tokens, bfloat16): it fits the
+    chip beside its 7.59 GB of parameters; every layer's attention is the
+    kernel on grouped keys and every mixture layer's combine the kernel; the
+    full layers turn under ``gqa/rope_yarn``, the sliding ones under
+    ``gqa/rope``; no gate, QK-norm, post-norm or shared expert exists, and
+    neither the ``[T, T]`` scores nor the ``[T, V]`` logits exist whole."""
+    import re
+
+    from dmlc_core_tpu.models.hybrid_lm import HybridMoELM, load_arch
+    here = os.path.dirname(os.path.abspath(__file__))
+    model = HybridMoELM(load_arch(os.path.join(
+        here, "..", "benchmarks", "chip", "configs",
+        "mellum2_12b_a2.5b_l8.json")))
+    one = SingleDeviceSharding(topo.devices[0])
+    S, i32 = jax.ShapeDtypeStruct, jnp.int32
+    t, rows = 32768, 8
+    params = jax.tree.map(lambda shape: S(shape, model.dtype, sharding=one),
+                          model.shapes(),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    batch = _on(one, dict(_batch(rows, t), row_ptr=S((rows + 1,), i32)))
+    compiled = jax.jit(model.forward_counted).lower(params, batch).compile()
+    assert sum(np.prod(leaf.shape) for leaf in jax.tree.leaves(params)) \
+        == 3_794_966_784
+    # 7.59 GB of arguments + the temporaries (reckoned at 10.5-11.5 GB in
+    # all before the first compile)
+    assert _hbm(compiled) < 12_000_000_000 < HBM_BYTES
+    text = compiled.as_text()
+    assert "ragged-dot" in text                  # experts: grouped products
+    _attention_is_the_kernel(text, model, "gqa")
+    _combine_is_the_kernel(text, model, t)
+    top = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]", text,
+                     re.M)
+    for dims in top:
+        d = [int(x) for x in dims.split(",")]
+        assert not (t in d and model.vocab in d), dims     # [T, V]
+        assert d.count(t) < 2, dims                        # [T, T]
+    op_names = " ".join(re.findall(r'op_name="([^"]*)"', text))
+    for absent in ("gqa/gate", "gqa/qk_norm/", "post_norm", "moe/shared"):
+        assert absent not in op_names, absent
+    _names_scopes(compiled, [
+        "lm_embed", "gqa/project", "gqa/rope", "gqa/rope_yarn",
+        "gqa/attention", "gqa/out", "moe/router", "moe/dispatch",
+        "moe/experts", "moe/combine", "lm_head"])
 
 
 def case_document_scorer_sambay(topo):
@@ -527,6 +604,7 @@ CASES = {
     "document_scorer_dsv3_forward": case_document_scorer_dsv3,
     "document_scorer_afmoe_forward": case_document_scorer_afmoe,
     "document_scorer_sambay_forward": case_document_scorer_sambay,
+    "document_scorer_mellum_forward": case_document_scorer_mellum,
 }
 
 
