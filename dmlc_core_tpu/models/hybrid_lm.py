@@ -635,7 +635,8 @@ class HybridMoELM:
                                     self.groups, self.groups_kept,
                                     self.scoring)
         routed, counters = held_experts_sum(
-            x, chosen, weights, live, p["e_gu"], p["e_down"], self.held)
+            x, chosen, weights, live, p["e_gu"], p["e_down"], self.held,
+            self.experts)
         if not self.shared:
             return routed, counters, chosen
         with jax.named_scope("moe/shared"):
@@ -701,6 +702,10 @@ class HybridMoELM:
                 out, counters[name], choices[name] = self._moe(p, y, live)
                 counters["moe.fused_combines"] += counters[name].pop(
                     "fused_combine")
+                if "gather_combine" in counters[name]:
+                    counters["moe.gather_combines"] = counters.get(
+                        "moe.gather_combines", 0) + counters[name].pop(
+                            "gather_combine")
             x = x + self._post(p, "post_norm2", out)
         return _rms(x, params["final_norm"], self.eps), counters, choices
 
@@ -713,11 +718,14 @@ class HybridMoELM:
         ``gqa.fused_layers``, how many KDA layers of this program took the
         chunk kernel and how many layers of either attention the attention
         kernel, and ``moe.fused_combines``, how many mixture layers added
-        their experts' rows by the combine kernel; under grouped-query
-        attention ``attn.key_blocks_full`` and ``attn.key_blocks_window``,
-        the key blocks one full and one sliding layer's walk visits for this
-        batch (``ops.doc_attention.walk_blocks``), and where layers rotate
-        by YaRN ``gqa.yarn_layers``, how many."""
+        their experts' rows by the combine kernel; where a layer holds
+        every expert ``moe.gather_combines``, how many mixture layers
+        summed their rows by token (``ops.moe.held_experts_sum``); under
+        grouped-query attention ``attn.key_blocks_full`` and
+        ``attn.key_blocks_window``, the key blocks one full and one sliding
+        layer's walk visits for this batch
+        (``ops.doc_attention.walk_blocks``), and where layers rotate by
+        YaRN ``gqa.yarn_layers``, how many."""
         hid, counters, _ = self._hidden(params, batch)
         scores, counts = doc_scores(
             hid, batch, lambda h: jnp.dot(h, params["head"],

@@ -30,6 +30,17 @@ else the same statement is a scatter.  On a v5e the kernel adds a row for
 0.04-0.08 us where XLA's scatter took 0.26 us a row of 2304 and ~1 us of
 7168 (PERF.md, PR 37 and 40); that scatter was why a holder of half the
 experts used to sort all ``T * k`` rows into one buffer instead.
+
+A layer that holds every expert serves all ``T * k`` rows anyway, and
+each live token has exactly ``k`` of them, so it sums by token instead
+(PR 46): the loop stores each block's unweighted outputs, in the
+activations' type, into a buffer of every row in sorted order, and after
+the loop one ``moe_combine`` kernel reads each token's rows once and
+writes its sum once, ``[T, H]`` in the activations' type — no float32 sums
+and no read-modify-write a row.  The products and their order are the
+blocked scatter's, so the sums are too, to the last bit.  A holder of part
+of the experts keeps the blocked scatter: a ``T * k``-row buffer would
+cost it 1.2-1.9 GB for rows it never serves.
 """
 
 from __future__ import annotations
@@ -222,15 +233,172 @@ def _combine(out, y, token, held):
         tpu=lambda *a: (_combine_kernel(*a), jnp.int32(1)))
 
 
-def _blocked_sum(x, order, loads, w, e_gu, e_down, block):
-    """(the sum ``[T, H]``, rows gathered, whether the combine was the
-    kernel): ``block`` sorted rows at a time, for as many blocks as the
-    held assignments fill."""
-    t, k = w.shape
-    h = x.shape[1]
+def _row_words(ys, lanes):
+    """Rows ``[n, H]`` as the by-token combine reads them, ``[n * W,
+    lanes]`` uint32: a row's columns in chunks of ``lanes``, one chunk a
+    word row of a 32-bit type, two of a 16-bit one (the even chunk in the
+    low halves, a zero chunk behind an odd count), a row's ``W`` word rows
+    one behind the other.  A row is then one run of 32-bit sublanes, which
+    Mosaic copies whole from any row; a bfloat16 ``[n, H]`` pads nothing."""
+    n, h = ys.shape
+    c = h // lanes
+    if ys.dtype.itemsize == 4:
+        return lax.bitcast_convert_type(ys, jnp.uint32).reshape(n * c, lanes)
+    half = lax.bitcast_convert_type(ys, jnp.uint16).astype(
+        jnp.uint32).reshape(n, c, lanes)
+    half = jnp.pad(half, ((0, 0), (0, c % 2), (0, 0)))
+    return (half[:, 0::2] | half[:, 1::2] << 16).reshape(-1, lanes)
+
+
+def _word_values(words, dtype):
+    """The float32 values ``words`` hold (``_row_words``), chunk by chunk:
+    one array of a 32-bit ``dtype``, two of a 16-bit one, low half first."""
     f32 = jnp.float32
+    if jnp.dtype(dtype).itemsize == 4:
+        return [lax.bitcast_convert_type(words, dtype).astype(f32)]
+    if dtype == jnp.bfloat16:                  # a float32's upper half
+        return [lax.bitcast_convert_type(words << 16, f32),
+                lax.bitcast_convert_type(words & jnp.uint32(0xFFFF0000), f32)]
+    return [lax.bitcast_convert_type((words >> s).astype(jnp.uint16),
+                                     dtype).astype(f32) for s in (0, 16)]
+
+
+# the combine by token: tokens a kernel step sums (every copy of their
+# rows in flight at once), tokens a group of its adds, copies a turn of
+# the loop that starts them (by cost on a v5e, a layer's 262 144 rows at
+# the Mellum cell's shape: 14.3 ms with a wait of its own a copy, 10.5 with
+# one wait for all, 7.1 with eight starts a turn; PERF.md, PR 46)
+_STEP_TOKENS, _GROUP, _STARTS = 256, 32, 8
+
+
+def _token_sum_kernel_body(pos_ref, pos_v, w_ref, words_ref, out_ref, rows,
+                           prods, copied, *, c):
+    """A kernel step sums its tokens' rows.  It copies row ``(t, j)`` from
+    the words in HBM to ``rows``' ``t * k + j``-th ``W`` word rows — an
+    assignment no held expert took copies row 0, masked below — and awaits
+    the bytes of all of them at once; then, a group of tokens at a time,
+    weighs every row into ``prods`` (a strided load takes one word row of
+    the group's ``j``-th rows) and adds a token's weighted rows in
+    ascending ``j`` — its experts' order, as the blocked scatter adds them
+    — and writes the sums once, in the activations' type.  Products and
+    sums are two passes, so no backend contracts them into one rounding."""
+    tokens, k = pos_v.shape
+    n_words = rows.shape[0] // (tokens * k)
+    group = prods.shape[2]
+    per = prods.shape[1] // n_words
+
+    def starts(b, _):
+        for r in range(_STARTS):
+            i = b * _STARTS + r
+            at = jnp.maximum(pos_ref[i], 0) * n_words
+            pltpu.make_async_copy(words_ref.at[pl.ds(at, n_words)],
+                                  rows.at[pl.ds(i * n_words, n_words)],
+                                  copied).start()
+
+    lax.fori_loop(0, tokens * k // _STARTS, starts, None)
+    pltpu.make_async_copy(rows, rows, copied).wait()     # every copy's bytes
+
+    def one_group(g, _):
+        first = pl.multiple_of(g * group, group)
+        span = pl.ds(first, group)
+        w, served = w_ref[span, :], pos_v[span, :] >= 0
+
+        def weigh(word, _):
+            for j in range(k):
+                at = (first * k + j) * n_words + word
+                values = _word_values(
+                    rows[pl.ds(at, group, stride=k * n_words), :],
+                    out_ref.dtype)
+                for q, v in enumerate(values):
+                    prods[j, word * per + q] = jnp.where(
+                        served[:, j:j + 1], v * w[:, j:j + 1], 0.0)
+
+        lax.fori_loop(0, n_words, weigh, None)
+        for ch in range(c):
+            acc = jnp.zeros(prods.shape[2:], jnp.float32)
+            for j in range(k):
+                acc = acc + prods[j, ch]
+            out_ref[span, pl.ds(ch * acc.shape[1], acc.shape[1])] = \
+                acc.astype(out_ref.dtype)
+
+    lax.fori_loop(0, tokens // group, one_group, None)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "c", "interpret"))
+def _token_sum_kernel(words, pos, w, dtype, c, interpret=False):
+    """``_token_sum`` through the ``moe_combine`` kernel.  Jitted so that a
+    program's layers share one trace of the kernel's text."""
+    lanes = words.shape[1]
+    t, k = pos.shape
+    per = 4 // jnp.dtype(dtype).itemsize
+    n_words = -(-c // per)
+    tokens = min(_STEP_TOKENS, -(-t // _GROUP) * _GROUP)
+    while tokens > _GROUP and k * n_words * tokens * lanes * 4 \
+            > _VMEM_BUDGET // 2:
+        tokens //= 2
+    steps = -(-t // tokens)
+    # the last step's tokens past ``t`` take no expert and write nowhere
+    pos = jnp.pad(pos, ((0, steps * tokens - t), (0, 0)), constant_values=-1)
+    w = jnp.pad(w, ((0, steps * tokens - t), (0, 0)))
+    return pl.pallas_call(
+        functools.partial(_token_sum_kernel_body, c=c),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((tokens * k,), lambda i: (i,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((tokens, k), lambda i: (i, 0)),
+                  pl.BlockSpec((tokens, k), lambda i: (i, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((tokens, c * lanes), lambda i: (i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((tokens * k * n_words, lanes), jnp.uint32),
+            pltpu.VMEM((k, n_words * per, _GROUP, lanes), jnp.float32),
+            pltpu.SemaphoreType.DMA(())],
+        out_shape=jax.ShapeDtypeStruct((t, c * lanes), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_BUDGET + 8 * 2 ** 20),
+        interpret=interpret, name="moe_combine",
+    )(pos.reshape(-1), pos, w, words)
+
+
+def _token_sum(words, pos, w, dtype, c):
+    """(``sum_j w[t, j] * row(pos[t, j])`` ``[T, c * lanes]`` in ``dtype``,
+    whether the kernel did it): ``words [N * W, lanes]`` the rows
+    (``_row_words``), ``pos [T, k]`` a token's rows ascending, -1 for none.
+    A token's weighted rows are added to a float32 zero in ascending
+    ``j``.  On a TPU the ``moe_combine`` kernel where ``lanes`` is a
+    register's 128 and the type bfloat16 or float32, everywhere else a
+    gather (decided at lowering)."""
+    t, k = pos.shape
+    lanes = words.shape[-1]
+
+    def gather(words, pos, w):
+        rows = words.reshape(-1, -(-c // (4 // jnp.dtype(dtype).itemsize)),
+                             lanes)[jnp.maximum(pos, 0)]
+        v = jnp.stack(_word_values(rows, dtype), 3).reshape(
+            t, k, -1, lanes)[:, :, :c]
+        y = jnp.where((pos >= 0)[:, :, None, None],
+                      v * w[:, :, None, None], 0.0)
+        # the weighted rows whole before the adds, as the kernel's passes
+        out = lax.fori_loop(0, k, lambda j, acc: acc + y[:, j],
+                            jnp.zeros((t, c, lanes), jnp.float32))
+        return out.reshape(t, c * lanes).astype(dtype), jnp.int32(0)
+
+    if lanes != 128 or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
+        return gather(words, pos, w)
+    return lax.platform_dependent(
+        words, pos, w, default=gather,
+        tpu=lambda *a: (_token_sum_kernel(*a, dtype=dtype, c=c),
+                        jnp.int32(1)))
+
+
+def _blocked(x, order, loads, w, e_gu, e_down, block, init, take):
+    """(what ``take`` leaves, rows gathered): ``block`` sorted rows at a
+    time, for as many blocks as the held assignments fill, each block's
+    expert outputs handed to ``take(carry, ys, rows, token, first,
+    held_rows, flat_w)`` with the loop's carry, ``init()`` at the start."""
+    t, k = w.shape
     block = min(block, t * k)
-    lanes = 128 if h % 128 == 0 else h
     with jax.named_scope("moe/dispatch"):
         # room for a whole block behind the last assignment
         order = jnp.pad(order.astype(jnp.int32), (0, block))
@@ -239,7 +407,6 @@ def _blocked_sum(x, order, loads, w, e_gu, e_down, block):
         flat_w = w.reshape(-1)
 
     def one_block(b, carry):
-        out, fused = carry
         with jax.named_scope("moe/dispatch"):
             first = b * block
             rows = jax.lax.dynamic_slice(order, (first,), (block,))
@@ -251,32 +418,92 @@ def _blocked_sum(x, order, loads, w, e_gu, e_down, block):
         with jax.named_scope("moe/experts"):
             ys = _swiglu_experts(xs, e_gu, e_down, sizes)
         with jax.named_scope("moe/combine"):
-            # the weighted rows, each laid out as its token's sum is
-            y = ys.astype(f32) * flat_w[rows][:, None]
-            out, took = _combine(out, y.reshape(block, -1, lanes), token,
-                                 held_rows - first)
-        return out, jnp.maximum(fused, took)
+            return take(carry, ys, rows, token, first, held_rows, flat_w)
 
     blocks = (held_rows + block - 1) // block
-    out, fused = jax.lax.fori_loop(
-        0, blocks, one_block,
-        (jnp.zeros((t, h // lanes, lanes), f32), jnp.int32(0)))
-    return out.reshape(t, h).astype(x.dtype), blocks * block, fused
+    return jax.lax.fori_loop(0, blocks, one_block, init()), blocks * block
+
+
+def _blocked_sum(x, order, loads, w, e_gu, e_down, block):
+    """(the sum ``[T, H]``, rows gathered, whether the combine was the
+    kernel): each block's weighted rows added to their tokens' float32
+    sums as the block leaves its experts."""
+    t = w.shape[0]
+    h = x.shape[1]
+    f32 = jnp.float32
+    lanes = 128 if h % 128 == 0 else h
+
+    def add(carry, ys, rows, token, first, held_rows, flat_w):
+        out, fused = carry
+        # the weighted rows, each laid out as its token's sum is
+        y = ys.astype(f32) * flat_w[rows][:, None]
+        out, took = _combine(out, y.reshape(y.shape[0], -1, lanes), token,
+                             held_rows - first)
+        return out, jnp.maximum(fused, took)
+
+    (out, fused), rows = _blocked(
+        x, order, loads, w, e_gu, e_down, block, lambda: (
+            jnp.zeros((t, h // lanes, lanes), f32), jnp.int32(0)), add)
+    return out.reshape(t, h).astype(x.dtype), rows, fused
+
+
+def _blocked_rows(x, order, loads, w, e_gu, e_down, block):
+    """(the expert outputs as words ``[N * W, lanes]`` in sorted order,
+    rows gathered): each block's unweighted outputs stored at the block's
+    offset, ``N`` the rows of every block the assignments could fill."""
+    t, k = w.shape
+    h = x.shape[1]
+    lanes = 128 if h % 128 == 0 else h
+    block = min(block, t * k)
+    words = -(-(h // lanes) // (4 // x.dtype.itemsize))
+
+    def store(buf, ys, rows, token, first, held_rows, flat_w):
+        return lax.dynamic_update_slice(buf, _row_words(ys, lanes),
+                                        (first * words, 0))
+
+    return _blocked(x, order, loads, w, e_gu, e_down, block, lambda: jnp.zeros(
+        (-(-t * k // block) * block * words, lanes), jnp.uint32), store)
+
+
+def _token_positions(order, mine, w):
+    """(each token's rows' places in sorted order ``[T, k]``, ascending —
+    its experts' order — and -1 for an assignment no held expert took;
+    their weights beside them)."""
+    t, k = mine.shape
+    n = t * k
+    at = jnp.zeros(n, jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+    return lax.sort((jnp.where(mine, at.reshape(t, k), -1), w),
+                    dimension=1, num_keys=1)
 
 
 def held_experts_sum(x: jax.Array, chosen: jax.Array, weights: jax.Array,
                      live: jax.Array, e_gu: jax.Array, e_down: jax.Array,
-                     held: Tuple[int, int]
+                     held: Tuple[int, int], experts: int
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """``sum_e w_e SwiGLU_e(x)`` over a token's chosen experts inside
     ``held``; ``live [T]`` is false for padding tokens, which reach no
     expert.  ``e_gu [G, H, 2I]``, ``e_down [G, I, H]`` hold experts
-    ``lo .. hi-1``.  Also the layer's counters."""
+    ``lo .. hi-1`` of the router's ``experts``.  Also the layer's counters;
+    a layer that holds every expert sums its rows by token and says so
+    (``gather_combine``)."""
     with jax.named_scope("moe/dispatch"):
         mine, order, loads, w = _sorted_assignments(chosen, weights, live,
                                                     held)
-    out, rows, fused = _blocked_sum(x, order, loads, w, e_gu, e_down,
-                                    DISPATCH_BLOCK)
+    counters = {}
+    if tuple(held) == (0, experts):
+        # every assignment of a live token is served: the rows are kept in
+        # sorted order and each token's read back once
+        buf, rows = _blocked_rows(x, order, loads, w, e_gu, e_down,
+                                  DISPATCH_BLOCK)
+        with jax.named_scope("moe/combine"):
+            pos, pos_w = _token_positions(order, mine, w)
+            out, fused = _token_sum(buf, pos, pos_w, x.dtype,
+                                    x.shape[1] // buf.shape[-1])
+        counters["gather_combine"] = jnp.int32(1)
+    else:
+        out, rows, fused = _blocked_sum(x, order, loads, w, e_gu, e_down,
+                                        DISPATCH_BLOCK)
     return out, {
         "assignments": loads.sum(),
         "load_max": loads.max(),
@@ -284,4 +511,5 @@ def held_experts_sum(x: jax.Array, chosen: jax.Array, weights: jax.Array,
         "unserved_tokens": jnp.sum(live & ~mine.any(-1)),
         "dispatch_rows": rows,
         "fused_combine": fused,
+        **counters,
     }

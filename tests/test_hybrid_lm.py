@@ -268,6 +268,24 @@ def test_counters_say_how_many_layers_took_the_kernel(model, params, scorer):
     assert rec["tokens"] == sum(TEMPLATES["mixed"])
 
 
+def test_a_holder_of_every_expert_counts_its_combines_by_token(
+        model, params, scorer):
+    """Every mixture layer of a holder of all 8 experts sums its rows by
+    token (``moe.gather_combines``, on the CPU as on a TPU); a holder of
+    part of them has no such key."""
+    batch, _ = make_batch(TEMPLATES["mixed"])
+    whole = HybridMoELM(dict(ARCH, held_experts=[0, 8]))
+    _, counters = jax.jit(whole.forward_counted)(
+        spiced(whole.init(jax.random.PRNGKey(2))), batch)
+    rec = HybridMoELM.counter_record(counters)
+    assert rec["moe.gather_combines"] == whole.layers - whole.dense_layers \
+        == 4.0
+    assert rec["moe.fused_combines"] == 0.0                # the CPU: none
+    _, counters = scorer[0](params, batch)
+    rec = HybridMoELM.counter_record(counters)
+    assert not [name for name in rec if "gather_combine" in name]
+
+
 def attention_inputs(lengths, h, dqk, dv, seed):
     """``q`` (scaled), ``k``, ``v`` float64, the document ids and every
     token's document start."""

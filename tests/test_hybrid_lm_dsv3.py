@@ -335,8 +335,8 @@ def test_the_layer_takes_one_form_at_every_share_held(held):
     e_down = jnp.asarray(rng.normal(size=(g, i, h)) / 4, jnp.float32)
     want, loads = whole_buffer_dispatch(x, chosen, weights, live, e_gu,
                                         e_down, held)
-    got, counters = jax.jit(moe.held_experts_sum, static_argnums=6)(
-        x, chosen, weights, live, e_gu, e_down, held)
+    got, counters = jax.jit(moe.held_experts_sum, static_argnums=(6, 7))(
+        x, chosen, weights, live, e_gu, e_down, held, 16)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     rows = int(loads.sum())
     assert int(counters["assignments"]) == rows

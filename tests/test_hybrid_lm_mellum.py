@@ -175,6 +175,7 @@ def test_forward_agrees_with_the_reference(model, params, scorer, template):
     assert rec["tokens"] == total and rec["documents"] == len(lengths)
     assert rec["gqa.yarn_layers"] == 1.0
     assert rec["gqa.fused_layers"] == rec["moe.fused_combines"] == 0.0
+    assert rec["moe.gather_combines"] == len(MIXTURES)      # by token
     for name in MIXTURES:            # every expert held: every assignment
         assert rec[f"{name}.assignments"] == 2 * total
         assert rec[f"{name}.unserved_tokens"] == 0.0

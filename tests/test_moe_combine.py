@@ -143,3 +143,117 @@ def test_the_blocked_sum_through_the_kernel(dtype, h, monkeypatch):
     assert np.asarray(got[240:] == 0).all()      # padding tokens: no sum
     np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
                                   np.asarray(want.astype(jnp.float32)))
+
+
+# -- the combine by token: a layer that holds every expert ------------------
+
+def _every_expert_layer(dtype, h, seed):
+    """A layer of 8 experts, 4 a token, every one held: expert 3 gets no
+    rows, tokens 230-249 are padding that reaches no expert, and 250 tokens
+    give 1 000 rows, which blocks of 64 do not divide."""
+    rng = np.random.default_rng(seed)
+    t, i, k, e = 250, 16, 4, 8
+    dt = jnp.dtype(dtype)
+    others = [0, 1, 2, 4, 5, 6, 7]
+    x = jnp.asarray(rng.normal(size=(t, h)), dt)
+    chosen = jnp.asarray(np.stack([rng.permutation(others)[:k]
+                                   for _ in range(t)]), jnp.int32)
+    # weights of eight bits, as above
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, size=(t, k)),
+                          jnp.bfloat16).astype(jnp.float32)
+    live = jnp.asarray(np.arange(t) < 230)
+    e_gu = jnp.asarray(rng.normal(size=(e, h, 2 * i)) / 6, dt)
+    e_down = jnp.asarray(rng.normal(size=(e, i, h)) / 4, dt)
+    return (x, chosen, weights, live, e_gu, e_down), e
+
+
+def _scatter_sums(args, e, block=64):
+    x, chosen, weights, live, e_gu, e_down = args
+    _, order, loads, w = moe._sorted_assignments(chosen, weights, live, (0, e))
+    return moe._blocked_sum(x, order, loads, w, e_gu, e_down, block)[0]
+
+
+def _token_inputs(args, e, block=64):
+    """The by-token form's rows as words, each token's positions and
+    weights."""
+    x, chosen, weights, live, e_gu, e_down = args
+    mine, order, loads, w = moe._sorted_assignments(chosen, weights, live,
+                                                    (0, e))
+    words, rows = moe._blocked_rows(x, order, loads, w, e_gu, e_down, block)
+    return (words, *moe._token_positions(order, mine, w)), rows
+
+
+def _equal(a, b):
+    np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                  np.asarray(b.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("h", [256, 384, 32])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_combine_by_token_adds_what_the_scatter_adds(dtype, h,
+                                                         monkeypatch):
+    """The layer that holds every expert takes the form: the gather (the
+    CPU's) and the kernel, run interpreted, give the blocked scatter's sums
+    to the last bit — a token's weighted rows are added in the same order;
+    ``h`` of 32 has no 128 lanes and keeps the gather."""
+    args, e = _every_expert_layer(dtype, h, seed=h)
+    monkeypatch.setattr(moe, "DISPATCH_BLOCK", 64)
+    want = jax.jit(lambda *a: _scatter_sums(a, e))(*args)
+    layer = jax.jit(lambda *a: moe.held_experts_sum(*a, (0, e), e))
+    got, counters = layer(*args)
+    assert int(counters["gather_combine"]) == 1
+    assert int(counters["fused_combine"]) == 0       # the CPU: the gather
+    assert int(counters["dispatch_rows"]) == 960     # 230 live tokens' 920
+    _equal(got, want)
+    assert np.asarray(got[230:] == 0).all()          # padding tokens: no sum
+    gather = moe._token_sum
+
+    def kernel(words, pos, w, dtype, c):
+        if words.shape[-1] != 128:
+            return gather(words, pos, w, dtype, c)
+        return moe._token_sum_kernel(words, pos, w, dtype=dtype, c=c,
+                                     interpret=True), jnp.int32(1)
+
+    monkeypatch.setattr(moe, "_token_sum", kernel)
+    got_k, counters = jax.jit(lambda *a: moe.held_experts_sum(
+        *a, (0, e), e))(*args)
+    assert int(counters["fused_combine"]) == (h % 128 == 0)
+    _equal(got_k, got)
+
+
+def test_no_copy_of_a_row_races_its_reads():
+    """Under the TPU interpreter, which runs a copy only when it is awaited
+    and notes every read and write of a buffer: the rows' copies into VMEM
+    and the adds that read them do not race, and the sums are the
+    gather's."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+    args, e = _every_expert_layer("bfloat16", 256, seed=7)
+    (words, pos, w), _ = jax.jit(lambda *a: _token_inputs(a, e))(*args)
+    want, _ = moe._token_sum(words, pos, w, jnp.bfloat16, 2)
+    got = moe._token_sum_kernel.__wrapped__(
+        words, pos, w, dtype=jnp.bfloat16, c=2,
+        interpret=pltpu.InterpretParams(detect_races=True,
+                                        dma_execution_mode="on_wait"))
+    _equal(got, want)
+    assert not interpret_pallas_call.races.races_found
+
+
+@pytest.mark.parametrize("held", [(0, 5), (3, 8), (2, 6)])
+def test_a_holder_of_part_of_the_experts_never_sums_by_token(held,
+                                                             monkeypatch):
+    """A holder of part of the experts keeps the blocked scatter: the
+    rows' buffer is never built and its counters lack ``gather_combine``."""
+    args, e = _every_expert_layer("bfloat16", 256, seed=3)
+    x, chosen, weights, live, e_gu, e_down = args
+    lo, hi = held
+
+    def refused(*a, **kw):
+        raise AssertionError("a partial holder built the rows' buffer")
+
+    monkeypatch.setattr(moe, "_blocked_rows", refused)
+    monkeypatch.setattr(moe, "_token_sum", refused)
+    _, counters = jax.jit(lambda *a: moe.held_experts_sum(
+        *a, held, e))(x, chosen, weights, live, e_gu[lo:hi], e_down[lo:hi])
+    assert "gather_combine" not in counters
+    assert int(counters["assignments"]) > 0

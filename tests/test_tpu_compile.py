@@ -288,9 +288,11 @@ def _attention_is_the_kernel(text, model, mixer="mla"):
 
 
 def _combine_is_the_kernel(text, model, t):
-    """Every mixture layer adds its blocks' rows to their tokens by the
+    """Every mixture layer brings its rows to their tokens by one
     ``moe_combine`` Pallas kernel, selected at lowering, under the scope a
-    trace reads; no scatter into the ``[T, H]`` float32 sums is left."""
+    trace reads — a holder of part of the experts adds each block's rows
+    to the ``[T, H]`` float32 sums, a holder of every expert sums each
+    token's rows once after the loop; no scatter into the sums is left."""
     import re
     kernels = [line for line in text.splitlines()
                if "custom-call(" in line and " %moe_combine" in line]
@@ -471,8 +473,10 @@ def case_document_scorer_mellum(topo):
     sizes (eight layers at published widths, all 64 experts of each, the
     whole vocabulary, 8 documents in 32 768 tokens, bfloat16): it fits the
     chip beside its 7.59 GB of parameters; every layer's attention is the
-    kernel on grouped keys and every mixture layer's combine the kernel; the
-    full layers turn under ``gqa/rope_yarn``, the sliding ones under
+    kernel on grouped keys and every mixture layer sums its rows by token
+    through the combine kernel, once after its loop, which carries the
+    rows and no float32 sums; the full layers turn under
+    ``gqa/rope_yarn``, the sliding ones under
     ``gqa/rope``; no gate, QK-norm, post-norm or shared expert exists, and
     neither the ``[T, T]`` scores nor the ``[T, V]`` logits exist whole."""
     import re
@@ -499,6 +503,11 @@ def case_document_scorer_mellum(topo):
     assert "ragged-dot" in text                  # experts: grouped products
     _attention_is_the_kernel(text, model, "gqa")
     _combine_is_the_kernel(text, model, t)
+    # by token: the loop carries the rows in sorted order as words (nine
+    # word rows of bfloat16 pairs a row), not the [T, H / 128, 128] sums
+    words = model.hidden // 256
+    assert f"u32[{t * model.top_k * words},128]" in text
+    assert f"f32[{t},{model.hidden // 128},128]" not in text
     top = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]", text,
                      re.M)
     for dims in top:
