@@ -702,10 +702,11 @@ class HybridMoELM:
                 out, counters[name], choices[name] = self._moe(p, y, live)
                 counters["moe.fused_combines"] += counters[name].pop(
                     "fused_combine")
-                if "gather_combine" in counters[name]:
-                    counters["moe.gather_combines"] = counters.get(
-                        "moe.gather_combines", 0) + counters[name].pop(
-                            "gather_combine")
+                for key, total in (("gather_combine", "moe.gather_combines"),
+                                   ("dispatch_turns", "moe.dispatch_turns")):
+                    if key in counters[name]:
+                        counters[total] = counters.get(total, 0) + \
+                            counters[name].pop(key)
             x = x + self._post(p, "post_norm2", out)
         return _rms(x, params["final_norm"], self.eps), counters, choices
 
@@ -720,7 +721,8 @@ class HybridMoELM:
         kernel, and ``moe.fused_combines``, how many mixture layers added
         their experts' rows by the combine kernel; where a layer holds
         every expert ``moe.gather_combines``, how many mixture layers
-        summed their rows by token (``ops.moe.held_experts_sum``); under
+        summed their rows by token (``ops.moe.held_experts_sum``), and
+        ``moe.dispatch_turns``, the turns their loops took in all; under
         grouped-query attention ``attn.key_blocks_full`` and
         ``attn.key_blocks_window``, the key blocks one full and one sliding
         layer's walk visits for this batch

@@ -13,12 +13,13 @@ and nothing stands in for it.
 
 Experts run as grouped matrix products (``lax.ragged_dot``) over the
 assignments sorted by expert, so each expert multiplies only the tokens it
-received; the held assignments sort first and are carried
-``DISPATCH_BLOCK`` sorted rows at a time — gathered, multiplied, weighed
-and added to their tokens' float32 sums — for as many blocks as the batch's
-held assignments fill.  Nothing is dropped at any load up to all ``T * k``,
-no buffer of activations is larger than a block or ``[T, H]``, and the only
-parameter is the number of held rows the data brings.
+received; the held assignments sort first and a holder of part of the
+experts carries them ``DISPATCH_BLOCK`` sorted rows at a time — gathered,
+multiplied, weighed and added to their tokens' float32 sums — for as many
+blocks as the batch's held assignments fill.  Nothing is dropped at any
+load up to all ``T * k``, no buffer of activations is larger than a block
+or ``[T, H]``, and the only parameter is the number of held rows the data
+brings.
 
 On a TPU the adding is one Pallas kernel a block (``moe_combine``, PR 40).
 The sums are kept as ``[T, H / 128, 128]``, a token's sum one piece of
@@ -41,6 +42,18 @@ and no read-modify-write a row.  The products and their order are the
 blocked scatter's, so the sums are too, to the last bit.  A holder of part
 of the experts keeps the blocked scatter: a ``T * k``-row buffer would
 cost it 1.2-1.9 GB for rows it never serves.
+
+Such a layer also carries larger blocks (PR 47): the least power of two
+that holds an expert's mean run of rows, ``T * k / E`` (4 096 rows in the
+Mellum cell, against ``DISPATCH_BLOCK``'s 512), so a run falls in one or
+two blocks; and on a TPU each of its products is the grouped-matmul Pallas
+kernel that ships with JAX (``megablox`` ``gmm``) with the whole depth in
+a tile, which gives ``ragged_dot``'s bits.  ``ragged_dot`` slows as its
+blocks grow; on a v5e, one layer alone, the kernel reaches ~87 % of the
+chip's peak at 4 096 rows, where ``ragged_dot`` took 42 % at 512 (PERF.md,
+PR 47).  Only the block's
+extent and the products' kernel differ: the rows, their order, the store
+and the combine by token are the same.
 """
 
 from __future__ import annotations
@@ -53,15 +66,18 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _megablox_gmm
 
 __all__ = ["route", "held_experts_sum", "swiglu", "DISPATCH_BLOCK"]
 
-# by cost on a v5e, one layer alone at the three scorer cells' shapes
-# (PERF.md, PR 40, §6): a turn of the loop costs ~20 us beside its rows (the
-# grouped products' metadata, the gather's and the kernel's starts), the
-# padding behind the last held row next to nothing since the kernel skips
-# it; 16, 32 and 128 held experts read 14.87, 18.23, 51.68 ms a layer at
-# 256, 14.96, 18.58, 44.32 at 512, 15.06, 18.41, 43.96 at 1 024
+# the block of a holder of part of the experts (a holder of every expert
+# derives its own: ``_every_expert_block``); by cost on a v5e, one layer
+# alone at the three such scorer cells' shapes (PERF.md, PR 40, §6): a turn
+# of the loop costs ~20 us beside its rows (the grouped products' metadata,
+# the gather's and the kernel's starts), the padding behind the last held
+# row next to nothing since the kernel skips it; 16, 32 and 128 held
+# experts read 14.87, 18.23, 51.68 ms a layer at 256, 14.96, 18.58, 44.32
+# at 512, 15.06, 18.41, 43.96 at 1 024
 DISPATCH_BLOCK = 512
 
 
@@ -104,16 +120,80 @@ def route(x: jax.Array, router: jax.Array, bias, k: int,
         -1, keepdims=True)
 
 
-def _swiglu_experts(xs, e_gu, e_down, sizes):
-    """The sorted rows ``xs`` through their experts' SwiGLU.  Products
-    accumulate in float32 inside; what leaves is an activation, in the
-    activations' type."""
+def _swiglu_experts(xs, e_gu, e_down, sizes, product=jax.lax.ragged_dot):
+    """The sorted rows ``xs`` through their experts' SwiGLU, each grouped
+    product by ``product``.  Products accumulate in float32 inside; what
+    leaves is an activation, in the activations' type."""
     f32 = jnp.float32
-    gu = jax.lax.ragged_dot(xs, e_gu, sizes, preferred_element_type=xs.dtype)
+    gu = product(xs, e_gu, sizes, preferred_element_type=xs.dtype)
     gate, up = jnp.split(gu, 2, axis=-1)
     hid = (jax.nn.silu(gate.astype(f32)) * up.astype(f32)).astype(xs.dtype)
-    return jax.lax.ragged_dot(hid, e_down, sizes,
-                              preferred_element_type=xs.dtype)
+    return product(hid, e_down, sizes, preferred_element_type=xs.dtype)
+
+
+# a holder of every expert takes its products by the grouped-matmul kernel
+# in blocks of about one expert's mean run of rows, so a run falls in one or
+# two products.  By cost on a v5e, one layer alone at the Mellum cell's shape
+# (a mean run of 4 096 rows; PERF.md, PR 47, §6): 74.5 ms at 512 rows a turn
+# by ``ragged_dot``, which slows as its blocks grow (72.4 at 2 048, 81.5 at
+# 8 192, 126.4 at 32 768); by the kernel 57.3 at 2 048, 54.6 at 4 096, 62.6
+# at 8 192, 81.1 at 16 384 (XLA lays the larger blocks' rows out again).  So
+# a block's temporaries stay within _BLOCK_BYTES; a tile of a product is
+# _GMM_ROWS rows and passes no more than _GMM_VMEM of VMEM
+_BLOCK_BYTES, _GMM_ROWS, _GMM_VMEM = 64 * 2 ** 20, 256, 14 * 2 ** 20
+
+
+def _every_expert_block(rows: int, experts: int, h: int, i: int,
+                        itemsize: int) -> int:
+    """The sorted rows a turn of the loop carries where a layer holds every
+    one of ``experts`` and so serves all ``rows`` (``T * k``): the least
+    power of two from ``DISPATCH_BLOCK`` up that holds a mean run (``rows /
+    experts``), no larger than the rows whose gathered input, gate and up,
+    hidden activations and output (``2h + 3i`` values a row) fit
+    ``_BLOCK_BYTES``, nor than ``rows``.  A power of two divides a ``rows``
+    that is a multiple of it: then the blocks hold every row and no
+    padding."""
+    cap = _BLOCK_BYTES // ((2 * h + 3 * i) * itemsize)
+    block = DISPATCH_BLOCK
+    while block * experts < rows and 2 * block <= cap:
+        block *= 2
+    return min(block, rows)
+
+
+def _gmm_tiling(k: int, n: int, itemsize: int) -> Tuple[int, int, int]:
+    """A grouped product's tile ``(rows, depth, columns)``: ``_GMM_ROWS``
+    rows, the whole depth ``k`` — each output a single pass of float32
+    sums, as ``ragged_dot`` takes it, so both give the same bits — and the
+    ``n`` columns halved while the tiles, double-buffered, and the float32
+    accumulator pass ``_GMM_VMEM``."""
+    m, tn = _GMM_ROWS, n
+
+    def vmem(tn):
+        return 2 * (m * k + k * tn + m * tn) * itemsize + m * tn * 4
+
+    while tn % 256 == 0 and vmem(tn) > _GMM_VMEM:
+        tn //= 2
+    return m, k, tn
+
+
+def _gmm(lhs, rhs, sizes, preferred_element_type):
+    """``ragged_dot`` as the grouped-matmul Pallas kernel (``megablox``)."""
+    return _megablox_gmm(lhs, rhs, sizes,
+                         preferred_element_type=preferred_element_type,
+                         tiling=_gmm_tiling(*rhs.shape[1:],
+                                            lhs.dtype.itemsize))
+
+
+def _swiglu_grouped(xs, e_gu, e_down, sizes):
+    """``_swiglu_experts`` with both products the grouped-matmul kernel on
+    a TPU where a block is whole tiles, ``ragged_dot`` everywhere else
+    (decided at lowering).  Rows behind the last expert's run are left as
+    they were."""
+    if xs.shape[0] % _GMM_ROWS:
+        return _swiglu_experts(xs, e_gu, e_down, sizes)
+    return lax.platform_dependent(
+        xs, e_gu, e_down, sizes, default=_swiglu_experts,
+        tpu=functools.partial(_swiglu_experts, product=_gmm))
 
 
 def _sorted_assignments(chosen, weights, live, held):
@@ -392,11 +472,13 @@ def _token_sum(words, pos, w, dtype, c):
                         jnp.int32(1)))
 
 
-def _blocked(x, order, loads, w, e_gu, e_down, block, init, take):
-    """(what ``take`` leaves, rows gathered): ``block`` sorted rows at a
-    time, for as many blocks as the held assignments fill, each block's
-    expert outputs handed to ``take(carry, ys, rows, token, first,
-    held_rows, flat_w)`` with the loop's carry, ``init()`` at the start."""
+def _blocked(x, order, loads, w, e_gu, e_down, block, init, take,
+             experts=_swiglu_experts):
+    """(what ``take`` leaves, rows gathered, turns): ``block`` sorted rows
+    at a time, for as many blocks as the held assignments fill, each
+    block's rows through ``experts`` and the outputs handed to
+    ``take(carry, ys, rows, token, first, held_rows, flat_w)`` with the
+    loop's carry, ``init()`` at the start."""
     t, k = w.shape
     block = min(block, t * k)
     with jax.named_scope("moe/dispatch"):
@@ -416,12 +498,13 @@ def _blocked(x, order, loads, w, e_gu, e_down, block, init, take):
                 - jnp.clip(starts, first, first + block)
             xs = x[token]                                     # [block, H]
         with jax.named_scope("moe/experts"):
-            ys = _swiglu_experts(xs, e_gu, e_down, sizes)
+            ys = experts(xs, e_gu, e_down, sizes)
         with jax.named_scope("moe/combine"):
             return take(carry, ys, rows, token, first, held_rows, flat_w)
 
     blocks = (held_rows + block - 1) // block
-    return jax.lax.fori_loop(0, blocks, one_block, init()), blocks * block
+    return jax.lax.fori_loop(0, blocks, one_block, init()), blocks * block, \
+        blocks
 
 
 def _blocked_sum(x, order, loads, w, e_gu, e_down, block):
@@ -441,7 +524,7 @@ def _blocked_sum(x, order, loads, w, e_gu, e_down, block):
                              held_rows - first)
         return out, jnp.maximum(fused, took)
 
-    (out, fused), rows = _blocked(
+    (out, fused), rows, _ = _blocked(
         x, order, loads, w, e_gu, e_down, block, lambda: (
             jnp.zeros((t, h // lanes, lanes), f32), jnp.int32(0)), add)
     return out.reshape(t, h).astype(x.dtype), rows, fused
@@ -449,8 +532,9 @@ def _blocked_sum(x, order, loads, w, e_gu, e_down, block):
 
 def _blocked_rows(x, order, loads, w, e_gu, e_down, block):
     """(the expert outputs as words ``[N * W, lanes]`` in sorted order,
-    rows gathered): each block's unweighted outputs stored at the block's
-    offset, ``N`` the rows of every block the assignments could fill."""
+    rows gathered, turns): each block's unweighted outputs stored at the
+    block's offset, ``N`` the rows of every block the assignments could
+    fill."""
     t, k = w.shape
     h = x.shape[1]
     lanes = 128 if h % 128 == 0 else h
@@ -462,7 +546,8 @@ def _blocked_rows(x, order, loads, w, e_gu, e_down, block):
                                         (first * words, 0))
 
     return _blocked(x, order, loads, w, e_gu, e_down, block, lambda: jnp.zeros(
-        (-(-t * k // block) * block * words, lanes), jnp.uint32), store)
+        (-(-t * k // block) * block * words, lanes), jnp.uint32), store,
+        _swiglu_grouped)
 
 
 def _token_positions(order, mine, w):
@@ -493,14 +578,19 @@ def held_experts_sum(x: jax.Array, chosen: jax.Array, weights: jax.Array,
     counters = {}
     if tuple(held) == (0, experts):
         # every assignment of a live token is served: the rows are kept in
-        # sorted order and each token's read back once
-        buf, rows = _blocked_rows(x, order, loads, w, e_gu, e_down,
-                                  DISPATCH_BLOCK)
+        # sorted order, carried in large blocks, and each token's read back
+        # once
+        t, k = chosen.shape
+        buf, rows, turns = _blocked_rows(
+            x, order, loads, w, e_gu, e_down,
+            _every_expert_block(t * k, experts, x.shape[1], e_down.shape[1],
+                                x.dtype.itemsize))
         with jax.named_scope("moe/combine"):
             pos, pos_w = _token_positions(order, mine, w)
             out, fused = _token_sum(buf, pos, pos_w, x.dtype,
                                     x.shape[1] // buf.shape[-1])
         counters["gather_combine"] = jnp.int32(1)
+        counters["dispatch_turns"] = turns
     else:
         out, rows, fused = _blocked_sum(x, order, loads, w, e_gu, e_down,
                                         DISPATCH_BLOCK)

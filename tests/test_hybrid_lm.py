@@ -16,7 +16,7 @@ import lm_reference
 from dmlc_core_tpu.data.row_block import RowBlock
 from dmlc_core_tpu.models.hybrid_lm import HybridMoELM
 from dmlc_core_tpu.ops.doc_attention import doc_causal_attention
-from dmlc_core_tpu.ops import doc_attention, kda
+from dmlc_core_tpu.ops import doc_attention, kda, moe
 from dmlc_core_tpu.ops.kda import kda_chunked
 from dmlc_core_tpu.pipeline.packing import pack_flat
 
@@ -280,10 +280,18 @@ def test_a_holder_of_every_expert_counts_its_combines_by_token(
     rec = HybridMoELM.counter_record(counters)
     assert rec["moe.gather_combines"] == whole.layers - whole.dense_layers \
         == 4.0
+    # the loops' turns: each layer's held rows over the derived block
+    block = moe._every_expert_block(batch["ids"].shape[0] * 2, 8, 64, 32, 4)
+    mixtures = [k[:-len(".assignments")] for k in rec
+                if k.endswith(".assignments")]
+    assert len(mixtures) == 4
+    assert rec["moe.dispatch_turns"] == sum(
+        -(-rec[f"{name}.assignments"] // block) for name in mixtures)
     assert rec["moe.fused_combines"] == 0.0                # the CPU: none
     _, counters = scorer[0](params, batch)
     rec = HybridMoELM.counter_record(counters)
-    assert not [name for name in rec if "gather_combine" in name]
+    assert not [name for name in rec if "gather_combine" in name
+                or "dispatch_turns" in name]
 
 
 def attention_inputs(lengths, h, dqk, dv, seed):

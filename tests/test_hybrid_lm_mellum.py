@@ -176,6 +176,10 @@ def test_forward_agrees_with_the_reference(model, params, scorer, template):
     assert rec["gqa.yarn_layers"] == 1.0
     assert rec["gqa.fused_layers"] == rec["moe.fused_combines"] == 0.0
     assert rec["moe.gather_combines"] == len(MIXTURES)      # by token
+    # the loops' turns: each layer's rows over the derived block
+    block = moe._every_expert_block(batch["ids"].shape[0] * 2, 8, 64, 32, 4)
+    assert rec["moe.dispatch_turns"] == sum(
+        -(-rec[f"{name}.assignments"] // block) for name in MIXTURES)
     for name in MIXTURES:            # every expert held: every assignment
         assert rec[f"{name}.assignments"] == 2 * total
         assert rec[f"{name}.unserved_tokens"] == 0.0

@@ -179,7 +179,8 @@ def _token_inputs(args, e, block=64):
     x, chosen, weights, live, e_gu, e_down = args
     mine, order, loads, w = moe._sorted_assignments(chosen, weights, live,
                                                     (0, e))
-    words, rows = moe._blocked_rows(x, order, loads, w, e_gu, e_down, block)
+    words, rows, _ = moe._blocked_rows(x, order, loads, w, e_gu, e_down,
+                                       block)
     return (words, *moe._token_positions(order, mine, w)), rows
 
 
@@ -197,13 +198,14 @@ def test_the_combine_by_token_adds_what_the_scatter_adds(dtype, h,
     to the last bit — a token's weighted rows are added in the same order;
     ``h`` of 32 has no 128 lanes and keeps the gather."""
     args, e = _every_expert_layer(dtype, h, seed=h)
-    monkeypatch.setattr(moe, "DISPATCH_BLOCK", 64)
     want = jax.jit(lambda *a: _scatter_sums(a, e))(*args)
     layer = jax.jit(lambda *a: moe.held_experts_sum(*a, (0, e), e))
     got, counters = layer(*args)
     assert int(counters["gather_combine"]) == 1
     assert int(counters["fused_combine"]) == 0       # the CPU: the gather
-    assert int(counters["dispatch_rows"]) == 960     # 230 live tokens' 920
+    # 230 live tokens' 920 rows in two blocks of 512
+    assert int(counters["dispatch_rows"]) == 1024
+    assert int(counters["dispatch_turns"]) == 2
     _equal(got, want)
     assert np.asarray(got[230:] == 0).all()          # padding tokens: no sum
     gather = moe._token_sum
@@ -257,3 +259,109 @@ def test_a_holder_of_part_of_the_experts_never_sums_by_token(held,
         *a, held, e))(x, chosen, weights, live, e_gu[lo:hi], e_down[lo:hi])
     assert "gather_combine" not in counters
     assert int(counters["assignments"]) > 0
+
+
+# -- the every-expert loop's block -------------------------------------------
+
+def _routed_layer(case, seed=0):
+    """A layer of 8 experts, 2 a token over 4 096 tokens (8 192 rows, a
+    mean run of 1 024), every one held, routed as ``case`` says."""
+    rng = np.random.default_rng(seed)
+    t, h, i, k, e = 4096, 256, 16, 2, 8
+    among = np.arange(e)
+    if case == "empty_experts":                  # experts 0-2 get no rows
+        among = np.arange(3, e)
+    chosen = np.stack([rng.choice(among, k, replace=False)
+                       for _ in range(t)])
+    if case == "one_run_spans_blocks":           # every token takes expert 5
+        chosen[:, 0] = 5
+        chosen[:, 1] = np.where(chosen[:, 1] == 5, 6, chosen[:, 1])
+    live = np.arange(t) < (t - 333 if case == "padding_tokens" else t)
+    x = jnp.asarray(rng.normal(size=(t, h)), jnp.bfloat16)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, size=(t, k)),
+                          jnp.bfloat16).astype(jnp.float32)
+    e_gu = jnp.asarray(rng.normal(size=(e, h, 2 * i)) / 6, jnp.bfloat16)
+    e_down = jnp.asarray(rng.normal(size=(e, i, h)) / 4, jnp.bfloat16)
+    return (x, jnp.asarray(chosen, jnp.int32), weights, jnp.asarray(live),
+            e_gu, e_down), e
+
+
+@pytest.mark.parametrize("case", ["routed", "one_run_spans_blocks",
+                                  "empty_experts", "padding_tokens"])
+def test_the_derived_block_sums_what_512_rows_a_turn_sum(case, monkeypatch):
+    """A layer that holds every expert carries blocks derived from its
+    shapes, several mean runs of rows each; the sums are those of 512 rows a
+    turn to the last bit (the same rows through the same weights), every
+    row is carried once and the turns are counted."""
+    args, e = _routed_layer(case, seed=len(case))
+    x, chosen, _, live, _, e_down = args
+    t, k = chosen.shape
+    block = moe._every_expert_block(t * k, e, x.shape[1], e_down.shape[1],
+                                    x.dtype.itemsize)
+    assert moe.DISPATCH_BLOCK < block < t * k    # 8 turns, not 16
+    got, counters = jax.jit(lambda *a: moe.held_experts_sum(
+        *a, (0, e), e))(*args)
+    loads = np.bincount(np.asarray(chosen)[np.asarray(live)].ravel(),
+                        minlength=e)
+    held_rows = int(loads.sum())
+    assert int(counters["assignments"]) == held_rows
+    assert int(counters["dispatch_turns"]) == -(-held_rows // block)
+    assert int(counters["dispatch_rows"]) == \
+        int(counters["dispatch_turns"]) * block
+    if case == "padding_tokens":
+        assert held_rows < t * k
+        assert np.asarray(got[t - 333:] == 0).all()
+    else:                                        # every row, no padding
+        assert int(counters["dispatch_rows"]) == t * k
+    if case == "one_run_spans_blocks":           # expert 5's: 4 blocks+
+        ends = np.cumsum(loads)
+        assert (ends[5] - 1) // block - (ends[5] - loads[5]) // block >= 2
+    if case == "empty_experts":
+        assert (loads[:3] == 0).all()
+    monkeypatch.setattr(moe, "_every_expert_block", lambda *a: 512)
+    want, counters = jax.jit(lambda *a: moe.held_experts_sum(
+        *a, (0, e), e))(*args)
+    assert int(counters["dispatch_turns"]) == -(-held_rows // 512)
+    _equal(got, want)
+
+
+@pytest.mark.parametrize("dtype,h,i", [("bfloat16", 256, 128),
+                                       ("bfloat16", 384, 64),
+                                       ("float32", 256, 128)])
+def test_the_grouped_kernel_multiplies_each_run_by_its_expert(
+        dtype, h, i, monkeypatch):
+    """The every-expert loop's products on a TPU: the grouped-matmul kernel
+    (``megablox``), run interpreted, with the tiles ``_gmm_tiling`` gives
+    (the whole depth in one pass), multiplies each run of rows by its
+    expert and rounds once: experts with no rows and a block the runs do
+    not fill among them, at the widths of both products of the SwiGLU."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    rng = np.random.default_rng(h + i)
+    dt = jnp.dtype(dtype)
+    m, e = 512, 6
+    loads = [0, 130, 200, 0, 100, 40]                  # 470 rows of 512
+    sizes = jnp.asarray(loads, jnp.int32)
+    group = np.repeat(np.arange(e), loads)
+    monkeypatch.setattr(moe, "_megablox_gmm",
+                        lambda *a, **kw: gmm(*a, **kw, interpret=True))
+    for k, n in ((h, 2 * i), (i, h)):
+        assert moe._gmm_tiling(k, n, dt.itemsize) == (256, k, n)
+        lhs = jnp.asarray(rng.normal(size=(m, k)), dt)
+        rhs = jnp.asarray(rng.normal(size=(e, k, n)) / np.sqrt(k), dt)
+        got = moe._gmm(lhs, rhs, sizes, preferred_element_type=dt)
+        want = np.einsum("rk,rkn->rn",
+                         np.asarray(lhs[:470], np.float64),
+                         np.asarray(rhs, np.float64)[group])
+        # a float32 sum, rounded once to the output's type
+        rtol = 2.0 ** -8 if dtype == "bfloat16" else 1e-5
+        np.testing.assert_allclose(np.asarray(got[:470], np.float64), want,
+                                   rtol=rtol, atol=1e-5)
+
+
+def test_the_tiles_fit_vmem_at_the_mellum_widths():
+    """At the Mellum cell's widths the gate-and-up product's columns halve
+    to one of gate or up, the down product keeps all 2 304; both pass the
+    whole depth."""
+    assert moe._gmm_tiling(2304, 1792, 2) == (256, 2304, 896)
+    assert moe._gmm_tiling(896, 2304, 2) == (256, 896, 2304)
+    assert moe._every_expert_block(32768 * 8, 64, 2304, 896, 2) == 4096

@@ -473,9 +473,11 @@ def case_document_scorer_mellum(topo):
     sizes (eight layers at published widths, all 64 experts of each, the
     whole vocabulary, 8 documents in 32 768 tokens, bfloat16): it fits the
     chip beside its 7.59 GB of parameters; every layer's attention is the
-    kernel on grouped keys and every mixture layer sums its rows by token
-    through the combine kernel, once after its loop, which carries the
-    rows and no float32 sums; the full layers turn under
+    kernel on grouped keys, every mixture layer's products are the
+    grouped-matmul kernel over blocks of one mean run of rows, and every
+    mixture layer sums its rows by token through the combine kernel, once
+    after its loop, which carries the rows and no float32 sums; the full
+    layers turn under
     ``gqa/rope_yarn``, the sliding ones under
     ``gqa/rope``; no gate, QK-norm, post-norm or shared expert exists, and
     neither the ``[T, T]`` scores nor the ``[T, V]`` logits exist whole."""
@@ -500,7 +502,17 @@ def case_document_scorer_mellum(topo):
     # all before the first compile)
     assert _hbm(compiled) < 12_000_000_000 < HBM_BYTES
     text = compiled.as_text()
-    assert "ragged-dot" in text                  # experts: grouped products
+    # experts: each product the grouped-matmul kernel, under the scope a
+    # trace reads, over blocks of one mean run of rows (T k / E)
+    block = t * model.top_k // model.experts
+    products = [line for line in text.splitlines()
+                if "custom-call(" in line and " %gmm" in line]
+    assert len(products) == 2 * (model.layers - model.dense_layers)
+    for line in products:
+        assert 'custom_call_target="tpu_custom_call"' in line
+        assert re.search(r'op_name="[^"]*moe/experts/', line), line[:300]
+    assert "ragged-dot" not in text
+    assert f"bf16[{block},{model.hidden}]" in text      # a block's rows
     _attention_is_the_kernel(text, model, "gqa")
     _combine_is_the_kernel(text, model, t)
     # by token: the loop carries the rows in sorted order as words (nine
