@@ -1,9 +1,10 @@
 """The hybrid linear/latent-attention mixture-of-experts document scorer at
 small widths on the CPU (hidden 64, 4 heads of 16, 8 experts top-2 + 1
 shared, 5 layers in the published pattern, 512 vocabulary rows; float32),
-against the plain reference (``tests/lm_reference.py``, the same text as
-the benchmark's ``reference_lm.py``)."""
+against the plain reference (``benchmarks/chip/reference_lm.py``, loaded by
+its path)."""
 
+import functools
 import json
 import os
 
@@ -12,13 +13,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import lm_reference
+from conftest import reference
 from dmlc_core_tpu.data.row_block import RowBlock
 from dmlc_core_tpu.models.hybrid_lm import HybridMoELM
 from dmlc_core_tpu.ops.doc_attention import doc_causal_attention
 from dmlc_core_tpu.ops import doc_attention, kda, moe
 from dmlc_core_tpu.ops.kda import kda_chunked
 from dmlc_core_tpu.pipeline.packing import pack_flat
+
+reference_lm = reference("lm")
 
 ARCH = {
     "model_type": "kimi_linear", "hidden_size": 64, "num_hidden_layers": 5,
@@ -57,6 +60,27 @@ def make_batch(lengths, seed=1, vocab=512):
         indices=np.concatenate(docs).astype(np.uint64), values=None)
     host = pack_flat(blk, ROWS, CAP, id_mod=vocab)
     return {k: jnp.asarray(v) for k, v in host.items()}, host
+
+
+def edges(lengths):
+    """The stream positions a probe reads: each document's first and last
+    token, and the stream's."""
+    total = sum(lengths)
+    return sorted({0, total - 1} | set(np.cumsum(lengths)[:-1].tolist())
+                  | set((np.cumsum(lengths) - 1).tolist()))
+
+
+def plain_runs(ref, params, templates):
+    """The plain reference ``ref``'s run of each template, made once and
+    kept: a forward test holds the program to it, and each control of the
+    reference and each variant of the program differs from its scores."""
+    @functools.cache
+    def run(template):
+        lengths = templates[template]
+        _, host = make_batch(lengths)
+        return ref.run(params, host["ids"],
+                       host["row_ptr"][:len(lengths) + 1], edges(lengths))
+    return run
 
 
 def spiced(params, seed=5):
@@ -100,9 +124,8 @@ def test_forward_agrees_with_the_reference(model, params, scorer, template):
     lengths = TEMPLATES[template]
     batch, host = make_batch(lengths)
     total = sum(lengths)
-    positions = sorted({0, total - 1} | set(np.cumsum(lengths)[:-1].tolist())
-                       | set((np.cumsum(lengths) - 1).tolist()))
-    ref = lm_reference.Reference(ARCH).run(
+    positions = edges(lengths)
+    ref = reference_lm.Reference(ARCH).run(
         params, host["ids"], host["row_ptr"][:len(lengths) + 1], positions)
     scores, counters = scorer[0](params, batch)
     scores = np.asarray(scores)
@@ -467,7 +490,7 @@ def test_the_shares_add_up_to_the_uncut_layer(model, params, layer, split):
         out, counters, _ = share._moe(mine, x, live)
         parts.append(np.asarray(out))
         loads += int(counters["assignments"])
-    ref = lm_reference.Reference(dict(ARCH, held_experts=[0, 8]))
+    ref = reference_lm.Reference(dict(ARCH, held_experts=[0, 8]))
     uncut, _, _ = ref.moe(whole, x)
     shared = np.asarray(ref.swiglu(x, p["s_gu"], p["s_down"]))
     np.testing.assert_allclose(parts[0] + parts[1] - shared,
@@ -525,15 +548,6 @@ def test_an_architecture_it_does_not_compute_is_refused(key, value):
         HybridMoELM(dict(ARCH, **{key: value}))
 
 
-def test_the_two_copies_of_the_reference_are_one_text():
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "lm_reference.py")) as f:
-        mine = f.read()
-    with open(os.path.join(here, "..", "benchmarks", "chip",
-                           "reference_lm.py")) as f:
-        assert f.read() == mine
-
-
 # -- the normal path: registry, loader, predict -----------------------------
 
 def write_docs(path, lengths, seed=9):
@@ -576,7 +590,7 @@ def test_predict_scores_documents_through_the_cli(run_dir):
     assert cli.main(predict_args(tmp, arch)) == 0
     got = np.loadtxt(f"{tmp}/scores.txt")
     assert got.shape == (len(docs),)
-    ref = lm_reference.Reference(ARCH)
+    ref = reference_lm.Reference(ARCH)
     for lo in range(0, len(docs), ROWS):
         part = docs[lo:lo + ROWS]
         rp = np.concatenate([[0], np.cumsum([len(d) for d in part])])

@@ -3,8 +3,8 @@ the CPU (hidden 64, 4 query heads on 2 key heads of 16, a window of 16 under
 documents of up to 600 tokens, sliding and full layers mixed, QK-norm, a
 gated output, sandwich norms, the embedding times 8, 16 experts top-4 + 1
 shared of which 3 are held, one dense layer and four mixtures; float32),
-against the plain reference (``tests/afmoe_reference.py``, the same text as
-the benchmark's ``reference_afmoe.py``)."""
+against the plain reference (``benchmarks/chip/reference_afmoe.py``, loaded
+by its path)."""
 
 import json
 import os
@@ -14,13 +14,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import afmoe_reference
+from conftest import reference
 from dmlc_core_tpu.models import hybrid_lm
 from dmlc_core_tpu.models.hybrid_lm import HybridMoELM
 from dmlc_core_tpu.ops import moe
 from test_hybrid_lm import (ARCH as KIMI_ARCH, CAP, ROWS, TEMPLATES,
-                            make_batch, predict_args, spiced, write_docs)
+                            edges, make_batch, plain_runs, predict_args,
+                            spiced, write_docs)
 from test_hybrid_lm_dsv3 import ARCH as DSV3_ARCH
+
+reference_afmoe = reference("afmoe")
 
 WINDOW = 16
 ARCH = {
@@ -57,6 +60,11 @@ def params(model):
 @pytest.fixture(scope="module")
 def scorer(model):
     return jax.jit(model.forward_counted), jax.jit(model.probe)
+
+
+@pytest.fixture(scope="module")
+def plain(params):
+    return plain_runs(reference_afmoe.Reference(ARCH), params, TEMPLATES)
 
 
 def test_the_layers_are_grouped_query_attention_with_four_norms(model):
@@ -102,7 +110,8 @@ def test_the_benchmark_s_configuration_is_the_issue_s_arithmetic():
 
 
 @pytest.mark.parametrize("template", sorted(TEMPLATES))
-def test_forward_agrees_with_the_reference(model, params, scorer, template):
+def test_forward_agrees_with_the_reference(model, params, scorer, plain,
+                                           template):
     """float32 on both sides, so the gaps are the order of the sums: scores
     are means of log-probabilities (2e-5), logits single sums (1e-4); a
     choice is compared where the reference's margin is clear of that noise
@@ -111,10 +120,8 @@ def test_forward_agrees_with_the_reference(model, params, scorer, template):
     assert max(max(t) for t in TEMPLATES.values()) > 8 * WINDOW
     batch, host = make_batch(lengths)
     total = sum(lengths)
-    positions = sorted({0, total - 1} | set(np.cumsum(lengths)[:-1].tolist())
-                       | set((np.cumsum(lengths) - 1).tolist()))
-    ref = afmoe_reference.Reference(ARCH).run(
-        params, host["ids"], host["row_ptr"][:len(lengths) + 1], positions)
+    positions = edges(lengths)
+    ref = plain(template)
     scores, counters = scorer[0](params, batch)
     scores = np.asarray(scores)
     np.testing.assert_allclose(scores[:len(lengths)], ref["scores"],
@@ -142,26 +149,28 @@ def test_forward_agrees_with_the_reference(model, params, scorer, template):
             < rec[f"{name}.assignments"] + moe.DISPATCH_BLOCK
 
 
-@pytest.mark.parametrize("control", afmoe_reference.CONTROLS[1:])
-def test_each_control_of_the_reference_is_another_model(params, control):
+@pytest.mark.parametrize("control", reference_afmoe.CONTROLS[1:])
+def test_each_control_of_the_reference_is_another_model(params, plain,
+                                                        control):
     lengths = TEMPLATES["mixed"]
     _, host = make_batch(lengths)
-    run = lambda c: afmoe_reference.Reference(ARCH, c).run(    # noqa: E731
+    planted = reference_afmoe.Reference(ARCH, control).run(
         params, host["ids"], host["row_ptr"][:len(lengths) + 1])["scores"]
-    assert np.abs(run(control) - run(None)).max() > 1e-3
+    assert np.abs(planted - plain("mixed")["scores"]).max() > 1e-3
 
 
-def test_a_window_over_every_document_is_the_no_window_control(model, params):
+def test_a_window_over_every_document_is_the_no_window_control(model, params,
+                                                               plain):
     """The program with ``sliding_window`` past the longest document is the
     reference that ignores the window, and no more the sound one."""
     lengths = TEMPLATES["one_long"]
     batch, host = make_batch(lengths)
     wide = HybridMoELM(dict(ARCH, sliding_window=4096))
     got = np.asarray(wide.forward(params, batch))[:1]
-    run = lambda c: afmoe_reference.Reference(ARCH, c).run(    # noqa: E731
+    wanted = reference_afmoe.Reference(ARCH, "no_window").run(
         params, host["ids"], host["row_ptr"][:2])["scores"]
-    np.testing.assert_allclose(got, run("no_window"), atol=2e-5)
-    assert np.abs(got - run(None)).max() > 1e-3
+    np.testing.assert_allclose(got, wanted, atol=2e-5)
+    assert np.abs(got - plain("one_long")["scores"]).max() > 1e-3
 
 
 def test_positions_and_the_window_restart_at_a_document_boundary(model,
@@ -213,7 +222,7 @@ def test_the_rotation_pairs_the_halves(first):
         1j * np.asarray(angle, np.float64))[:, None]
     np.testing.assert_allclose(
         np.asarray(got), np.concatenate([z.real, z.imag], -1), atol=2e-5)
-    ref = afmoe_reference.Reference(dict(ARCH, head_dim=128))
+    ref = reference_afmoe.Reference(dict(ARCH, head_dim=128))
     np.testing.assert_allclose(
         np.asarray(ref.rotate(jnp.asarray(x, jnp.float32),
                               jnp.asarray(positions))),
@@ -242,7 +251,7 @@ def test_the_shares_add_up_to_the_uncut_layer(model, params, layer):
         out, counters, _ = share._moe(mine, x, live)
         total = total + np.asarray(out)
         loads += int(counters["assignments"])
-    ref = afmoe_reference.Reference(dict(ARCH, held_experts=[0, 16]))
+    ref = reference_afmoe.Reference(dict(ARCH, held_experts=[0, 16]))
     uncut, _, _ = ref.moe(whole, x)
     shared = np.asarray(ref.swiglu(x, whole["s_gu"], whole["s_down"]))
     np.testing.assert_allclose(total - 7 * shared, np.asarray(uncut),
@@ -299,17 +308,6 @@ def test_the_other_two_architectures_read_as_before():
                        for k in m.shapes()["layer_01"])
 
 
-def test_the_two_copies_of_the_reference_are_one_text():
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "afmoe_reference.py")) as f:
-        mine = f.read()
-    with open(os.path.join(here, "..", "benchmarks", "chip",
-                           "reference_afmoe.py")) as f:
-        assert f.read() == mine
-    assert "dmlc_core_tpu" not in mine
-    assert 'default_matmul_precision("highest")' in mine
-
-
 def test_predict_scores_an_afmoe_arch_file_through_the_cli(tmp_path):
     from dmlc_core_tpu.models import cli
     from dmlc_core_tpu.telemetry import trace
@@ -328,7 +326,7 @@ def test_predict_scores_an_afmoe_arch_file_through_the_cli(tmp_path):
     assert cli.main(argv) == 0
     got = np.loadtxt(f"{tmp_path}/scores.txt")
     assert got.shape == (len(docs),)
-    ref = afmoe_reference.Reference(ARCH)
+    ref = reference_afmoe.Reference(ARCH)
     for lo in range(0, len(docs), ROWS):
         part = docs[lo:lo + ROWS]
         rp = np.concatenate([[0], np.cumsum([len(d) for d in part])])

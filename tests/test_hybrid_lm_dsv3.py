@@ -3,8 +3,7 @@ widths on the CPU (hidden 64, 4 heads, ``q_lora_rank`` 32, ``kv_lora_rank``
 16, 16 + 8 / 24 a head so that ``d_v != d_n != d_n + d_r``, 16 experts in 4
 groups of which 2 are kept, top-4, 1 shared, one dense layer and three
 mixtures, YaRN over an original context of 64; float32), against the plain
-reference (``tests/dsv3_reference.py``, the same text as the benchmark's
-``reference_dsv3.py``)."""
+reference (``benchmarks/chip/reference_dsv3.py``, loaded by its path)."""
 
 import json
 import os
@@ -14,12 +13,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import dsv3_reference
+from conftest import reference
 from dmlc_core_tpu.models import hybrid_lm
 from dmlc_core_tpu.models.hybrid_lm import HybridMoELM
 from dmlc_core_tpu.ops import doc_attention, moe
 from test_hybrid_lm import (ARCH as KIMI_ARCH, CAP, ROWS, TEMPLATES,
-                            make_batch, predict_args, spiced, write_docs)
+                            edges, make_batch, plain_runs, predict_args,
+                            spiced, write_docs)
+
+reference_dsv3 = reference("dsv3")
 
 ARCH = {
     "model_type": "deepseek_v3", "hidden_size": 64, "num_hidden_layers": 4,
@@ -58,6 +60,11 @@ def scorer(model):
     return jax.jit(model.forward_counted), jax.jit(model.probe)
 
 
+@pytest.fixture(scope="module")
+def plain(params):
+    return plain_runs(reference_dsv3.Reference(ARCH), params, TEMPLATES)
+
+
 def test_the_layers_are_latent_attention_with_a_low_rank_query(model):
     shapes = model.shapes()
     assert [model.mixer(n) for n in range(1, 5)] == ["mla"] * 4
@@ -70,14 +77,13 @@ def test_the_layers_are_latent_attention_with_a_low_rank_query(model):
 
 
 @pytest.mark.parametrize("template", sorted(TEMPLATES))
-def test_forward_agrees_with_the_reference(model, params, scorer, template):
+def test_forward_agrees_with_the_reference(model, params, scorer, plain,
+                                           template):
     lengths = TEMPLATES[template]
     batch, host = make_batch(lengths)
     total = sum(lengths)
-    positions = sorted({0, total - 1} | set(np.cumsum(lengths)[:-1].tolist())
-                       | set((np.cumsum(lengths) - 1).tolist()))
-    ref = dsv3_reference.Reference(ARCH).run(
-        params, host["ids"], host["row_ptr"][:len(lengths) + 1], positions)
+    positions = edges(lengths)
+    ref = plain(template)
     scores, counters = scorer[0](params, batch)
     scores = np.asarray(scores)
     np.testing.assert_allclose(scores[:len(lengths)], ref["scores"],
@@ -102,12 +108,13 @@ def test_forward_agrees_with_the_reference(model, params, scorer, template):
 
 @pytest.mark.parametrize("control", ["no_rope", "plain_rope", "ungrouped",
                                      "half_experts", "fp8"])
-def test_each_control_of_the_reference_is_another_model(params, control):
+def test_each_control_of_the_reference_is_another_model(params, plain,
+                                                        control):
     lengths = TEMPLATES["mixed"]
     _, host = make_batch(lengths)
-    run = lambda c: dsv3_reference.Reference(ARCH, c).run(     # noqa: E731
+    planted = reference_dsv3.Reference(ARCH, control).run(
         params, host["ids"], host["row_ptr"][:len(lengths) + 1])["scores"]
-    assert np.abs(run(control) - run(None)).max() > 1e-3
+    assert np.abs(planted - plain("mixed")["scores"]).max() > 1e-3
 
 
 def complex_rotation(x, positions, freqs):
@@ -126,7 +133,7 @@ def test_the_rotation_is_a_complex_product(first):
         64, 1e5, dict(ARCH["rope_scaling"],
                       original_max_position_embeddings=4096))
     assert mscale == pytest.approx(0.1 * np.log(64) + 1)
-    want_f, _, want_m = dsv3_reference.yarn(dict(
+    want_f, _, want_m = reference_dsv3.yarn(dict(
         qk_rope_head_dim=64, rope_theta=1e5, rope_scaling=dict(
             ARCH["rope_scaling"], original_max_position_embeddings=4096)))
     np.testing.assert_allclose(freqs, want_f, rtol=1e-12)
@@ -250,7 +257,7 @@ def test_the_shares_add_up_to_the_uncut_layer(model, params, layer, each):
         out, counters, _ = share._moe(mine, x, live)
         total = total + np.asarray(out)
         loads += int(counters["assignments"])
-    ref = dsv3_reference.Reference(dict(ARCH, held_experts=[0, 16]))
+    ref = reference_dsv3.Reference(dict(ARCH, held_experts=[0, 16]))
     uncut, _, _ = ref.moe(whole, x)
     shared = np.asarray(ref.swiglu(x, whole["s_gu"], whole["s_down"]))
     np.testing.assert_allclose(total - (16 // each - 1) * shared,
@@ -430,17 +437,6 @@ def test_plain_rope_without_scaling_is_computed():
     assert m.attn_scale == pytest.approx(24 ** -0.5)
 
 
-def test_the_two_copies_of_the_reference_are_one_text():
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "dsv3_reference.py")) as f:
-        mine = f.read()
-    with open(os.path.join(here, "..", "benchmarks", "chip",
-                           "reference_dsv3.py")) as f:
-        assert f.read() == mine
-    assert "dmlc_core_tpu" not in mine.replace("``dmlc_core_tpu``", "")
-    assert 'default_matmul_precision("highest")' in mine
-
-
 def test_predict_scores_a_deepseek_v3_arch_file_through_the_cli(tmp_path):
     from dmlc_core_tpu.models import cli
     from dmlc_core_tpu.telemetry import trace
@@ -459,7 +455,7 @@ def test_predict_scores_a_deepseek_v3_arch_file_through_the_cli(tmp_path):
     assert cli.main(argv) == 0
     got = np.loadtxt(f"{tmp_path}/scores.txt")
     assert got.shape == (len(docs),)
-    ref = dsv3_reference.Reference(ARCH)
+    ref = reference_dsv3.Reference(ARCH)
     for lo in range(0, len(docs), ROWS):
         part = docs[lo:lo + ROWS]
         rp = np.concatenate([[0], np.cumsum([len(d) for d in part])])

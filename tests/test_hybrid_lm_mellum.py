@@ -5,7 +5,6 @@ sliding layers and YaRN on the full one, 8 experts top-2 by softmax, all
 held, no shared expert; float32), against the plain reference
 (``benchmarks/chip/reference_mellum.py``, loaded by its path)."""
 
-import importlib.util
 import json
 import math
 import os
@@ -15,27 +14,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import BENCH, reference
 from dmlc_core_tpu.models import hybrid_lm
 from dmlc_core_tpu.models.hybrid_lm import HybridMoELM
 from dmlc_core_tpu.ops import moe
-from test_hybrid_lm import (ARCH as KIMI_ARCH, ROWS, make_batch,
-                            predict_args, spiced, write_docs)
+from test_hybrid_lm import (ARCH as KIMI_ARCH, ROWS, edges, make_batch,
+                            plain_runs, predict_args, spiced, write_docs)
 from test_hybrid_lm_afmoe import ARCH as AFMOE_ARCH
 from test_hybrid_lm_dsv3 import ARCH as DSV3_ARCH
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-BENCH = os.path.join(HERE, "..", "benchmarks", "chip")
-
-
-def _load_reference():
-    spec = importlib.util.spec_from_file_location(
-        "reference_mellum", os.path.join(BENCH, "reference_mellum.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-reference_mellum = _load_reference()
+reference_mellum = reference("mellum")
 
 WINDOW = 8
 # a YaRN that bends inside 40 tokens: theta 100 and an original context of
@@ -81,6 +70,11 @@ def params(model):
 @pytest.fixture(scope="module")
 def scorer(model):
     return jax.jit(model.forward_counted), jax.jit(model.probe)
+
+
+@pytest.fixture(scope="module")
+def plain(params):
+    return plain_runs(reference_mellum.Reference(ARCH), params, TEMPLATES)
 
 
 def yarn_by_transformers(d, theta, factor, original, beta_fast, beta_slow):
@@ -145,7 +139,8 @@ def test_the_benchmark_s_configuration_counts_its_parameters():
 
 
 @pytest.mark.parametrize("template", sorted(TEMPLATES))
-def test_forward_agrees_with_the_reference(model, params, scorer, template):
+def test_forward_agrees_with_the_reference(model, params, scorer, plain,
+                                           template):
     """float32 on both sides, so the gaps are the order of the sums: scores
     are means of log-probabilities (2e-5), logits single sums (1e-4); a
     choice is compared where the reference's margin between the k-th and
@@ -154,10 +149,8 @@ def test_forward_agrees_with_the_reference(model, params, scorer, template):
     assert max(lengths) > 3 * WINDOW
     batch, host = make_batch(lengths)
     total = sum(lengths)
-    positions = sorted({0, total - 1} | set(np.cumsum(lengths)[:-1].tolist())
-                       | set((np.cumsum(lengths) - 1).tolist()))
-    ref = reference_mellum.Reference(ARCH).run(
-        params, host["ids"], host["row_ptr"][:len(lengths) + 1], positions)
+    positions = edges(lengths)
+    ref = plain(template)
     scores, counters = scorer[0](params, batch)
     scores = np.asarray(scores)
     np.testing.assert_allclose(scores[:len(lengths)], ref["scores"],
@@ -212,7 +205,8 @@ def test_yarn_follows_transformers_at_the_published_numbers(factor):
     assert (np.diff(between) < 0).all()
 
 
-def test_sliding_layers_turn_plainly_and_full_layers_by_yarn(model, params):
+def test_sliding_layers_turn_plainly_and_full_layers_by_yarn(model, params,
+                                                             plain):
     """Each kind its own rotation: the program with the full layers on
     plain RoPE is the reference's ``plain_rope_full`` control, and with the
     attention factor at 1 its ``no_attention_factor`` control; the sound
@@ -230,7 +224,7 @@ def test_sliding_layers_turn_plainly_and_full_layers_by_yarn(model, params):
     batch, host = make_batch(lengths)
     run = lambda c: reference_mellum.Reference(ARCH, c).run(   # noqa: E731
         params, host["ids"], host["row_ptr"][:len(lengths) + 1])["scores"]
-    sound = run(None)
+    sound = plain("mixed")["scores"]
     for control, rope in (
             ("plain_rope_full", {"rope_type": "default", "rope_theta": 100}),
             ("no_attention_factor", dict(YARN, attention_factor=1.0))):
@@ -293,12 +287,13 @@ def test_the_shares_add_up_to_the_uncut_layer(params, layer):
 
 
 @pytest.mark.parametrize("control", reference_mellum.CONTROLS[1:])
-def test_each_control_of_the_reference_is_another_model(params, control):
+def test_each_control_of_the_reference_is_another_model(params, plain,
+                                                        control):
     lengths = TEMPLATES["mixed"]
     _, host = make_batch(lengths)
-    run = lambda c: reference_mellum.Reference(ARCH, c).run(   # noqa: E731
+    planted = reference_mellum.Reference(ARCH, control).run(
         params, host["ids"], host["row_ptr"][:len(lengths) + 1])["scores"]
-    assert np.abs(run(control) - run(None)).max() > 1e-3
+    assert np.abs(planted - plain("mixed")["scores"]).max() > 1e-3
 
 
 def test_canonical_reads_the_mellum_spelling():

@@ -15,6 +15,7 @@ import subprocess
 import sys
 import threading
 import time
+import types
 
 import numpy as np
 import pytest
@@ -184,9 +185,13 @@ def test_frame_reassembly_trickle_coalesced_torn():
 # idle reaping
 # ---------------------------------------------------------------------------
 
-def test_idle_connections_reaped_active_ones_kept():
-    # generous idle_s: on a loaded 1-core CI host a keepalive sleep can
-    # stretch well past a tight deadline and reap the chatty conn too
+def test_idle_connections_reaped_active_ones_kept(monkeypatch):
+    # the reactor reads this clock, which moves only when the test moves
+    # it: a slow host stretches the real gaps, never the idle ones
+    from dmlc_core_tpu.transport import reactor as reactor_mod
+    clock = [1000.0]
+    monkeypatch.setattr(reactor_mod, "time",
+                        types.SimpleNamespace(monotonic=lambda: clock[0]))
     r, lst, _frames = _echo_reactor(idle_s=1.0)
     before = _counter("transport.reactor.idle_reaped")
     try:
@@ -194,12 +199,17 @@ def test_idle_connections_reaped_active_ones_kept():
         chatty = socket.create_connection((lst.host, lst.port), timeout=10)
         silent.settimeout(10.0)
         chatty.settimeout(10.0)
-        # traffic every 0.2 s keeps chatty alive well past the deadline
-        end = time.monotonic() + 3.0
-        while time.monotonic() < end:
+        # chatty speaks every 0.4 s of the clock, 3.2 s in all: silent's
+        # 1 s passes, chatty's never does
+        for _ in range(8):
             chatty.sendall(LEN.pack(2) + b"hi")
             assert _recv_exact(chatty, LEN.size + 2) is not None
-            time.sleep(0.2)
+            if clock[0] < 1000.9:         # not yet idle for its limit
+                silent.setblocking(False)
+                with pytest.raises(BlockingIOError):
+                    silent.recv(1)
+                silent.settimeout(10.0)
+            clock[0] += 0.4
         assert silent.recv(1) == b""        # reaped: EOF
         assert _counter("transport.reactor.idle_reaped") > before
         chatty.sendall(LEN.pack(2) + b"yo")

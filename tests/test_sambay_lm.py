@@ -4,8 +4,7 @@ window of 16 under documents of up to 600 tokens, 8 layers keeping the
 pattern — Mamba and windowed differential attention, the memory's Mamba
 layer, the whole-document layer whose keys and values are shared, a GMU and
 a cross-attention layer; float32), against the plain reference
-(``tests/sambay_reference.py``, the same text as the benchmark's
-``reference_sambay.py``)."""
+(``benchmarks/chip/reference_sambay.py``, loaded by its path)."""
 
 import json
 import os
@@ -15,10 +14,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import sambay_reference
+from conftest import reference
 from dmlc_core_tpu.models.sambay_lm import SambaYLM
-from test_hybrid_lm import ROWS, TEMPLATES, make_batch, predict_args, \
-    write_docs
+from test_hybrid_lm import (ROWS, TEMPLATES, edges, make_batch, plain_runs,
+                            predict_args, write_docs)
+
+reference_sambay = reference("sambay")
 
 WINDOW = 16
 PAD = 64        # documents filled up to 64 rows: fewer shapes, as on the chip
@@ -66,10 +67,10 @@ def scorer(model):
     return jax.jit(model.forward_counted), jax.jit(model.probe)
 
 
-def edges(lengths):
-    total = sum(lengths)
-    return sorted({0, total - 1} | set(np.cumsum(lengths)[:-1].tolist())
-                  | set((np.cumsum(lengths) - 1).tolist()))
+@pytest.fixture(scope="module")
+def plain(params):
+    return plain_runs(reference_sambay.Reference(ARCH, pad_to=PAD), params,
+                      TEMPLATES)
 
 
 def test_the_layers_keep_the_pattern(model):
@@ -88,7 +89,8 @@ def test_the_layers_keep_the_pattern(model):
 
 
 @pytest.mark.parametrize("template", sorted(TEMPLATES))
-def test_forward_agrees_with_the_reference(model, params, scorer, template):
+def test_forward_agrees_with_the_reference(model, params, scorer, plain,
+                                           template):
     """float32 on both sides, so the gaps are the order of the sums (a
     chunked associative scan against a token-by-token one, a blocked walk
     against dense maps): scores 2e-5, logits 1e-4."""
@@ -97,8 +99,7 @@ def test_forward_agrees_with_the_reference(model, params, scorer, template):
     batch, host = make_batch(lengths)
     total = sum(lengths)
     positions = edges(lengths)
-    ref = sambay_reference.Reference(ARCH, pad_to=PAD).run(
-        params, host["ids"], host["row_ptr"][:len(lengths) + 1], positions)
+    ref = plain(template)
     scores, counters = scorer[0](params, batch)
     scores = np.asarray(scores)
     np.testing.assert_allclose(scores[:len(lengths)], ref["scores"],
@@ -116,22 +117,14 @@ def test_forward_agrees_with_the_reference(model, params, scorer, template):
                                            else 5.0)
 
 
-@pytest.fixture(scope="module")
-def mixed(params):
-    """The ``mixed`` template's arguments and its sound reference scores."""
+@pytest.mark.parametrize("control", reference_sambay.CONTROLS[1:])
+def test_each_control_of_the_reference_is_another_model(params, plain,
+                                                        control):
     lengths = TEMPLATES["mixed"]
     _, host = make_batch(lengths)
-    args = (params, host["ids"], host["row_ptr"][:len(lengths) + 1])
-    return args, sambay_reference.Reference(ARCH, pad_to=PAD).run(
-        *args)["scores"]
-
-
-@pytest.mark.parametrize("control", sambay_reference.CONTROLS[1:])
-def test_each_control_of_the_reference_is_another_model(mixed, control):
-    args, sound = mixed
-    planted = sambay_reference.Reference(ARCH, control, pad_to=PAD).run(
-        *args)["scores"]
-    assert np.abs(planted - sound).max() > 1e-3
+    planted = reference_sambay.Reference(ARCH, control, pad_to=PAD).run(
+        params, host["ids"], host["row_ptr"][:len(lengths) + 1])["scores"]
+    assert np.abs(planted - plain("mixed")["scores"]).max() > 1e-3
 
 
 def test_the_scan_s_state_resets_at_every_document(model, params, scorer):
@@ -144,7 +137,7 @@ def test_the_scan_s_state_resets_at_every_document(model, params, scorer):
     both = np.asarray(scorer[0](params, batch)[0])
     single = np.asarray(scorer[0](params, alone)[0])
     assert abs(both[1] - single[0]) < 1e-5
-    leaky = sambay_reference.Reference(ARCH, "no_reset", pad_to=PAD).run(
+    leaky = reference_sambay.Reference(ARCH, "no_reset", pad_to=PAD).run(
         params, host["ids"], host["row_ptr"][:3])["scores"]
     assert abs(leaky[1] - both[1]) > 1e-3 and abs(leaky[0] - both[0]) < 1e-5
 
@@ -214,17 +207,6 @@ def test_an_architecture_it_does_not_compute_is_refused(key, value):
         SambaYLM(dict(ARCH, **{key: value}))
 
 
-def test_the_two_copies_of_the_reference_are_one_text():
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "sambay_reference.py")) as f:
-        mine = f.read()
-    with open(os.path.join(here, "..", "benchmarks", "chip",
-                           "reference_sambay.py")) as f:
-        assert f.read() == mine
-    assert "dmlc_core_tpu" not in mine
-    assert 'default_matmul_precision("highest")' in mine
-
-
 def test_predict_scores_a_sambay_arch_file_through_the_cli(tmp_path):
     from dmlc_core_tpu.models import cli
     from dmlc_core_tpu.telemetry import trace
@@ -245,7 +227,7 @@ def test_predict_scores_a_sambay_arch_file_through_the_cli(tmp_path):
     assert cli.main(argv) == 0
     got = np.loadtxt(f"{tmp_path}/scores.txt")
     assert got.shape == (len(docs),)
-    ref = sambay_reference.Reference(ARCH, pad_to=PAD)
+    ref = reference_sambay.Reference(ARCH, pad_to=PAD)
     for lo in range(0, len(docs), ROWS):
         part = docs[lo:lo + ROWS]
         rp = np.concatenate([[0], np.cumsum([len(d) for d in part])])
