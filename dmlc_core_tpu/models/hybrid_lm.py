@@ -11,9 +11,9 @@ score a row: the mean log-probability of each next token given the tokens
 before it in the document, ``1/(n-1) sum_t log p(x_{t+1} | x_{<=t})``.
 
 The architecture arrives as the model's published ``config.json`` keys, in
-the ``kimi_linear``, the ``deepseek_v3``, the ``afmoe`` or the ``mellum``
-spelling (``canonical`` maps the others onto the first, the one the class
-reads; a key the file leaves out means the plain block below),
+the ``kimi_linear``, the ``deepseek_v3``, the ``afmoe``, the ``mellum`` or
+the ``brumby`` spelling (``canonical`` maps the others onto the first, the
+one the class reads; a key the file leaves out means the plain block below),
 plus two that say what this holder keeps of a layer shared between chips:
 ``held_experts = [lo, hi]`` and ``vocab_rows``.  Pre-norm residual blocks,
 RMSNorm, untied embedding and head:
@@ -51,9 +51,23 @@ RMSNorm, untied embedding and head:
   norms every sublayer's *output* before it joins the residual
   (``x + post_norm(f(norm(x)))``); ``mup_enabled`` scales the embedding
   row by ``sqrt(hidden_size)``;
+* a ``power_retention`` layer (``brumby``: Qwen3's block, QK-norm kept,
+  ``RETENTION_BLOCK``) retains in place of attending (``ops.power_retention``).
+  With ``x`` the layer's normed input, query head ``h`` on key head
+  ``g = h // (H / H_kv)``: ``q_h = RoPE(RMSNorm_d(W_q,h x))``,
+  ``k_g = RoPE(RMSNorm_d(W_k,g x))`` (plain RoPE at ``rope_theta``, the
+  halves paired, at the position in the document), ``v_g = W_v,g x``; a
+  gate a key head ``log gamma_t = logsigmoid(decay_up x_t + decay_rate)``
+  and ``Gamma_tj = prod_{l=j+1..t} gamma_l`` inside one document;
+  ``o_t = sum_{j<=t} Gamma_tj (q_t . k_j)^2 v_j / (sum_{j<=t} Gamma_tj
+  (q_t . k_j)^2 + eps)``, the same as the state form ``S_t = gamma_t S_{t-1}
+  + phi(k_t) v_t^T``, ``z_t = gamma_t z_{t-1} + phi(k_t)``, ``o_t =
+  phi(q_t)^T S_t / (phi(q_t)^T z_t + eps)`` with ``phi`` the symmetric
+  square; then ``W_o [o_1 .. o_H]``;
 * the first ``first_k_dense_replace`` (``num_dense_layers``, the leading
-  ``dense`` entries of ``mlp_layer_types``) layers have a dense SwiGLU, the
-  rest a mixture (``ops.moe``): sigmoid scores with a bias in the choice
+  ``dense`` entries of ``mlp_layer_types``; every layer where the file names
+  no ``num_experts``) layers have a dense SwiGLU, the rest a mixture
+  (``ops.moe``): sigmoid scores with a bias in the choice
   (``topk_method`` ``noaux_tc``), or, where the file names no scoring rule,
   the plain top ``k`` of the softmax over all experts (``greedy``), no bias;
   plus ``num_shared_experts`` shared experts (none where absent), weights
@@ -64,7 +78,8 @@ RMSNorm, untied embedding and head:
 ``dtype`` (bfloat16 as published) is the type of parameters and
 activations; router scores, group sums and the choice, the rotation,
 softmax and log-softmax, RMSNorm statistics, the gates' sigmoid, the decay
-(in log space), the recurrent state and every accumulation are float32.  The
+(in log space), the recurrent state, retention's squares, numerator and
+denominator and every accumulation are float32.  The
 ``[T, vocab_rows]`` logits exist one block of tokens at a time.
 """
 
@@ -80,6 +95,7 @@ import jax.numpy as jnp
 from ..ops.doc_attention import doc_causal_attention_counted, walk_blocks
 from ..ops.kda import kda_chunked_counted
 from ..ops.moe import held_experts_sum, route, swiglu
+from ..ops.power_retention import needed_flops, power_retention_counted
 
 __all__ = ["HybridMoELM", "canonical", "doc_scores", "load_arch"]
 
@@ -111,6 +127,10 @@ SPELLINGS_AFMOE = {
 # config.json names no key for: a file in its spelling has all three
 AFMOE_BLOCK = {"attention_output_gate": True, "qk_norm": True,
                "sublayer_post_norm": True}
+# the layer kinds of ``layer_types``: windowed and whole-document softmax
+# attention, and power retention (``brumby``: Qwen3's block, QK-norm kept)
+KINDS = ("sliding_attention", "full_attention", "power_retention")
+RETENTION_BLOCK = {"qk_norm": True}
 # a router's scores -> the choice they go with: a bias beside sigmoid
 # scores (``noaux_tc``), the plain top k of a softmax (``greedy``)
 ROUTERS = {"sigmoid": "noaux_tc", "softmax": "greedy"}
@@ -140,11 +160,15 @@ def canonical(arch: dict) -> dict:
     """``arch`` with every key of ``SPELLINGS`` and ``SPELLINGS_AFMOE``
     under the name the class reads, ``mlp_layer_types`` as
     ``first_k_dense_replace`` and, in a file in ``afmoe``'s spelling, the
-    ``AFMOE_BLOCK`` keys it does not give; a file that gives two spellings
-    has to give them alike."""
+    ``AFMOE_BLOCK`` keys it does not give (``RETENTION_BLOCK``'s in a file
+    with ``power_retention`` layers); a file that gives two spellings has
+    to give them alike."""
     a = dict(arch)
     if set(SPELLINGS_AFMOE.values()) & set(a):
         for key, value in AFMOE_BLOCK.items():
+            a.setdefault(key, value)
+    if KINDS[2] in a.get("layer_types", ()):
+        for key, value in RETENTION_BLOCK.items():
             a.setdefault(key, value)
     spelt = [*SPELLINGS.items(), *SPELLINGS_AFMOE.items()]
     if "mlp_layer_types" in a:
@@ -337,6 +361,7 @@ class HybridMoELM:
             self.conv_taps = int(lin["short_conv_kernel_size"])
             self.gate_rank = self.kda_dim
         self.heads = int(a["num_attention_heads"])
+        self.retention_layers: set = set()
         theta = float(a.get("rope_theta", 10000.0))
         # what the layers outside ``kda_layers`` mix tokens with
         self.attention = "gqa" if "layer_types" in a \
@@ -361,8 +386,15 @@ class HybridMoELM:
         # the norm on a sublayer's output and the embedding's multiplier
         self.sandwich = bool(a.get("sublayer_post_norm"))
         self.embed_scale = self.hidden ** 0.5 if a.get("mup_enabled") else 1.0
-        self.dense_layers = int(a["first_k_dense_replace"])
         self.dense_width = int(a["intermediate_size"])
+        # an architecture without experts is dense at every layer
+        self.dense_layers = int(a["first_k_dense_replace"]) \
+            if "num_experts" in a else self.layers
+        if self.dense_layers < self.layers:
+            self._init_experts(a)
+        self.vocab = int(a.get("vocab_rows", a["vocab_size"]))
+
+    def _init_experts(self, a: dict) -> None:
         self.expert_width = int(a["moe_intermediate_size"])
         self.experts = int(a["num_experts"])
         self.top_k = int(a["num_experts_per_token"])
@@ -384,7 +416,6 @@ class HybridMoELM:
         if not 0 <= self.held[0] < self.held[1] <= self.experts:
             raise ValueError(f"held_experts {self.held} is no range of the "
                              f"{self.experts} experts")
-        self.vocab = int(a.get("vocab_rows", a["vocab_size"]))
 
     def _init_gqa(self, a: dict, theta: float) -> None:
         self.kv_heads = int(a["num_key_value_heads"])
@@ -393,16 +424,18 @@ class HybridMoELM:
             raise ValueError(f"{self.heads} query heads on {self.kv_heads} "
                              f"key heads are no whole groups")
         types = list(a["layer_types"])[:self.layers]
-        known = ("sliding_attention", "full_attention")
-        if len(types) < self.layers or set(types) - set(known):
-            raise ValueError(f"layer_types has to say one of {known} for "
+        if len(types) < self.layers or set(types) - set(KINDS):
+            raise ValueError(f"layer_types has to say one of {KINDS} for "
                              f"each of the {self.layers} layers, it says "
                              f"{types}")
         self.sliding = {n for n, kind in enumerate(types, 1)
-                        if kind == known[0]}
-        self.window = int(a["sliding_window"])
-        if self.window < 1:
-            raise ValueError(f"sliding_window {self.window}")
+                        if kind == KINDS[0]}
+        self.retention_layers = {n for n, kind in enumerate(types, 1)
+                                 if kind == KINDS[2]}
+        if self.sliding or a.get("sliding_window") is not None:
+            self.window = int(a["sliding_window"])
+            if self.window < 1:
+                raise ValueError(f"sliding_window {self.window}")
         if a.get("rope_scaling"):
             raise ValueError("hybrid_moe_lm computes rope_scaling=None only "
                              "under grouped-query attention, the "
@@ -411,15 +444,19 @@ class HybridMoELM:
         # scope a trace reads), or None where that kind rotates nothing
         by_kind = a.get("rope_parameters")
         if by_kind is None:
-            self.rotations = {known[0]: (*rope_frequencies(
-                self.head_dim, theta, None), "gqa/rope"), known[1]: None}
+            self.rotations = {KINDS[0]: (*rope_frequencies(
+                self.head_dim, theta, None), "gqa/rope"), KINDS[1]: None}
+            if self.retention_layers:      # plain RoPE at rope_theta
+                self.rotations[KINDS[2]] = (*rope_frequencies(
+                    self.head_dim, theta, None), "retention/qk_norm_rope")
         else:
             if set(types) - set(by_kind):
                 raise ValueError(f"rope_parameters has to give each of "
                                  f"{sorted(set(types))} its own entry, it "
                                  f"gives {sorted(by_kind)}")
             self.rotations = {kind: (*rope_of_kind(self.head_dim, rope), (
-                "gqa/rope_yarn" if rope.get("rope_type") == "yarn"
+                "retention/qk_norm_rope" if kind == KINDS[2]
+                else "gqa/rope_yarn" if rope.get("rope_type") == "yarn"
                 else "gqa/rope")) for kind, rope in by_kind.items()
                 if kind in types}
         self.yarn_layers = sum(turn is not None and turn[2] == "gqa/rope_yarn"
@@ -427,7 +464,10 @@ class HybridMoELM:
         self.attn_scale = self.head_dim ** -0.5
 
     def mixer(self, layer: int) -> str:
-        return "kda" if layer in self.kda_layers else self.attention
+        if layer in self.kda_layers:
+            return "kda"
+        return "retention" if layer in self.retention_layers \
+            else self.attention
 
     # -- parameters -------------------------------------------------------
     def _layer_shapes(self, layer: int) -> Dict[str, tuple]:
@@ -451,6 +491,10 @@ class HybridMoELM:
                 s.update(w_gate=(h, n))
             if self.qk_norm:
                 s.update(q_head_norm=(d,), k_head_norm=(d,))
+            if self.mixer(layer) == "retention":
+                # the gate's logit a key head: decay_up x + decay_rate
+                s.update(decay_up=(h, self.kv_heads),
+                         decay_rate=(self.kv_heads,))
         else:
             dq = self.heads * (self.d_nope + self.d_rope)
             if self.q_rank:
@@ -588,21 +632,36 @@ class HybridMoELM:
         with jax.named_scope("mla/out"):
             return _mm(o.astype(x.dtype).reshape(t, nh * dv), p["wo"]), fused
 
+    def _heads(self, p, x, scope):
+        """The grouped-query projections under ``<scope>/project``: ``q [T,
+        H, d]``, ``k, v [T, H_kv, d]`` and the output gate's logits (None
+        where the block has no gate)."""
+        t = x.shape[0]
+        nh, nkv, d = self.heads, self.kv_heads, self.head_dim
+        with jax.named_scope(f"{scope}/project"):
+            q = _mm(x, p["wq"]).reshape(t, nh, d)
+            k = _mm(x, p["wk"]).reshape(t, nkv, d)
+            v = _mm(x, p["wv"]).reshape(t, nkv, d)
+            gate = _mm(x, p["w_gate"]) if self.gate else None
+        return q, k, v, gate
+
+    def _qk_norm(self, p, q, k):
+        """Each head's columns of ``q`` and of ``k`` through RMSNorm, one
+        weight vector each, where the block norms them."""
+        if not self.qk_norm:
+            return q, k
+        return (_rms(q, p["q_head_norm"], self.eps),
+                _rms(k, p["k_head_norm"], self.eps))
+
     def _gqa(self, p, x, seg, first_key, turn):
         """``first_key [T]`` the first key each token sees in this layer;
         ``turn`` (cos, sin, scope) of the layer's kind, None on a layer that
         rotates nothing."""
         t = x.shape[0]
-        nh, nkv, d = self.heads, self.kv_heads, self.head_dim
-        with jax.named_scope("gqa/project"):
-            q = _mm(x, p["wq"]).reshape(t, nh, d)
-            k = _mm(x, p["wk"]).reshape(t, nkv, d)
-            v = _mm(x, p["wv"]).reshape(t, nkv, d)
-            gate = _mm(x, p["w_gate"]) if self.gate else None
+        nh, d = self.heads, self.head_dim
+        q, k, v, gate = self._heads(p, x, "gqa")
         with jax.named_scope("gqa/qk_norm"):
-            if self.qk_norm:
-                q = _rms(q, p["q_head_norm"], self.eps)
-                k = _rms(k, p["k_head_norm"], self.eps)
+            q, k = self._qk_norm(p, q, k)
             if turn is None:
                 q = (q.astype(F32) * self.attn_scale).astype(x.dtype)
         if turn is not None:
@@ -619,6 +678,28 @@ class HybridMoELM:
                 o = o * jax.nn.sigmoid(gate.astype(F32))
         with jax.named_scope("gqa/out"):
             return _mm(o.astype(x.dtype), p["wo"]), fused
+
+    def _retention(self, p, x, seg, turn):
+        """Power retention in the grouped-query block's place: ``q`` and
+        ``k`` normed a head and turned by ``turn`` (cos, sin, scope), the
+        gate ``log gamma = logsigmoid(decay_up x + decay_rate)`` a key head,
+        then ``ops.power_retention``.  Returns ``(out, fused, flops_done)``."""
+        t = x.shape[0]
+        q, k, v, _ = self._heads(p, x, "retention")
+        with jax.named_scope(turn[2]):
+            q, k = self._qk_norm(p, q, k)
+            cos, sin = turn[0][:, None], turn[1][:, None]
+            q = _rotate_halves(q, cos, sin).astype(x.dtype)
+            k = _rotate_halves(k, cos, sin).astype(x.dtype)
+        with jax.named_scope("retention/gate"):
+            log_g = jax.nn.log_sigmoid(
+                jnp.dot(x, p["decay_up"], preferred_element_type=F32)
+                + p["decay_rate"].astype(F32))
+        with jax.named_scope("retention/kernel"):
+            o, fused, done = power_retention_counted(q, k, v, log_g, seg)
+        with jax.named_scope("retention/out"):
+            o = o.astype(x.dtype).reshape(t, self.heads * self.head_dim)
+            return _mm(o, p["wo"]), fused, done
 
     def _post(self, p, name, out):
         """A sublayer's output as it joins the residual: through its own
@@ -667,7 +748,11 @@ class HybridMoELM:
                     "mla.fused_layers": jnp.int32(0),
                     "gqa.fused_layers": jnp.int32(0),
                     "moe.fused_combines": jnp.int32(0)}
-        if self.attention == "gqa":
+        if self.retention_layers:
+            counters["retention.fused_layers"] = jnp.int32(0)
+            needed = needed_flops(jnp.diff(row_ptr), self.heads,
+                                  self.kv_heads, self.head_dim)
+        if any(self.mixer(n) == "gqa" for n in range(1, self.layers + 1)):
             # the first key a token sees: on a full layer its document's
             # start, on a sliding one no more than a window back
             in_window = jnp.maximum(
@@ -686,6 +771,11 @@ class HybridMoELM:
                 out, fused = self._kda(p, y, seg, pos)
             elif mixer == "mla":
                 out, fused = self._mla(p, y, seg, doc_start, turn)
+            elif mixer == "retention":
+                out, fused, done = self._retention(
+                    p, y, seg, turns["power_retention"])
+                counters[name] = {"retention.flops_done": done,
+                                  "retention.flops_needed": needed}
             elif layer in self.sliding:
                 out, fused = self._gqa(p, y, seg, in_window,
                                        turns["sliding_attention"])
@@ -699,7 +789,8 @@ class HybridMoELM:
                 with jax.named_scope("dense_mlp"):
                     out = swiglu(y, p["w_gu"], p["w_down"])
             else:
-                out, counters[name], choices[name] = self._moe(p, y, live)
+                out, mine, choices[name] = self._moe(p, y, live)
+                counters[name] = {**counters.get(name, {}), **mine}
                 counters["moe.fused_combines"] += counters[name].pop(
                     "fused_combine")
                 for key, total in (("gather_combine", "moe.gather_combines"),
@@ -727,7 +818,12 @@ class HybridMoELM:
         ``attn.key_blocks_window``, the key blocks one full and one sliding
         layer's walk visits for this batch
         (``ops.doc_attention.walk_blocks``), and where layers rotate by
-        YaRN ``gqa.yarn_layers``, how many."""
+        YaRN ``gqa.yarn_layers``, how many; where layers retain,
+        ``retention.fused_layers``, how many took the retention kernel, and
+        per such layer ``retention.flops_done``, the operations its
+        schedule executed (masked pairs and padding included), and
+        ``retention.flops_needed``, those of each document's cheaper exact
+        form (``ops.power_retention.needed_flops``)."""
         hid, counters, _ = self._hidden(params, batch)
         scores, counts = doc_scores(
             hid, batch, lambda h: jnp.dot(h, params["head"],

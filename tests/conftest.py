@@ -52,7 +52,7 @@ if os.environ.get("DMLC_LOCKCHECK") == "1":
 
 
 # A test that runs this long is hung: the longest, the first scorer compile
-# of ``test_tpu_compile_lm.py``, which waits while the file's five compiles
+# of ``test_tpu_compile_lm.py``, which waits while the file's six compiles
 # run side by side, takes 53 s alone and up to 102 s in a whole run of the
 # suite on 8 cores, so a host 2.4 times slower still leaves it a margin of
 # 1.7.  Its own run ends there as a failure, with every thread's

@@ -165,6 +165,22 @@ def _sambay(compiled, text, model, t, args):
                     and model.d_inner in d), d             # [T, C, N]
 
 
+def _brumby(compiled, text, model, t, args):
+    # retention never holds the symmetric square of a token's key or query
+    # in HBM: no buffer of its 8 256 features (8 320 as the kernel lays
+    # them out), and the kernel writes bfloat16 [T, H d] alone
+    features = model.head_dim * (model.head_dim + 1) // 2
+    for d in _dims(text):
+        assert features not in d and features + 64 not in d, d
+    for line in _kernels(text, "power_retention"):
+        assert re.search(rf"= bf16\[{t},{model.heads * model.head_dim}\]",
+                         line), line[:200]
+    # the dense layer's float32 gate and up in two halves of the tokens
+    width = 2 * model.dense_width
+    assert f"f32[{t // 2},{width}]" in text
+    assert f"f32[{t},{width}]" not in text
+
+
 class Scorer(NamedTuple):
     """One configuration's compile: ``config`` under
     ``benchmarks/chip/configs`` read by ``load_arch`` into a ``model``;
@@ -247,6 +263,21 @@ SCORERS = {
         absent=("ragged-dot",),
         absent_ops=("gqa/gate", "gqa/qk_norm/", "post_norm", "moe/shared"),
         own=_mellum),
+    # eight layers, the whole vocabulary, 4 documents in 32 768 tokens:
+    # 8.40 GB of arguments + 3.67 GB of temporaries (12.07 GB; 14.34 GB
+    # before the dense layer's products were halved).  Every layer's
+    # retention is one ``power_retention`` kernel under the scope a trace
+    # reads; no attention, no experts
+    "document_scorer_brumby_forward": Scorer(
+        hybrid_lm.load_arch, HybridMoELM, "brumby_14b_base_l8", 32768, 4,
+        params=4_198_652_992, hbm=12_500_000_000,
+        kernels={"power_retention": ("retention/kernel", 8)},
+        scopes=("lm_embed", "retention/project", "retention/qk_norm_rope",
+                "retention/gate", "retention/kernel", "retention/out",
+                "dense_mlp", "lm_head"),
+        absent=("ragged-dot", " %doc_attention", " %kda_chunk"),
+        absent_ops=("gqa/", "mla/", "moe/"),
+        own=_brumby),
     # the SambaY scorer whole: 32 layers, the whole tied vocabulary, 8
     # documents in 32 768 tokens: 7.706 GB of arguments + 3.545 GB of
     # temporaries (reckoned at 4-5 GB of temporaries before the first
